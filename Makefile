@@ -4,7 +4,7 @@
 PY ?= python3
 N ?= 4
 
-.PHONY: test lint race status-smoke bench bench-mesh bench-ingest bench-packed trend soak dist wheel-proof demo-conf demo demo-watch demo-bombard multichip version
+.PHONY: test lint race status-smoke smoke smoke-tiny bench bench-mesh bench-ingest bench-packed trend soak dist wheel-proof demo-conf demo demo-watch demo-bombard multichip version
 
 test:
 	$(PY) -m pytest tests/ -q
@@ -53,6 +53,17 @@ lint:
 status-smoke:
 	JAX_PLATFORMS=cpu $(PY) scripts/status_smoke.py
 
+# the quickest proof that the served path runs on the chip: strict, needs
+# a TPU (exit 3 without one), one process per chip. Through the chip tool:
+# `chiprun -- python3 chip_smoke.py`
+smoke:
+	$(PY) chip_smoke.py
+
+# the same phases at toy sizes on XLA:CPU (the dry run before spending
+# chip time; 4 virtual devices so the mesh4 phase runs too)
+smoke-tiny:
+	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 $(PY) chip_smoke.py --tiny
+
 bench:
 	$(PY) bench.py
 
@@ -78,8 +89,10 @@ bench-packed:
 bench-ingest:
 	$(PY) bench_ingest.py --slo
 
-# cross-round perf-trend gate over the archived BENCH_r*/MULTICHIP_r*
-# artifacts: fails on a >10% regression against the best prior round
+# cross-round perf-trend gate over the archived BENCH_*_r*/MULTICHIP_r*
+# artifacts (all CPU-platform records today; a series with fewer than two
+# rounds has nothing to gate): fails on a >10% regression against the
+# best prior round
 trend:
 	$(PY) scripts/bench_trend.py
 

@@ -60,8 +60,8 @@ FLOAT_DTYPES = {
 HOST_SYNC_CALLS = {"jax.device_get", "np.asarray", "np.array",
                    "numpy.asarray", "numpy.array", "onp.asarray"}
 
-# spellings of shard_map at its call sites (tpu/sharded.py aliases the
-# experimental import and wraps it in a local compat shim)
+# spellings of shard_map at its call sites (tpu/sharded.py calls
+# jax.shard_map; the aliases cover the lint's own scratch fixtures)
 SHARD_MAP_CALLEES = {
     "shard_map", "_shard_map", "jax.shard_map",
     "jax.experimental.shard_map.shard_map", "_exp_shard_map",

@@ -83,10 +83,19 @@ class Core:
         if str(packed_voting) not in ("0", "1", "auto"):
             raise ValueError(f"unknown packed_voting mode: {packed_voting!r}")
         self.packed_voting = str(packed_voting)
+        # platform the device engines run on ({"platform", "kind",
+        # "count"}; None for the host backend), surfaced in /stats and the
+        # HealthDigest. Checked ONCE, here: a "tpu" node that lost the
+        # chip to another process must fail at start, not run XLA:CPU
+        # under the name "tpu" (tpu/runtime.py)
+        self.device: Optional[Dict[str, object]] = None
         if consensus_backend == "tpu":
             from ..tpu.packed import set_packed_mode
+            from ..tpu.runtime import enable_compile_cache, require_tpu
 
             set_packed_mode(self.packed_voting)
+            enable_compile_cache()
+            self.device = require_tpu()
         self._mesh = None  # built lazily on the first mesh-backend run
         self.device_consensus_runs = 0
         self.device_consensus_fallbacks = 0
@@ -97,6 +106,13 @@ class Core:
         # including post-fast-sync and deep-history restarts)
         self.live_demotions = 0
         self.live_reattaches = 0
+        # first attaches of the live / queued-mesh rung that failed on
+        # something other than GridUnsupported (a compile error, device
+        # memory): the ladder rides the one-shot rung either way, so this
+        # counter and one warning per error type are what make a rung
+        # that cannot start visible
+        self.device_attach_failures = 0
+        self._attach_errors_logged: set = set()
         self._consensus_calls = 0
         self._live_retry_at = 0  # next _consensus_calls value to retry at
         self._live_backoff = 1
@@ -413,6 +429,8 @@ class Core:
                         # below recomputes everything from the store
                         if attached:
                             self.live_demotions += 1
+                        else:
+                            self._note_attach_failure("queued mesh", e)
                         self._live_backoff = min(self._live_backoff * 2, 64)
                         self._live_retry_at = (
                             self._consensus_calls + self._live_backoff
@@ -495,6 +513,8 @@ class Core:
                             backoff=min(self._live_backoff * 2, 64),
                         )
                         self.hg.obs.flightrec.note_flap("demotion")
+                    else:
+                        self._note_attach_failure("live", e)
                     self._live_backoff = min(self._live_backoff * 2, 64)
                     self._live_retry_at = (
                         self._consensus_calls + self._live_backoff
@@ -529,6 +549,24 @@ class Core:
                 # range as consensus advances
                 self._mark_device_down("device consensus", e)
         self.hg.run_consensus()
+
+    def _note_attach_failure(self, rung: str, e: Exception) -> None:
+        """A rung whose FIRST attach failed. GridUnsupported is routine (a
+        state the rung does not model; it retries quietly); anything else
+        means the rung could not start at all — counted, and logged at
+        warning once per error type."""
+        from ..tpu.grid import GridUnsupported
+
+        if isinstance(e, GridUnsupported):
+            return
+        self.device_attach_failures += 1
+        kind = type(e).__name__
+        if kind not in self._attach_errors_logged:
+            self._attach_errors_logged.add(kind)
+            self.logger.warning(
+                "%s rung failed to attach (%s: %s); the one-shot device "
+                "path serves instead", rung, kind, e,
+            )
 
     def _mark_device_down(self, what: str, e: Exception) -> None:
         # info exactly once per up->down transition; retries that fail
@@ -762,7 +800,14 @@ class Core:
             "sigs": self.hg.pending_signatures(),
             "rung": self.ladder_rung(),
             "forks": int(getattr(self.hg, "fork_evidence", 0)),
+            **self.device_fields(),
         }
+
+    def device_fields(self) -> Dict[str, object]:
+        """device_platform / device_kind / device_count of a device-backed
+        core (empty for the host backend) — the /stats and HealthDigest
+        fields that say what "tpu" actually ran on."""
+        return {f"device_{k}": v for k, v in (self.device or {}).items()}
 
     def need_gossip(self) -> bool:
         return (

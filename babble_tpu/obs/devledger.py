@@ -13,7 +13,7 @@ per (rung, pass, layout, component) with component one of
     integrate host write-back of pass results
 
 — by wrapping every host call into a staged callable in a *seam*
-(`ledger_call` / `DeviceLedger.call`). The 23 `# kernel-contract:`
+(`ledger_call` / `DeviceLedger.call`). The 24 `# kernel-contract:`
 entry points (analysis/staged.py, PR 18) map onto seams via
 `ENTRY_INFO`: entries whose trace lives inside another staged body
 (e.g. `_divide_rounds` inside `consensus_pipeline`) carry a
@@ -107,6 +107,7 @@ ENTRY_INFO: Dict[str, Tuple[str, str, Optional[str]]] = {
     # tpu/live.py — packed result fetch program
     "_pack_results": ("live", "pack", None),
     # tpu/sharded.py — mesh-partitioned stages
+    "_fame_setup_staged": ("sharded", "fame_setup", None),
     "local_fame": ("sharded", "fame", None),
     "local_received": ("sharded", "received", None),
     "_fame_tables": ("sharded", "fame_tables", None),
@@ -154,17 +155,19 @@ def _on_jax_event(name: str, secs: float, **_kw) -> None:
 
 
 def _ensure_listener() -> None:
+    """Register the compile/trace listener, once per process, at the
+    first seam call — so a cpu-backend node, whose ledger never sees a
+    seam, never imports jax. A failed registration raises: a silent one
+    would make "retraces must stay 0" pass vacuously."""
     global _LISTENER_REGISTERED
     if _LISTENER_REGISTERED:
         return
     with _LISTENER_LOCK:
         if _LISTENER_REGISTERED:
             return
-        try:
-            from jax import monitoring
-            monitoring.register_event_duration_secs_listener(_on_jax_event)
-        except Exception:  # noqa: BLE001 — jax absent/old: counting degrades
-            pass
+        from jax import monitoring
+
+        monitoring.register_event_duration_secs_listener(_on_jax_event)
         _LISTENER_REGISTERED = True
 
 
@@ -231,6 +234,11 @@ def _sig_of(value) -> Any:
         return ("a", tuple(shape), str(getattr(value, "dtype", "?")))
     if isinstance(value, (int, float, bool, str, bytes, type(None))):
         return value
+    if isinstance(value, tuple):
+        # pytree arguments (IncState, Batch): their leaves' shapes ARE the
+        # signature — multi_step at K=4 and at K=16 differ only there, and
+        # naming both "Batch" booked the second compile as a retrace
+        return (type(value).__name__,) + tuple(_sig_of(v) for v in value)
     return type(value).__name__
 
 
@@ -313,7 +321,6 @@ class DeviceLedger:
             "Wall seconds of seam calls that compiled, per entry point",
             labels=("entry",),
         )
-        _ensure_listener()
 
     # -- clock policy ------------------------------------------------------
 
@@ -367,6 +374,7 @@ class DeviceLedger:
             rung = info[0] if info else "unknown"
             layout = "wide"
         sig = (layout,) + _abstract_sig(args, kwargs)
+        _ensure_listener()
         acc = _monitor_begin()
         t0 = self.now()
         try:

@@ -42,21 +42,6 @@ from .packed import (
     LANE, pack_bits, pack_votes_t, packed_tally, popcount_sum, resolve_packed,
 )
 
-# jax.shard_map is top-level only from jax 0.5; 0.4.x ships it under
-# experimental with the same signature, but its replication checker
-# predates lax.while_loop support ("No replication rule for while"), so
-# the fallback disables the check — out_specs still define the layout
-try:
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, *, mesh, in_specs, out_specs):
-        return _exp_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-
 # module-level jit so repeated pipeline runs reuse the compiled post-walk
 _frontier_post_jit = jax.jit(frontier_post)
 
@@ -271,7 +256,7 @@ def _fame_loop_fn(mesh: Mesh, axis: str, chunk: int, n_participants: int,
     # (wvalid_s aliases setup state shared with the received tables).
     # Platforms without donation (CPU test mesh) fall back to copies.
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             local_fame,
             mesh=mesh,
             in_specs=(rep, P(axis), shp2, votes_spec, shp2, shp2,
@@ -310,7 +295,7 @@ def _received_fn(mesh: Mesh, axis):
     shp = P(axis)
     rep = P()
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             local_received,
             mesh=mesh,
             in_specs=(shp, shp, shp, rep, rep, rep, rep),
@@ -335,6 +320,21 @@ def _fame_tables(wtable, la, decided, famous, last_round):
         wtable, la, decided, famous, rounds_decided, last_round
     )
     return min_la, famous_count, i_ok, horizon, rounds_decided
+
+
+# kernel-contract: _fame_setup_staged
+#   in: wtable:i32[2] la:i32[2] fd:i32[2] index:i32[1] coin_bit:bool[1]:wide
+#   static: super_majority
+#   rung: sharded
+#   out: ss/votes0/wvalid/coin_w (wide; the caller pads and packs)
+@functools.partial(jax.jit, static_argnames=("super_majority",))
+def _fame_setup_staged(wtable, la, fd, index, coin_bit, super_majority: int):
+    """kernels._fame_setup as ONE program. Run op by op, its
+    (R, N, N, N) ancestry compare is materialized before the count —
+    66 GB at N=1024, R=64, refused by a 16 GB chip (chip_smoke.py mesh4,
+    PR 21); staged, XLA fuses the compare into the reduction as it does
+    inside the single-device pipelines."""
+    return kernels._fame_setup(wtable, la, fd, index, coin_bit, super_majority)
 
 
 def _sharded_fame_received(
@@ -381,8 +381,9 @@ def _sharded_fame_received(
 
     putr = lambda x: jax.device_put(np.asarray(x), rep)
     wtable = putr(_pad_axis0(wtable_np, r_pad, -1))
-    ss, votes0, wvalid, coin_w = kernels._fame_setup(
-        wtable, la, fd, index, putr(grid.coin_bit), grid.super_majority
+    ss, votes0, wvalid, coin_w = ledger_call(
+        "_fame_setup_staged", _fame_setup_staged,
+        wtable, la, fd, index, putr(grid.coin_bit), grid.super_majority,
     )
     # witness-axis padding for the validator shards: padded columns are
     # never strongly seen (ss False) so their garbage vote rows tally 0,
@@ -517,7 +518,7 @@ def _sharded_build_inv_fn(mesh: Mesh, axis):
     from .frontier import build_inv
 
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             build_inv,
             mesh=mesh,
             in_specs=(P(axis, None), P()),
@@ -615,7 +616,7 @@ def _frontier_walk_fn(mesh: Mesh, axis, super_majority: int, r_cap: int,
         return x_hist_local  # (r_cap, B)
 
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             local_walk,
             mesh=mesh,
             in_specs=(P(axis, None, None), P(axis, None), P(), P(), P(axis)),

@@ -209,6 +209,10 @@ def main(argv=None):
 
     import jax
 
+    from babble_tpu.tpu.runtime import enable_compile_cache
+
+    enable_compile_cache()
+
     from babble_tpu.obs import Observability
     from babble_tpu.tpu.engine import run_frontier_passes
     from babble_tpu.tpu.grid import section_grid, synthetic_deep_grid

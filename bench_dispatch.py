@@ -101,6 +101,9 @@ def main(argv=None):
     from babble_tpu.tpu.dispatch import _AsyncPass
     from babble_tpu.tpu.grid import build_levels, synthetic_grid
     from babble_tpu.tpu.sharded import sharded_frontier_passes
+    from babble_tpu.tpu.runtime import enable_compile_cache
+
+    enable_compile_cache()
 
     devices = jax.devices()
     n_dev = 1

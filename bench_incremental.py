@@ -129,6 +129,9 @@ def main():
 
     from babble_tpu.tpu import synthetic_grid
     from babble_tpu.tpu.incremental import trains_from_grid
+    from babble_tpu.tpu.runtime import enable_compile_cache
+
+    enable_compile_cache()
 
     grid = synthetic_grid(
         N_VALIDATORS, N_EVENTS, seed=SEED, zipf_a=1.1, record_fd_updates=True

@@ -482,6 +482,10 @@ def main(argv=None):
 
     import jax
 
+    from babble_tpu.tpu.runtime import enable_compile_cache
+
+    enable_compile_cache()
+
     sweep = [int(x) for x in args.validators.split(",") if x.strip()]
     devices = jax.devices()
     mesh, n_dev, dv = build_mesh(devices, args.validator_shards)

@@ -169,9 +169,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def run_driver_like_subprocess(code, extra_env=None):
     """Run `code` in a subprocess whose environment mimics the driver:
     jax importable, JAX_PLATFORMS and XLA_FLAGS UNSET (conftest's pins
-    scrubbed), jax pre-imported before __graft_entry__ — the exact setup
-    under which MULTICHIP_r02 failed (module-level default-backend touch +
-    env-var-only pin arriving too late)."""
+    scrubbed), jax pre-imported before __graft_entry__ — the setup under
+    which an early multichip dry run died (module-level default-backend
+    touch + env-var-only pin arriving too late)."""
     env = {
         k: v
         for k, v in os.environ.items()
@@ -198,7 +198,7 @@ def test_tpu_import_initializes_no_backend():
     empty across import."""
     proc = run_driver_like_subprocess(
         """
-        import jax  # simulate sitecustomize pre-import
+        import jax  # a caller that imported jax first
         from jax._src import xla_bridge
         assert not xla_bridge.backends_are_initialized(), "pre-import dirty"
         import babble_tpu.tpu  # pulls grid, engine, kernels

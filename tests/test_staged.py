@@ -297,7 +297,7 @@ def test_every_engine_rung_carries_checked_contracts():
     assert {"one-shot", "frontier", "doubling", "sharded",
             "incremental", "live"} <= rungs
     by_name = {rec.name: c for _rel, rec, c in rows}
-    assert len(by_name) == 23
+    assert len(by_name) == 24
     duals = {
         name for name, c in by_name.items()
         if any(v.layout == "dual" for v in c.args.values())
