@@ -427,8 +427,14 @@ class Hashgraph:
         return writes
 
     def insert_event(self, event: Event, set_wire_info: bool) -> None:
+        # per-event work is timed into the tracer's totals only (`insert`,
+        # `insert.verify`, `insert.fd`): a ring span each would wrap the
+        # ring within one sync
+        now = self.obs.clock.monotonic
+        t_insert = now()
         if not event.verify():
             raise ValueError("Invalid Event signature")
+        t_verified = now()
 
         self._check_self_parent(event)
         self._check_other_parent(event)
@@ -441,7 +447,9 @@ class Hashgraph:
 
         self._init_event_coordinates(event)
         self.store.set_event(event)
+        t_fd = now()
         fd_writes = self._update_ancestor_first_descendant(event)
+        t_fd_done = now()
         if self.insert_listener is not None:
             self.insert_listener(event, fd_writes)
 
@@ -453,6 +461,10 @@ class Hashgraph:
         # now in the graph — the trace store looks them up by tx hash, so
         # no trace data touches the signed event bytes
         self.obs.traces.mark_event(event.transactions())
+        tracer = self.obs.tracer
+        tracer.add("insert.verify", t_verified - t_insert)
+        tracer.add("insert.fd", t_fd_done - t_fd)
+        tracer.add("insert", now() - t_insert)
 
     def _set_wire_info(self, event: Event) -> None:
         self_parent_index = -1
@@ -759,7 +771,17 @@ class Hashgraph:
 
     def process_decided_rounds(self) -> None:
         """Map decided rounds onto Frames and Blocks; commit through the
-        callback (reference: src/hashgraph/hashgraph.go:1041-1122).
+        callback (reference: src/hashgraph/hashgraph.go:1041-1122). Timed
+        here, not by the caller, so that every engine rung that runs the
+        host commit leaves the same span and histogram sample."""
+        with self.obs.span(
+            "consensus.process_decided_rounds",
+            histogram=self._pass_hist.labels(phase="process_decided_rounds"),
+        ):
+            self._process_decided_rounds()
+
+    def _process_decided_rounds(self) -> None:
+        """The commit loop of process_decided_rounds.
 
         Processing order is SORTED round order, not queue order, and any
         round at or below last_consensus_round is dropped as settled —
@@ -811,18 +833,24 @@ class Hashgraph:
                 frame = self.get_frame(pr.index)
 
                 if frame.events:
-                    for e in frame.events:
-                        self.store.add_consensus_event(e)
-                        self.consensus_transactions += len(e.transactions())
-                        if e.is_loaded():
-                            self.pending_loaded_events -= 1
+                    # the span's end is the commit instant
+                    with self.obs.span("commit.block", round=pr.index) as sp:
+                        txs = 0
+                        for e in frame.events:
+                            self.store.add_consensus_event(e)
+                            txs += len(e.transactions())
+                            if e.is_loaded():
+                                self.pending_loaded_events -= 1
+                        self.consensus_transactions += txs
 
-                    last_block_index = self.store.last_block_index()
-                    block = new_block_from_frame(last_block_index + 1, frame)
-                    self.check_block_immutable(block)
-                    self.store.set_block(block)
-                    if self.commit_callback is not None:
-                        self.commit_callback(block)
+                        last_block_index = self.store.last_block_index()
+                        block = new_block_from_frame(last_block_index + 1, frame)
+                        self.check_block_immutable(block)
+                        self.store.set_block(block)
+                        if self.commit_callback is not None:
+                            self.commit_callback(block)
+                        sp.attrs["index"] = block.index()
+                        sp.attrs["txs"] = txs
 
                 pos += 1
                 self._set_last_consensus_round(pr.index)
@@ -844,6 +872,12 @@ class Hashgraph:
             # store's LRU but still the only buildable copy of its round
             return rf
 
+        with self.obs.span("commit.frame", round=round_received) as sp:
+            return self._build_frame(round_received, sp.attrs)
+
+    def _build_frame(self, round_received: int, note: dict) -> Frame:
+        """Build and store the frame of a round the store does not hold;
+        `note` takes what the `commit.frame` span says of it."""
         round_info = self.store.get_round(round_received)
         events = [self.store.get_event(eh) for eh in round_info.consensus_events()]
         from .event import by_lamport_key
@@ -851,10 +885,12 @@ class Hashgraph:
         events.sort(key=by_lamport_key)
 
         roots: Dict[str, Root] = {}
+        created = 0
         for ev in events:
             p = ev.creator()
             if p not in roots:
                 roots[p] = self._create_root(ev)
+                created += 1
 
         # participants with no events in the frame: root from last consensus event
         for p in self.participants.to_pub_key_slice():
@@ -864,6 +900,7 @@ class Hashgraph:
                     root = self.store.get_root(p)
                 else:
                     root = self._create_root(self.store.get_event(last_consensus))
+                    created += 1
                 roots[p] = root
 
         # other-parents outside the frame must be reachable via Root.Others
@@ -881,6 +918,8 @@ class Hashgraph:
 
         res = Frame(round=round_received, roots=ordered_roots, events=events)
         self.store.set_frame(res)
+        note["events"] = len(events)
+        note["roots_created"] = created
         return res
 
     # ECDSA verifications per process_sig_pool pass. The pass runs under
@@ -920,6 +959,15 @@ class Hashgraph:
     def process_sig_pool(self) -> None:
         """Attach valid signatures to blocks; advance the anchor block once a
         block has >1/3 signatures (reference: src/hashgraph/hashgraph.go:1236-1300).
+        Timed here, not by the caller, like process_decided_rounds."""
+        with self.obs.span(
+            "consensus.process_sig_pool",
+            histogram=self._pass_hist.labels(phase="process_sig_pool"),
+        ):
+            self._process_sig_pool()
+
+    def _process_sig_pool(self) -> None:
+        """The pass of process_sig_pool.
 
         The pool discipline is deliberately tighter than the reference,
         which keeps every unprocessed signature in one flat list and
@@ -1073,22 +1121,18 @@ class Hashgraph:
         injected clock, not perf_counter, so the per-pass histograms are
         byte-deterministic under the simulator's virtual time (where
         every pass reads as zero-cost, which is exactly the sim's model)."""
-        clock = self.obs.clock
         for name, phase, pass_ in (
             ("DivideRounds", "divide_rounds", self.divide_rounds),
             ("DecideFame", "decide_fame", self.decide_fame),
             ("DecideRoundReceived", "decide_round_received",
              self.decide_round_received),
-            ("ProcessDecidedRounds", "process_decided_rounds",
-             self.process_decided_rounds),
-            ("ProcessSigPool", "process_sig_pool", self.process_sig_pool),
         ):
-            start = clock.monotonic()
-            pass_()
-            dur = clock.monotonic() - start
-            self._pass_hist.labels(phase=phase).observe(dur)
-            self.obs.tracer.record("consensus." + phase, start, dur)  # obs-ok: phases are the literal tuple above
-            self.logger.debug("%s() duration=%dns", name, int(dur * 1e9))
+            with self.obs.span("consensus." + phase, histogram=self._pass_hist.labels(phase=phase)) as sp:  # obs-ok: phases are the literal tuple above
+                pass_()
+            self.logger.debug("%s() duration=%dns", name, int(sp.duration * 1e9))
+        # the last two passes time themselves: the device rungs call them too
+        self.process_decided_rounds()
+        self.process_sig_pool()
 
     # ------------------------------------------------------------------
     # anchor / reset / bootstrap (reference: src/hashgraph/hashgraph.go:1302-1410)

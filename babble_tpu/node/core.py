@@ -91,10 +91,15 @@ class Core:
         self.device: Optional[Dict[str, object]] = None
         if consensus_backend == "tpu":
             from ..tpu.packed import set_packed_mode
-            from ..tpu.runtime import enable_compile_cache, require_tpu
+            from ..tpu.runtime import (
+                annotate_spans,
+                enable_compile_cache,
+                require_tpu,
+            )
 
             set_packed_mode(self.packed_voting)
             enable_compile_cache()
+            annotate_spans()
             self.device = require_tpu()
         self._mesh = None  # built lazily on the first mesh-backend run
         self.device_consensus_runs = 0
@@ -369,15 +374,32 @@ class Core:
     # -- consensus ---------------------------------------------------------
 
     def run_consensus(self) -> None:
-        """Five-pass pipeline through the configured backend. The device
-        path covers passes 1-3 (grid extraction + fused XLA pipeline) and
-        falls back to the host engine on any state the dense grid cannot
-        express (reference boundary: src/node/core.go:335-377)."""
+        """Five-pass pipeline through the configured backend, as the root
+        of the call's span tree: `core.run_consensus` is the parent of
+        every span the ladder below opens, and the tracer's totals are
+        checkpointed on entry and on return, so that a reader can take
+        any window of whole calls afterwards (`totals_between`)."""
+        obs = self.hg.obs
+        self._consensus_calls += 1
+        obs.tracer.checkpoint()
+        try:
+            with obs.span("core.run_consensus",
+                          call=self._consensus_calls) as sp:
+                try:
+                    self._run_ladder()
+                finally:
+                    sp.attrs["rung"] = self.ladder_rung()
+        finally:
+            obs.tracer.checkpoint()
+
+    def _run_ladder(self) -> None:
+        """The device path covers passes 1-3 (grid extraction + fused XLA
+        pipeline) and falls back to the host engine on any state the dense
+        grid cannot express (reference boundary: src/node/core.go:335-377)."""
         if self.consensus_backend == "tpu":
             from ..tpu.engine import run_consensus_device
             from ..tpu.grid import GridUnsupported
 
-            self._consensus_calls += 1
             if self._device_down and self._consensus_calls < self._device_retry_at:
                 # down, but healing: CPU serves until the next retry slot
                 self.hg.run_consensus()

@@ -18,6 +18,7 @@ names and undeclared label sets at lint time.
 
 from __future__ import annotations
 
+import weakref
 from typing import Optional, Sequence
 
 from ..common.clock import Clock, SYSTEM_CLOCK
@@ -61,7 +62,7 @@ from .provenance import (
     capture_pass_results,
     run_bisector_smoke,
 )
-from .trace import DEFAULT_SPAN_CAPACITY, Span, SpanTracer
+from .trace import DEFAULT_SPAN_CAPACITY, Span, SpanTracer, live_tracers
 from .slo import SLObjective, SLOEngine
 from .tracectx import (
     DEFAULT_TRACE_CAPACITY,
@@ -103,6 +104,7 @@ __all__ = [
     "Histogram",
     "SpanTracer",
     "Span",
+    "live_tracers",
     "TraceContext",
     "TraceStore",
     "assemble_cluster_trace",
@@ -155,6 +157,7 @@ class Observability:
         # ring behind GET /debug/timeline — durations follow the clock
         # policy (real SystemClock only; the sim records exact zeros)
         self.devledger = DeviceLedger(self)
+        self.tracer.ledger = weakref.ref(self.devledger)  # for span(..., ledger=cell)
         # cluster health plane (ISSUE 20): federates piggybacked peer
         # HealthDigests into derived cluster series, a queryable fleet
         # table, and staleness-asymmetry partition inference; dormant
@@ -177,7 +180,9 @@ class Observability:
                   labels: Sequence[str] = (), buckets=None) -> Histogram:
         return self.registry.histogram(name, help_text, labels, buckets=buckets)  # obs-ok: delegate, name checked at call sites
 
-    def span(self, name: str, histogram=None, **attrs):
-        """Context manager timing a block into the span ring (and an
-        optional histogram) via the injected clock."""
-        return self.tracer.span(name, histogram=histogram, **attrs)  # obs-ok: delegate, name checked at call sites
+    def span(self, name: str, histogram=None, ledger=None, **attrs):
+        """Context manager timing a block into the span ring, the
+        tracer's totals and, from the same clock-read pair, an optional
+        histogram and an optional device-ledger cell
+        `ledger=(rung, component, layout)`. The one way to open a span."""
+        return self.tracer.span(name, histogram=histogram, ledger=ledger, **attrs)  # obs-ok: delegate, name checked at call sites

@@ -122,6 +122,7 @@ class Batch(NamedTuple):
 D_UNROLL = 8
 
 
+@jax.named_scope("live.fame")
 def _fame_window(w_valid, la_w, fd_w, idx_w, coin_w, last_round_rel,
                  super_majority: int, n_participants: int,
                  packed: bool = False):
@@ -210,6 +211,7 @@ def _fame_window(w_valid, la_w, fd_w, idx_w, coin_w, last_round_rel,
     return decided, famous, rounds_decided, overflow
 
 
+@jax.named_scope("live.deltas")
 def _apply_deltas_and_stage(state: IncState, b):
     """Shared front half of the per-batch and train bodies (`b` is a Batch
     or a Train — same field names):
@@ -320,8 +322,9 @@ def _step_body(
 
     carry = (state.rounds, state.lamport, state.witness, state.wtable,
              state.w_of_row, state.la_w, fd_w, state.idx_w, state.coin_w)
-    for i in range(batch.levels.shape[0]):
-        carry = level_step(i, carry)
+    with jax.named_scope("live.levels"):
+        for i in range(batch.levels.shape[0]):
+            carry = level_step(i, carry)
     (rounds, lamport, witness, wtable, w_of_row, la_w, fd_w, idx_w,
      coin_w) = carry
     last_round = jnp.maximum(state.last_round, jnp.max(rounds))
@@ -409,46 +412,47 @@ def _decide_body(
     famous = jax.lax.dynamic_update_slice(state.famous, fam_w, (floor, 0))
     rounds_decided = jax.lax.dynamic_update_slice(state.rounds_decided, rdec_w, (floor,))
 
-    # round-received for the trailing row window (undetermined rows are
-    # always among the most recent)
-    is_famous = fame_decided & famous & (wtable >= 0)  # (R, N)
-    famous_count = jnp.sum(is_famous, axis=1)
-    # min over famous witnesses of lastAnc[w][c], from the dense buffer
-    min_la = jnp.min(
-        jnp.where(is_famous[:, :, None], la_w, MAX_INT32), axis=1
-    )  # (R, N_c)
-    i_ok = rounds_decided & (r_idx <= last_round)
-    bad = jnp.where(~i_ok, r_idx, r_cap)
-    horizon = suffix_min(bad, r_cap)
+    with jax.named_scope("live.received"):
+        # round-received for the trailing row window (undetermined rows are
+        # always among the most recent)
+        is_famous = fame_decided & famous & (wtable >= 0)  # (R, N)
+        famous_count = jnp.sum(is_famous, axis=1)
+        # min over famous witnesses of lastAnc[w][c], from the dense buffer
+        min_la = jnp.min(
+            jnp.where(is_famous[:, :, None], la_w, MAX_INT32), axis=1
+        )  # (R, N_c)
+        i_ok = rounds_decided & (r_idx <= last_round)
+        bad = jnp.where(~i_ok, r_idx, r_cap)
+        horizon = suffix_min(bad, r_cap)
 
-    lo = jnp.clip(state.count - e_win, 0, e_cap - e_win)
-    idx_e = jax.lax.dynamic_slice(index, (lo,), (e_win,))
-    cre_e = jax.lax.dynamic_slice(creator, (lo,), (e_win,))
-    rnd_e = jax.lax.dynamic_slice(rounds, (lo,), (e_win,))
+        lo = jnp.clip(state.count - e_win, 0, e_cap - e_win)
+        idx_e = jax.lax.dynamic_slice(index, (lo,), (e_win,))
+        cre_e = jax.lax.dynamic_slice(creator, (lo,), (e_win,))
+        rnd_e = jax.lax.dynamic_slice(rounds, (lo,), (e_win,))
 
-    # creator -> min_la column and rounds+1 -> horizon entry, as one-hot
-    # MXU matmuls. Precision HIGHEST is load-bearing: TPU matmuls default
-    # to bf16 inputs and min_la carries event indices (up to 2^24) that
-    # bf16 cannot represent — a rounded threshold flips seen/not-seen
-    onehot_c = (cre_e[:, None] == jnp.arange(n)[None, :]).astype(jnp.float32)
-    seen_min = jnp.matmul(
-        onehot_c,
-        jnp.minimum(min_la, jnp.int32(1 << 24)).astype(jnp.float32).T,
-        precision=jax.lax.Precision.HIGHEST,
-    ).astype(jnp.int32)  # (e_win, R)
-    start = jnp.clip(rnd_e + 1, 0, r_cap - 1)
-    onehot_r = (start[:, None] == r_idx[None, :]).astype(jnp.float32)
-    horizon_start = jnp.matmul(
-        onehot_r,
-        jnp.minimum(horizon, r_cap).astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST,
-    ).astype(jnp.int32)  # (e_win,)
+        # creator -> min_la column and rounds+1 -> horizon entry, as one-hot
+        # MXU matmuls. Precision HIGHEST is load-bearing: TPU matmuls default
+        # to bf16 inputs and min_la carries event indices (up to 2^24) that
+        # bf16 cannot represent — a rounded threshold flips seen/not-seen
+        onehot_c = (cre_e[:, None] == jnp.arange(n)[None, :]).astype(jnp.float32)
+        seen_min = jnp.matmul(
+            onehot_c,
+            jnp.minimum(min_la, jnp.int32(1 << 24)).astype(jnp.float32).T,
+            precision=jax.lax.Precision.HIGHEST,
+        ).astype(jnp.int32)  # (e_win, R)
+        start = jnp.clip(rnd_e + 1, 0, r_cap - 1)
+        onehot_r = (start[:, None] == r_idx[None, :]).astype(jnp.float32)
+        horizon_start = jnp.matmul(
+            onehot_r,
+            jnp.minimum(horizon, r_cap).astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        ).astype(jnp.int32)  # (e_win,)
 
-    rec_e = received_core(idx_e, rnd_e, seen_min, famous_count, i_ok, horizon_start)
-    old_e = jax.lax.dynamic_slice(state.received, (lo,), (e_win,))
-    occ_e = idx_e != MAX_INT32
-    new_e = jnp.where((old_e < 0) & occ_e, rec_e, old_e)
-    received = jax.lax.dynamic_update_slice(state.received, new_e, (lo,))
+        rec_e = received_core(idx_e, rnd_e, seen_min, famous_count, i_ok, horizon_start)
+        old_e = jax.lax.dynamic_slice(state.received, (lo,), (e_win,))
+        occ_e = idx_e != MAX_INT32
+        new_e = jnp.where((old_e < 0) & occ_e, rec_e, old_e)
+        received = jax.lax.dynamic_update_slice(state.received, new_e, (lo,))
 
     # window-miss detector: an undetermined occupied row below the window
     # can never be decided again — latch it

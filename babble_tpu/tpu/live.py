@@ -136,6 +136,11 @@ class LiveDeviceEngine:
         # the pipelining heuristic reads them per-engine.
         self.dispatches = 0
         self.consensus_calls = 0
+        # run_consensus_live calls and dispatches so far: the `dispatch`
+        # attribute ties one dispatch's launch, fetch and integration,
+        # which the pipelined discipline spreads over several calls
+        self.calls = 0
+        self.dispatch_seq = 0
         self._m_dispatch = hg.obs.histogram(
             "babble_device_dispatch_seconds",
             "Host-side device program launch time per advance",
@@ -147,6 +152,11 @@ class LiveDeviceEngine:
         self._m_rebase = hg.obs.counter(
             "babble_device_rebases_total",
             "Live-engine grid rebases onto a committed frontier",
+        )
+        self._m_host_repair = hg.obs.counter(
+            "babble_live_host_repaired_integrations_total",
+            "Integrations whose fetched receptions the host rule refused, "
+            "so that the host's reception pass ran in the device's place",
         )
         # pipelined-fetch discipline: flips on when the measured blocking
         # fetch is consistently expensive (ASYNC_FETCH_MIN_S). inflight is
@@ -175,6 +185,7 @@ class LiveDeviceEngine:
             "gossip (1.0 = the fetch never blocked the serve path)",
             buckets=[i / 10 for i in range(11)],
         )
+        self.layout = "packed" if self.packed else "wide"  # ledger cells
         self.state: IncState = init_state(self.n, self.e_cap, self.r_cap)
         self.row_of: Dict[str, int] = {}
         self.hashes: List[str] = []
@@ -525,8 +536,8 @@ class LiveDeviceEngine:
             rounds_decided[sh] = ri.witnesses_decided()
 
         import jax
-        import jax.numpy as jnp
 
+        # NumPy scalars: jnp.int32(x) would be a device program each
         self.state = IncState(
             la=jax.device_put(la), fd=jax.device_put(fd),
             creator=jax.device_put(creator), index=jax.device_put(index),
@@ -538,9 +549,10 @@ class LiveDeviceEngine:
             fame_decided=jax.device_put(fame_decided),
             famous=jax.device_put(famous),
             rounds_decided=jax.device_put(rounds_decided),
-            last_round=jnp.int32(last_abs - base),
-            count=jnp.int32(len(kept)),
-            stale=jnp.bool_(False), fame_lag=jnp.bool_(False),
+            last_round=jax.device_put(np.int32(last_abs - base)),
+            count=jax.device_put(np.int32(len(kept))),
+            stale=jax.device_put(np.bool_(False)),
+            fame_lag=jax.device_put(np.bool_(False)),
         )
         self.row_of = new_row_of
         self.hashes = new_hashes
@@ -560,60 +572,59 @@ class LiveDeviceEngine:
         live path compiles at most three programs."""
         if not self.pending:
             return []
-        clock = self.hg.obs.clock
-        t0 = clock.monotonic()
-        drained, self.pending = self.pending, []
-        new_rows: List[int] = []
-        if len(self.hashes) + len(drained) > self.e_cap:
-            raise GridUnsupported("device event capacity exhausted")
+        obs = self.hg.obs
+        with obs.span("device.dispatch", histogram=self._m_dispatch,
+                      node=obs.node_id, dispatch=self.dispatch_seq + 1) as sp:
+            with obs.span("live.stage",
+                          ledger=("live", "stage", self.layout)) as stage:
+                drained, self.pending = self.pending, []
+                stage.attrs["events"] = len(drained)
+                stage.attrs["fd_updates"] = sum(len(w) for _, w in drained)
+                new_rows: List[int] = []
+                if len(self.hashes) + len(drained) > self.e_cap:
+                    raise GridUnsupported("device event capacity exhausted")
 
-        # greedy chunking: cap both the batch size and the within-batch
-        # dependency depth (a creator chaining deeply in one sync would
-        # otherwise exceed the level table — split instead of failing)
-        built: List[Batch] = []
-        pos = 0
-        while pos < len(drained):
-            chunk = drained[pos : pos + self.batch_cap]
-            chunk = self._depth_cut(chunk)
-            pos += len(chunk)
-            batch, rows = self._build_batch(chunk)
-            built.append(batch)
-            new_rows.extend(rows)
+                # greedy chunking: cap both the batch size and the
+                # within-batch dependency depth (a creator chaining deeply
+                # in one sync would otherwise exceed the level table)
+                built: List[Batch] = []
+                pos = 0
+                while pos < len(drained):
+                    chunk = drained[pos : pos + self.batch_cap]
+                    chunk = self._depth_cut(chunk)
+                    pos += len(chunk)
+                    batch, rows = self._build_batch(chunk)
+                    built.append(batch)
+                    new_rows.extend(rows)
+                sp.attrs["batches"] = len(built)
+                # trains: up to 16 batches, padded to K=4 or K=16, stacked
+                trains = []
+                if len(built) > 2:
+                    for i in range(0, len(built), 16):
+                        group = built[i : i + 16]
+                        k = 4 if len(group) <= 4 else 16
+                        group = group + [self._empty_batch()] * (k - len(group))
+                        trains.append((k, stack_batches(group)))
 
-        led = self.hg.obs.devledger
-        layout = "packed" if self.packed else "wide"
-        # batch building is the live rung's host staging work; the step/
-        # multi_step launches below are attributed by their own seams
-        led.component("live", "stage", clock.monotonic() - t0, layout=layout)
-        with led.activate("live", layout=layout):
-            if len(built) <= 2:
-                for b in built:
-                    self.state = ledger_call(
-                        "_step_full", step,
-                        self.state, b, self.hg.super_majority, self.n,
-                        e_win=self.e_win, r_win=self.r_win,
-                        packed=self.packed,
-                    )
+            with obs.devledger.activate("live", layout=self.layout):
+                for b in ([] if trains else built):
+                    with obs.span("live.launch", program="step", k=1):
+                        self.state = ledger_call(
+                            "_step_full", step,
+                            self.state, b, self.hg.super_majority, self.n,
+                            e_win=self.e_win, r_win=self.r_win,
+                            packed=self.packed,
+                        )
                     self.dispatches += 1
-            else:
-                for i in range(0, len(built), 16):
-                    group = built[i : i + 16]
-                    k = 4 if len(group) <= 4 else 16
-                    group = group + [self._empty_batch()] * (k - len(group))
-                    self.state = ledger_call(
-                        "multi_step", multi_step,
-                        self.state, stack_batches(group),
-                        self.hg.super_majority, self.n, e_win=self.e_win,
-                        r_win=self.r_win, packed=self.packed,
-                    )
+                for k, stacked in trains:
+                    with obs.span("live.launch", program="multi_step", k=k):
+                        self.state = ledger_call(
+                            "multi_step", multi_step,
+                            self.state, stacked,
+                            self.hg.super_majority, self.n, e_win=self.e_win,
+                            r_win=self.r_win, packed=self.packed,
+                        )
                     self.dispatches += 1
-        dt = clock.monotonic() - t0
-        led.component("live", "stage", dt, layout=layout)
-        self._m_dispatch.observe(dt)
-        self.hg.obs.tracer.record(
-            "device.dispatch", t0, dt,
-            {"node": self.hg.obs.node_id, "batches": len(built)},
-        )
         return new_rows
 
     def _empty_batch(self) -> Batch:
@@ -753,10 +764,6 @@ import jax
 import jax.numpy as jnp
 
 
-def jnp_int32(x):
-    return jnp.int32(x)
-
-
 # kernel-contract: _pack_results
 #   in: st:pytree lo:i32[0]
 #   static: e_win r_cap n
@@ -767,15 +774,16 @@ def _pack_results(st: IncState, lo, e_win: int, r_cap: int, n: int):
     """Flatten everything the host write-back reads into ONE int32 vector
     (a single transfer instead of nine round trips)."""
     sl = lambda a: jax.lax.dynamic_slice(a, (lo,), (e_win,)).astype(jnp.int32)
-    return jnp.concatenate([
-        sl(st.rounds), sl(st.lamport),
-        sl(st.witness.astype(jnp.int32)), sl(st.received),
-        st.wtable.reshape(-1),
-        st.fame_decided.astype(jnp.int32).reshape(-1),
-        st.famous.astype(jnp.int32).reshape(-1),
-        jnp.stack([st.stale.astype(jnp.int32), st.fame_lag.astype(jnp.int32),
-                   st.last_round]),
-    ])
+    with jax.named_scope("live.pack"):
+        return jnp.concatenate([
+            sl(st.rounds), sl(st.lamport),
+            sl(st.witness.astype(jnp.int32)), sl(st.received),
+            st.wtable.reshape(-1),
+            st.fame_decided.astype(jnp.int32).reshape(-1),
+            st.famous.astype(jnp.int32).reshape(-1),
+            jnp.stack([st.stale.astype(jnp.int32),
+                       st.fame_lag.astype(jnp.int32), st.last_round]),
+        ])
 
 
 def _unpack_results(packed, e_win: int, r_cap: int, n: int):
@@ -839,6 +847,7 @@ def run_consensus_live(hg, queue_depth: int = None,
             batch_cap=batch_cap,
         )
         hg._live_device_engine = eng
+        eng.calls = 1
         # the bootstrap replayed the whole pre-existing DAG on device; its
         # rows still need the host write-back — the attach call is always
         # synchronous so the node leaves it with a fully written store
@@ -846,6 +855,7 @@ def run_consensus_live(hg, queue_depth: int = None,
         new_rows.extend(eng.advance())
         _run_sync(hg, eng, new_rows)
         return
+    eng.calls += 1
     if eng.async_fetch:
         _run_pipelined(hg, eng)
     else:
@@ -903,6 +913,8 @@ def _snapshot(eng: LiveDeviceEngine, new_rows: List[int]) -> dict:
     (ADVICE r4)."""
     count = len(eng.hashes)
     return dict(
+        dispatch=eng.dispatch_seq,
+        call=eng.calls,
         new_rows=new_rows,
         hashes=eng.hashes,
         row_of=eng.row_of,
@@ -916,33 +928,42 @@ def _snapshot(eng: LiveDeviceEngine, new_rows: List[int]) -> dict:
 def _dispatch(eng: LiveDeviceEngine, new_rows: List[int]):
     """Launch the packed-results program for the current device state.
     Returns (device_array, snapshot); does NOT block on the transfer."""
-    snap = _snapshot(eng, new_rows)
-    with eng.hg.obs.devledger.activate(
-        "live", layout="packed" if eng.packed else "wide",
-    ):
-        packed = ledger_call(
-            "_pack_results", _pack_results,
-            eng.state, jnp_int32(snap["lo"]), eng.e_win, eng.r_cap, eng.n,
-        )
+    eng.dispatch_seq += 1
+    obs = eng.hg.obs
+    with obs.span("live.pack", dispatch=eng.dispatch_seq):
+        snap = _snapshot(eng, new_rows)
+        with obs.devledger.activate("live", layout=eng.layout):
+            # a NumPy scalar: jnp.int32(lo) would be a device program of its own
+            packed = ledger_call(
+                "_pack_results", _pack_results,
+                eng.state, np.int32(snap["lo"]), eng.e_win, eng.r_cap, eng.n,
+            )
     return packed, snap
+
+
+def _fetch(hg, eng: LiveDeviceEngine, snap: dict, wait, discipline: str):
+    """Block on one dispatch's packed results (`wait()` returns them): one
+    reading is the `device.fetch` span, the babble_device_fetch_seconds
+    sample and the ledger cell live/fetch. Returns (packed, the span)."""
+    with hg.obs.span(
+        "device.fetch", histogram=eng._m_fetch,
+        ledger=("live", "fetch", eng.layout), node=hg.obs.node_id,
+        dispatch=snap["dispatch"], discipline=discipline,
+        lag_calls=eng.calls - snap["call"],
+    ) as sp:
+        packed = wait()
+    eng.consensus_calls += 1
+    return packed, sp
 
 
 def _run_sync(hg, eng: LiveDeviceEngine, new_rows: List[int]) -> None:
     """Dispatch + blocking fetch + integrate, all under the caller's core
     lock (the original discipline)."""
-    clock = hg.obs.clock
     packed_dev, snap = _dispatch(eng, new_rows)
-    t0 = clock.monotonic()
-    packed = jax.device_get(packed_dev)
-    dt = clock.monotonic() - t0
-    eng._m_fetch.observe(dt)
-    hg.obs.devledger.component(
-        "live", "fetch", dt, layout="packed" if eng.packed else "wide",
+    packed, fetched = _fetch(
+        hg, eng, snap, lambda: jax.device_get(packed_dev), "sync",
     )
-    hg.obs.tracer.record(
-        "device.fetch", t0, dt, {"node": hg.obs.node_id},
-    )
-    eng.consensus_calls += 1
+    dt = fetched.duration
 
     last_round_rel = _integrate(hg, eng, packed, snap)
     hg.process_decided_rounds()
@@ -967,24 +988,15 @@ def _integrate_oldest(hg, eng: LiveDeviceEngine) -> int:
     rounds land before children's). Blocks only if the background reader
     has not finished; the blocked fraction of the dispatch's in-flight
     wall time feeds the overlap-utilization histogram."""
-    clock = hg.obs.clock
     fetch, snap, t_disp = eng.inflight.pop(0)
-    t0 = clock.monotonic()
-    packed = fetch.result()  # normally already resident
-    dt = clock.monotonic() - t0
-    eng._m_fetch.observe(dt)
-    hg.obs.devledger.component(
-        "live", "fetch", dt, layout="packed" if eng.packed else "wide",
-    )
-    in_flight = max(t0 + dt - t_disp, 1e-9)
+    # normally already resident
+    packed, fetched = _fetch(hg, eng, snap, fetch.result, "pipelined")
+    dt = fetched.duration
+    in_flight = max(fetched.start + dt - t_disp, 1e-9)
     eng._m_overlap.observe(max(0.0, min(1.0, 1.0 - dt / in_flight)))
-    hg.obs.tracer.record(
-        "device.fetch", t0, dt, {"node": hg.obs.node_id},
-    )
     hg.obs.flightrec.record(
         "live.integrate", blocked=dt, depth=len(eng.inflight),
     )
-    eng.consensus_calls += 1
     return _integrate(hg, eng, packed, snap)
 
 
@@ -1062,7 +1074,16 @@ def _run_pipelined(hg, eng: LiveDeviceEngine) -> None:
 
 
 def _integrate(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
-    """Write one dispatch's results into the host hashgraph, behind the
+    """_write_back as the `live.integrate` span and ledger cell."""
+    with hg.obs.span(
+        "live.integrate", ledger=("live", "integrate", eng.layout),
+        dispatch=snap["dispatch"], rows=len(snap["new_rows"]),
+    ):
+        return _write_back(hg, eng, packed, snap)
+
+
+def _write_back(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
+    """One dispatch's results into the host hashgraph, behind the
     same validation gates as the one-shot engine. Returns the dispatch's
     last_round (base-relative) for capacity management.
 
@@ -1073,8 +1094,6 @@ def _integrate(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
     from ..common import StoreErr, StoreErrType, is_store_err
     from ..hashgraph import PendingRound, RoundInfo
 
-    _led = hg.obs.devledger
-    _ti0 = _led.now()
     count, lo, base = snap["count"], snap["lo"], snap["base"]
     if base != eng.round_base:
         # rebases are ordered strictly between integrations; a mismatch
@@ -1243,7 +1262,11 @@ def _integrate(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
                 yield h, rr + base
 
     if not delegated:
-        if admissible_receptions(hg, round_infos, _proposed_receptions()):
+        with hg.obs.span("live.admissible") as sp:
+            proposed = list(_proposed_receptions())
+            sp.attrs["proposed"] = len(proposed)
+            admissible = admissible_receptions(hg, round_infos, proposed)
+        if admissible:
             new_undetermined = []
             for h in hg.undetermined_events:
                 row = _covered(h)
@@ -1270,16 +1293,14 @@ def _integrate(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
             # (frozen/missing rounds): persist the fame state and run the
             # HOST's reception pass this call — exact host timing, so
             # block composition cannot skew (engine.admissible_receptions)
-            for rnum, ri in round_infos.items():
-                hg.store.set_round(rnum, ri)
-            hg.decide_round_received()
+            eng._m_host_repair.inc()
+            with hg.obs.span("live.host_repair"):
+                for rnum, ri in round_infos.items():
+                    hg.store.set_round(rnum, ri)
+                hg.decide_round_received()
 
     if prov_cells:
         prov.mark("prov.capture", engine="live", cells=prov_cells)
-    _led.component(
-        "live", "integrate", _led.now() - _ti0,
-        layout="packed" if eng.packed else "wide",
-    )
     return last_round_rel
 
 
@@ -1309,7 +1330,9 @@ def _manage_capacity(eng: LiveDeviceEngine, last_round_rel: int) -> None:
     )
     if _capacity_soft(eng, last_round_rel):
         try:
-            eng.rebase()
+            with eng.hg.obs.span("live.rebase") as sp:
+                eng.rebase()
+                sp.attrs["base"] = eng.round_base
         except GridUnsupported:
             if hard:
                 raise

@@ -4,7 +4,8 @@ programs are cached, and which platform the engines really run on.
 Both are decided once, where a device backend is first chosen
 (`node.Core` for consensus_backend="tpu", chip_smoke.py, the bench
 mains), never at import: nothing here runs at module load, and
-`enable_compile_cache` initializes no backend.
+`enable_compile_cache` initializes no backend. The same place hands the
+program's spans to the profiler (`annotate_spans`).
 
 One process holds a chip. JAX registers its TPU client to fail quietly,
 so a process that asks for the chip and loses it would otherwise carry
@@ -41,6 +42,20 @@ def enable_compile_cache() -> str:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
+
+
+def annotate_spans() -> None:
+    """Put the program's spans on the device trace's clock: from here on
+    every `obs.span(...)` also opens a `jax.profiler.TraceAnnotation`
+    named "babble.<span>", so that a `jax.profiler` trace shows them in
+    its host plane beside the device's operations. With no profiler
+    session an annotation is a flag test. Process-wide, like the
+    profiler; the tracer itself (obs/trace.py) never imports jax."""
+    import jax
+
+    from ..obs.trace import SpanTracer
+
+    SpanTracer.annotator = jax.profiler.TraceAnnotation
 
 
 def device_info() -> Dict[str, object]:

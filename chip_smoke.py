@@ -332,12 +332,13 @@ def warm_live_programs(n: int, node_conf, seed: int) -> Dict[str, object]:
     program is timed twice around block_until_ready: first call (compile
     + run) and a steady call."""
     import jax
+    import numpy as np
 
     from babble_tpu.hashgraph import Hashgraph, InmemStore
     from babble_tpu.peers import Peer, Peers
     from babble_tpu.tpu.incremental import multi_step, stack_batches, step
     from babble_tpu.tpu.live import (
-        LiveDeviceEngine, _pack_results, jnp_int32,
+        LiveDeviceEngine, _pack_results,
     )
 
     peers = Peers.from_slice([
@@ -369,7 +370,7 @@ def warm_live_programs(n: int, node_conf, seed: int) -> Dict[str, object]:
             "multi_step", multi_step, stack_batches([empty] * 16)),
         "pack_results": lambda: led.call(
             "_pack_results", _pack_results,
-            eng.state, jnp_int32(0), eng.e_win, eng.r_cap, eng.n),
+            eng.state, np.int32(0), eng.e_win, eng.r_cap, eng.n),
     }
     out: Dict[str, object] = {}
     for name, run in programs.items():

@@ -1,0 +1,265 @@
+"""The span tree of one consensus call on the live device rung (XLA:CPU
+here): a small seeded stream handed sync by sync to an observer
+`Core("tpu")`, under the synchronous and the pipelined fetch discipline.
+
+What is held: every live/device/commit span hangs under a
+`core.run_consensus` root (but for the flush's), the `dispatch` id ties
+one dispatch's launch, fetch and integration across calls, the totals
+count what the program did (events inserted, blocks committed), one
+interval is booked once, and a reception the host rule refuses is counted.
+"""
+
+import pytest
+
+from babble_tpu.crypto import derive_key, pub_key_bytes
+from babble_tpu.hashgraph import Event, InmemStore, root_self_parent
+from babble_tpu.node import Core
+from babble_tpu.peers import Peer, Peers
+from babble_tpu.tpu import live as live_mod
+from babble_tpu.tpu.grid import synthetic_grid
+
+N, EVENTS, SYNC, SEED = 4, 400, 40, 23
+
+
+def signed_stream():
+    """(peers, observer key, signed events in creation order)."""
+    grid = synthetic_grid(N, EVENTS, seed=SEED)
+    by_pub = {}
+    for i in range(N):
+        key = derive_key(SEED * 1009 + i)
+        by_pub["0x" + pub_key_bytes(key).hex().upper()] = key
+    peers = Peers.from_slice([Peer(net_addr="", pub_key_hex=h) for h in by_pub])
+    plist = peers.to_peer_slice()  # creator positions index the sorted slice
+    keys = [by_pub[p.pub_key_hex] for p in plist]
+    signed = []
+    for i in range(grid.e):
+        c = int(grid.creator[i])
+        sp, op = int(grid.self_parent[i]), int(grid.other_parent[i])
+        ev = Event(
+            transactions=[f"tx{i}".encode()],
+            parents=[signed[sp].hex() if sp >= 0 else root_self_parent(plist[c].id),
+                     signed[op].hex() if op >= 0 else ""],
+            creator=pub_key_bytes(keys[c]), index=int(grid.index[i]),
+        )
+        ev.sign(keys[c])
+        signed.append(ev)
+    return peers, keys[0], signed
+
+
+def handed(ev):
+    """A fresh copy, as a decoded wire event arrives (insert mutates)."""
+    cp = Event(transactions=ev.body.transactions, parents=ev.body.parents,
+               creator=ev.body.creator, index=ev.body.index)
+    cp.signature = ev.signature
+    return cp
+
+
+class Blocks:
+    def __init__(self):
+        self.bodies = []
+
+    def put(self, block):
+        self.bodies.append(block.body.marshal())
+
+
+def drive(backend):
+    """Hand the stream over in SYNC-event syncs, then flush. Returns the
+    Core, its committed block bodies and the last span id before the
+    flush."""
+    peers, key, signed = signed_stream()
+    blocks = Blocks()
+    core = Core(0, key, peers, InmemStore(peers, 2000), commit_ch=blocks,
+                consensus_backend=backend)
+    for lo in range(0, len(signed), SYNC):
+        for ev in signed[lo:lo + SYNC]:
+            core.insert_event(handed(ev), True)
+        core.run_consensus()
+    before_flush = max((s.id for s in core.hg.obs.tracer.spans()), default=0)
+    core.flush_device_dispatch()
+    return core, blocks.bodies, before_flush
+
+
+@pytest.fixture(scope="module")
+def cpu_blocks():
+    return drive("cpu")[1]
+
+
+_RUNS = {}
+
+
+@pytest.fixture(params=["sync", "pipelined"])
+def run(request, monkeypatch):
+    """One driven Core per discipline, built once and shared by the cases."""
+    if request.param not in _RUNS:
+        monkeypatch.setitem(live_mod.ENGINE_DEFAULTS, "async_fetch",
+                            request.param == "pipelined")
+        core, blocks, before_flush = drive("tpu")
+        assert core.ladder_rung() == "live" and core.live_demotions == 0
+        _RUNS[request.param] = (core, blocks, before_flush,
+                                core.hg.obs.tracer.spans())
+    return (request.param,) + _RUNS[request.param]
+
+
+def named(spans, name):
+    return [s for s in spans if s.name == name]
+
+
+def test_spans_hang_under_run_consensus(run):
+    discipline, core, _, before_flush, spans = run
+    by_id = {s.id: s for s in spans}
+
+    def root_of(sp):
+        while sp.parent is not None:
+            sp = by_id[sp.parent]
+        return sp
+
+    tree = [s for s in spans
+            if s.name.startswith(("live.", "device.", "commit.",
+                                  "consensus.process_"))]
+    assert tree
+    for sp in tree:
+        if sp.id > before_flush:
+            assert root_of(sp).name != "core.run_consensus"
+        else:
+            assert root_of(sp).name == "core.run_consensus", sp.name
+    roots = named(spans, "core.run_consensus")
+    assert [s.attrs["call"] for s in roots] == list(range(1, len(roots) + 1))
+    assert {s.attrs["rung"] for s in roots} == {"live"}
+    # a child lies inside its parent on the clock too
+    for sp in tree:
+        if sp.parent is not None:
+            p = by_id[sp.parent]
+            assert p.start <= sp.start
+            assert sp.start + sp.duration <= p.start + p.duration + 1e-9
+    # what each span hangs under
+    parents = {(s.name, by_id[s.parent].name) for s in tree
+               if s.parent is not None}
+    assert ("live.stage", "device.dispatch") in parents
+    assert ("live.launch", "device.dispatch") in parents
+    assert ("live.admissible", "live.integrate") in parents
+    assert ("commit.frame", "consensus.process_decided_rounds") in parents
+    assert ("commit.block", "consensus.process_decided_rounds") in parents
+
+
+def test_dispatch_id_ties_launch_fetch_and_integration(run):
+    discipline, core, _, _, spans = run
+    by_id = {s.id: s for s in spans}
+    launched = {by_id[s.parent].attrs["dispatch"]
+                for s in named(spans, "live.launch")}
+    ids = {name: [s.attrs["dispatch"] for s in named(spans, name)]
+           for name in ("device.dispatch", "live.pack", "device.fetch",
+                        "live.integrate")}
+    for name, seen in ids.items():
+        assert len(seen) == len(set(seen)), name  # once in each
+    # the attach dispatch (the first) packs the bootstrapped state: it has
+    # no launch of its own; every other one has all four
+    attach = ids["live.pack"][0]
+    assert set(ids["live.pack"]) == set(ids["device.fetch"]) \
+        == set(ids["live.integrate"]) == launched | {attach}
+    assert set(ids["device.dispatch"]) == launched
+    fetches = named(spans, "device.fetch")
+    if discipline == "pipelined":
+        assert {s.attrs["discipline"] for s in fetches[1:]} == {"pipelined"}
+        assert max(s.attrs["lag_calls"] for s in fetches) >= 1
+    else:
+        assert {s.attrs["discipline"] for s in fetches} == {"sync"}
+        assert {s.attrs["lag_calls"] for s in fetches} == {0}
+    assert sum(s.attrs["rows"] for s in named(spans, "live.integrate")) == EVENTS
+    assert sum(s.attrs["events"] for s in named(spans, "live.stage")) \
+        == EVENTS - SYNC  # the first sync is bootstrapped, not staged
+
+
+def test_totals_count_what_the_program_did(run, cpu_blocks):
+    _, core, blocks, _, spans = run
+    totals = core.hg.obs.tracer.totals()
+    assert blocks and blocks == cpu_blocks
+    assert totals["commit.block"][0] == len(blocks) == len(named(spans, "commit.block"))
+    assert [s.attrs["index"] for s in named(spans, "commit.block")] \
+        == list(range(len(blocks)))
+    assert sum(s.attrs["txs"] for s in named(spans, "commit.block")) \
+        == core.hg.consensus_transactions
+    for name in ("insert", "insert.verify", "insert.fd"):
+        assert totals[name][0] == EVENTS, name
+    assert totals["insert"][1] >= totals["insert.verify"][1] + totals["insert.fd"][1]
+    assert totals["core.run_consensus"][0] == EVENTS // SYNC
+    assert "live.host_repair" not in totals
+    hist = core.hg.obs.histogram("babble_consensus_pass_duration_seconds",
+                                 labels=("phase",))
+    assert hist.stats(phase="process_decided_rounds")[0] \
+        == totals["consensus.process_decided_rounds"][0] > 0
+
+
+def test_one_interval_is_booked_once(run):
+    _, core, _, _, spans = run
+    obs = core.hg.obs
+    totals = obs.tracer.totals()
+    cells = obs.devledger.snapshot()["cells"]
+    advances = len(named(spans, "device.dispatch"))
+    for component, span in (("stage", "live.stage"), ("fetch", "device.fetch"),
+                            ("integrate", "live.integrate")):
+        [cell] = [v for k, v in cells.items()
+                  if k.startswith("live/dispatch/") and k.endswith("/" + component)]
+        assert cell[0] == totals[span][0], component
+        assert cell[1] == pytest.approx(totals[span][1], abs=1e-6)
+    assert totals["live.stage"][0] == advances
+    for metric, span in (("babble_device_dispatch_seconds", "device.dispatch"),
+                         ("babble_device_fetch_seconds", "device.fetch")):
+        count, seconds = obs.histogram(metric).stats()
+        assert count == totals[span][0]
+        assert seconds == pytest.approx(totals[span][1], abs=1e-9)
+
+
+def test_checkpoints_bracket_every_call(run):
+    _, core, _, _, spans = run
+    tracer = core.hg.obs.tracer
+    roots = named(spans, "core.run_consensus")
+    first, last = roots[0], roots[-1]
+    whole = tracer.totals_between(first.start - 1.0, last.start + last.duration + 1.0)
+    assert whole["core.run_consensus"][0] == len(roots)
+    # from the second call's entry: one call and its sync's inserts fewer
+    later = tracer.totals_between(roots[1].start - 1e-4, last.start + last.duration + 1.0)
+    assert later["core.run_consensus"][0] == len(roots) - 1
+    assert later["insert"][0] == EVENTS - 2 * SYNC
+
+
+def test_refused_reception_is_counted_and_repaired(monkeypatch, cpu_blocks):
+    """One fetched round-received moved past the newest decided round: the
+    host rule refuses it, the host's reception pass runs in the device's
+    place, and that is counted; blocks stay the CPU engine's."""
+    monkeypatch.setitem(live_mod.ENGINE_DEFAULTS, "async_fetch", False)
+    real = live_mod._unpack_results
+
+    def unpack(packed, e_win, r_cap, n):
+        out = list(real(packed, e_win, r_cap, n))
+        received = out[3].copy()
+        if received.max() >= 0:
+            received[received == received.max()] += 1
+        out[3] = received
+        return tuple(out)
+
+    monkeypatch.setattr(live_mod, "_unpack_results", unpack)
+    core, blocks, _ = drive("tpu")
+    obs = core.hg.obs
+    repaired = obs.counter("babble_live_host_repaired_integrations_total").value()
+    assert repaired > 0
+    assert obs.tracer.totals()["live.host_repair"][0] == repaired
+    assert core.ladder_rung() == "live"
+    assert blocks == cpu_blocks
+
+
+def test_rebase_has_a_span(monkeypatch, cpu_blocks):
+    """A round axis too short for the stream: every rebase leaves a
+    `live.rebase` span under the call that made it, carrying the new base."""
+    monkeypatch.setitem(live_mod.ENGINE_DEFAULTS, "async_fetch", False)
+    monkeypatch.setitem(live_mod.ENGINE_DEFAULTS, "r_cap", 16)
+    core, blocks, _ = drive("tpu")
+    spans = core.hg.obs.tracer.spans()
+    by_id = {s.id: s for s in spans}
+    rebases = [s for s in named(spans, "live.rebase") if s.attrs]
+    eng = core.hg._live_device_engine
+    assert len(rebases) == eng.rebases > 0
+    assert core.hg.obs.counter("babble_device_rebases_total").value() == eng.rebases
+    assert [s.attrs["base"] for s in rebases] == sorted(s.attrs["base"] for s in rebases)
+    assert rebases[-1].attrs["base"] == eng.round_base
+    assert {by_id[s.parent].name for s in rebases} == {"core.run_consensus"}
+    assert core.live_demotions == 0 and blocks == cpu_blocks

@@ -174,9 +174,260 @@ def test_chrome_trace_export_shape():
     assert len(meta) == 1 and meta[0]["name"] == "thread_name"
     assert [e["name"] for e in spans] == ["a", "b"]
     assert spans[0]["ts"] == 1e6 and spans[0]["dur"] == 5e5  # microseconds
-    assert spans[0]["args"] == {"k": "v"}
+    assert spans[0]["args"] == {"k": "v", "id": 1}  # attrs plus the span's id
+    assert spans[1]["args"] == {"id": 2}
     assert all(e["pid"] == 3 for e in evs)
     json.dumps(doc)  # must be directly serializable
+
+
+# ----------------------------------------------------------------------
+# the span tree (ISSUE 25): id and parent per thread, totals that never
+# wrap, checkpoints for windowed readings, the profiler annotator, the
+# weak set of live tracers
+# ----------------------------------------------------------------------
+
+def _tree_on_two_threads():
+    """root(a(b), c) on the main thread and, while `a` is open there,
+    other(x) on a second thread: {name: span}."""
+    import threading
+
+    tracer = SpanTracer(capacity=32)
+
+    def worker():
+        with tracer.span("other"):
+            with tracer.span("x"):
+                pass
+
+    with tracer.span("root"):
+        with tracer.span("a"):
+            t = threading.Thread(target=worker, name="second")
+            t.start()
+            t.join(10)
+            assert not t.is_alive()
+            with tracer.span("b"):
+                pass
+            tracer.record("marked", 0.0, 0.0)
+        with tracer.span("c"):
+            pass
+    tracer.record("loose", 0.0, 0.0)
+    return {s.name: s for s in tracer.spans()}
+
+
+@pytest.mark.parametrize("child,parent", [
+    ("root", None), ("a", "root"), ("b", "a"), ("c", "root"),  # nested, sibling
+    ("marked", "a"), ("loose", None),  # record(): whatever is open now
+    ("other", None), ("x", "other"),  # a thread has a stack of its own
+])
+def test_span_parent_is_the_span_open_on_its_thread(child, parent):
+    by_name = _tree_on_two_threads()
+    ids = [s.id for s in by_name.values()]
+    assert sorted(ids) == list(range(1, len(ids) + 1))  # a sequence, no gaps
+    got = by_name[child].parent
+    assert got == (by_name[parent].id if parent else None)
+    assert by_name["x"].thread == "second" != by_name["b"].thread
+    doc = SpanTracer(capacity=4)
+    with doc.span("p"):
+        with doc.span("q", k=1):
+            pass
+    q, p = [e["args"] for e in doc.to_chrome_trace()["traceEvents"] if e["ph"] == "X"]
+    assert p == {"id": 1} and q == {"k": 1, "id": 2, "parent_id": 1}
+
+
+def _timed(clock, tracer, name, seconds, how):
+    if how == "span":
+        with tracer.span(name):
+            clock.now += seconds
+    elif how == "record":
+        tracer.record(name, clock.now, seconds)
+    else:
+        tracer.add(name, seconds)
+
+
+@pytest.mark.parametrize("how,ring_entries", [("span", 3), ("record", 3), ("add", 0)])
+def test_totals_count_every_span_record_and_add(how, ring_entries):
+    clock = SimClock()
+    tracer = SpanTracer(clock=clock, capacity=2)  # the ring wraps, totals do not
+    for seconds in (0.25, 0.5, 1.0):
+        _timed(clock, tracer, "work", seconds, how)
+    _timed(clock, tracer, "other", 2.0, "add")
+    assert tracer.totals() == {"work": (3, 1.75), "other": (1, 2.0)}
+    assert len(tracer.spans()) == min(ring_entries, 2)
+    assert tracer.dropped == max(ring_entries - 2, 0)
+
+
+@pytest.mark.parametrize("t0,t1,want", [
+    # checkpoints at 0 (empty), 1, 3, 6: between the first and last inside
+    (0.0, 10.0, {"w": (3, 6.0), "late": (1, 0.5)}),
+    (0.5, 6.0, {"w": (2, 5.0), "late": (1, 0.5)}),
+    (1.0, 3.0, {"w": (1, 2.0)}),
+    (2.0, 5.0, {}),   # one checkpoint inside: nothing to take a difference of
+    (7.0, 9.0, {}),   # none
+])
+def test_totals_between_checkpoints(t0, t1, want):
+    clock = SimClock()
+    tracer = SpanTracer(clock=clock)
+    tracer.checkpoint()
+    for seconds in (1.0, 2.0, 3.0):
+        with tracer.span("w"):
+            clock.now += seconds
+        if seconds == 3.0:
+            tracer.add("late", 0.5)
+        tracer.checkpoint()
+    assert tracer.totals_between(t0, t1) == want
+
+
+def test_checkpoints_are_bounded():
+    from babble_tpu.obs.trace import CHECKPOINT_CAPACITY
+
+    clock = SimClock()
+    tracer = SpanTracer(clock=clock)
+    for i in range(CHECKPOINT_CAPACITY + 10):
+        clock.now = float(i)
+        tracer.add("tick", 1.0)
+        tracer.checkpoint()
+    got = tracer.totals_between(0.0, float("inf"))
+    assert got == {"tick": (CHECKPOINT_CAPACITY - 1, CHECKPOINT_CAPACITY - 1.0)}
+
+
+class _Note:
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _Note.log.append(("open", self.name))
+
+    def __exit__(self, *exc):
+        _Note.log.append(("close", self.name))
+
+
+@pytest.mark.parametrize("raises", [False, True])
+def test_annotator_wraps_every_span_and_closes_on_error(monkeypatch, raises):
+    monkeypatch.setattr(SpanTracer, "annotator", _Note)
+    monkeypatch.setattr(_Note, "log", [])
+    obs = Observability(clock=SimClock())
+    try:
+        with obs.span("outer"):
+            with obs.span("inner"):
+                if raises:
+                    raise KeyError("boom")
+    except KeyError:
+        assert raises
+    obs.tracer.add("quiet", 1.0)  # totals only: no annotation
+    assert _Note.log == [("open", "babble.outer"), ("open", "babble.inner"),
+                         ("close", "babble.inner"), ("close", "babble.outer")]
+    assert [s.name for s in obs.tracer.spans()] == ["inner", "outer"]
+    assert obs.tracer._stack() == []  # nothing left open after the error
+
+
+def test_a_cpu_node_imports_no_jax_and_annotates_nothing():
+    """The annotator is None until a device backend is chosen, and a
+    cpu-backend Core's consensus call, spans and all, never imports jax."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from babble_tpu.crypto import generate_key, pub_key_bytes\n"
+        "from babble_tpu.hashgraph import InmemStore\n"
+        "from babble_tpu.node import Core\n"
+        "from babble_tpu.obs import SpanTracer\n"
+        "from babble_tpu.peers import Peer, Peers\n"
+        "key = generate_key()\n"
+        "peers = Peers.from_slice([Peer(net_addr='', pub_key_hex='0x' + "
+        "pub_key_bytes(key).hex().upper())])\n"
+        "core = Core(0, key, peers, InmemStore(peers, 10))\n"
+        "core.run_consensus()\n"
+        "totals = core.hg.obs.tracer.totals()\n"
+        "assert totals['core.run_consensus'][0] == 1, totals\n"
+        "assert totals['consensus.process_decided_rounds'][0] == 1, totals\n"
+        "assert SpanTracer.annotator is None\n"
+        "assert 'jax' not in sys.modules\n"
+    )
+    done = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=120)
+    assert done.returncode == 0, done.stderr
+
+
+def test_span_feeds_histogram_and_ledger_from_one_reading():
+    clock = SimClock()
+    obs = Observability(clock=clock)
+    h = obs.histogram("one_reading_seconds", "x")
+    with obs.span("live.stage", histogram=h, ledger=("live", "stage", "wide")) as sp:
+        clock.now += 0.125
+        sp.attrs["events"] = 7
+    assert sp.duration == 0.125 and sp.attrs == {"events": 7}
+    assert h.stats() == (1, 0.125)
+    assert obs.tracer.totals()["live.stage"] == (1, 0.125)
+    assert obs.devledger.snapshot()["cells"]["live/dispatch/wide/stage"] == [1, 0.125]
+
+
+def test_live_tracers_drops_a_collected_tracer():
+    import gc
+
+    from babble_tpu.obs import live_tracers
+
+    kept = Observability()
+    gone = Observability()
+    assert {kept.tracer, gone.tracer} <= set(live_tracers())
+    gone_id = id(gone.tracer)
+    del gone
+    gc.collect()
+    alive = live_tracers()
+    assert kept.tracer in alive
+    assert gone_id not in {id(t) for t in alive}
+
+
+def test_a_checkpointed_tracer_outlives_its_node_for_later_readers():
+    """A benchmark's reader runs after the entry has dropped its Core: the
+    newest tracers that have checkpointed are held, and only they."""
+    import gc
+    import weakref
+
+    from babble_tpu.obs import live_tracers
+    from babble_tpu.obs.trace import KEPT_TRACERS
+
+    def node(clock):
+        obs = Observability(clock=clock)
+        obs.tracer.checkpoint()
+        with obs.span("w", ledger=("live", "stage", "wide")):
+            clock.now += 2.0
+        obs.tracer.checkpoint()
+        return id(obs.tracer), weakref.ref(obs.devledger)
+
+    ident, ledger = node(SimClock(start=1000.0))
+    gc.collect()
+    assert ledger() is None  # the tracer holds nothing of the node
+    [kept] = [t for t in live_tracers() if id(t) == ident]
+    assert kept.totals_between(999.0, 1003.0) == {"w": (1, 2.0)}
+    with kept.span("late", ledger=("live", "stage", "wide")):
+        pass  # a span after the ledger is gone books nowhere, and is no error
+    del kept
+    for _ in range(KEPT_TRACERS):
+        node(SimClock())
+    gc.collect()
+    assert ident not in {id(t) for t in live_tracers()}
+
+
+def test_sim_span_tree_export_is_byte_identical():
+    """Two same-seed simulator runs: every node's whole Chrome export
+    (ids and parent ids included) and its totals are byte-identical."""
+    from babble_tpu.sim import SimCluster, preset_plan
+
+    def export(seed):
+        cluster = SimCluster(n=4, seed=seed, plan=preset_plan("lossy", 4))
+        cluster.run(until=None, target_block=3)
+        doc = cluster.cluster_trace()
+        totals = [sorted(sn.node.obs.tracer.totals().items()) for sn in cluster.sns]
+        return json.dumps([doc, totals], sort_keys=True)
+
+    a, b = export(5), export(5)
+    assert a == b
+    spans = [e for e in json.loads(a)[0]["traceEvents"] if e["ph"] == "X"]
+    assert any(e["name"] == "core.run_consensus" for e in spans)
+    assert any(e["args"].get("parent_id") for e in spans)
+    assert a != export(6)
 
 
 # ----------------------------------------------------------------------
