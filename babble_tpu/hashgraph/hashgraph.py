@@ -131,9 +131,15 @@ class Hashgraph:
             p.id: i for i, p in enumerate(participants.to_peer_slice())
         }
 
-        # memo caches (unbounded dicts; cleared on Reset)
+        # memo caches (unbounded dicts; cleared on Reset). An event's own
+        # stamp fills them on a miss (see _memoized)
         self._round_cache: Dict[str, int] = {}
         self._timestamp_cache: Dict[str, int] = {}
+        # round() misses answered from a stamp, and rounds derived by
+        # strongly-see walks, since process_decided_rounds last handed
+        # them to the tracer (totals `round.stamp`, `round.derive`)
+        self._stamp_reads = 0
+        self._derivations = 0
 
         # identities of events below a fast-sync section cut, referenced as
         # other-parents by section events (see section.py); reset_floor is
@@ -202,21 +208,50 @@ class Hashgraph:
         cached = self._round_cache.get(x)
         if cached is not None:
             return cached
-        # iterative evaluation of the self/other-parent recursion
+        return self._memoized(
+            x, self._round_cache, self._stamped_round,
+            self._round_deps, self._round_once,
+        )
+
+    def _memoized(self, x: str, memo: Dict[str, int], stamped, deps, once) -> int:
+        """Iterative evaluation of a self/other-parent recursion (round,
+        lamport timestamp) into its memo dict. An event's own validated
+        stamp IS the memo: divide_rounds sets it from this very function,
+        and every device write-back (tpu/live.py, tpu/engine.py, the mesh
+        rung) stamps only what validate_round_writeback let through, so a
+        miss on a stamped event reads the stamp and derives nothing. Only
+        an event without a stamp (or gone from the store) is derived."""
         stack = [x]
         while stack:
             h = stack[-1]
-            if h in self._round_cache:
+            if h in memo:
                 stack.pop()
                 continue
-            deps = self._round_deps(h)
-            missing = [d for d in deps if d not in self._round_cache]
-            if missing:
-                stack.extend(missing)
-                continue
-            self._round_cache[h] = self._round_once(h)
+            known = stamped(h)
+            if known is None:
+                missing = [d for d in deps(h) if d not in memo]
+                if missing:
+                    stack.extend(missing)
+                    continue
+                known = once(h)
+            memo[h] = known
             stack.pop()
-        return self._round_cache[x]
+        return memo[x]
+
+    def _stamped_round(self, x: str) -> Optional[int]:
+        try:
+            stamp = self.store.get_event(x).round
+        except StoreErr:
+            return None
+        if stamp is not None:
+            self._stamp_reads += 1
+        return stamp
+
+    def _stamped_lamport(self, x: str) -> Optional[int]:
+        try:
+            return self.store.get_event(x).lamport_timestamp
+        except StoreErr:
+            return None
 
     def _round_deps(self, x: str) -> List[str]:
         """Parent hashes whose rounds must be known before x's."""
@@ -262,6 +297,9 @@ class Hashgraph:
             if op_round > parent_round:
                 parent_round = op_round
 
+        # from here on it is a derivation: one strongly-see walk for each
+        # witness of the parent round
+        self._derivations += 1
         c = 0
         for w in self.store.round_witnesses(parent_round):
             if self.strongly_see(x, w):
@@ -285,20 +323,10 @@ class Hashgraph:
         cached = self._timestamp_cache.get(x)
         if cached is not None:
             return cached
-        stack = [x]
-        while stack:
-            h = stack[-1]
-            if h in self._timestamp_cache:
-                stack.pop()
-                continue
-            deps = self._lamport_deps(h)
-            missing = [d for d in deps if d not in self._timestamp_cache]
-            if missing:
-                stack.extend(missing)
-                continue
-            self._timestamp_cache[h] = self._lamport_once(h)
-            stack.pop()
-        return self._timestamp_cache[x]
+        return self._memoized(
+            x, self._timestamp_cache, self._stamped_lamport,
+            self._lamport_deps, self._lamport_once,
+        )
 
     def _lamport_deps(self, x: str) -> List[str]:
         if x in self.store.roots_by_self_parent():
@@ -778,7 +806,18 @@ class Hashgraph:
             "consensus.process_decided_rounds",
             histogram=self._pass_hist.labels(phase="process_decided_rounds"),
         ):
-            self._process_decided_rounds()
+            try:
+                self._process_decided_rounds()
+            finally:
+                # once a call, not once an event: the host engine derives
+                # a round for every event it divides
+                tracer = self.obs.tracer
+                if self._stamp_reads:
+                    tracer.add("round.stamp", 0.0, count=self._stamp_reads)
+                    self._stamp_reads = 0
+                if self._derivations:
+                    tracer.add("round.derive", 0.0, count=self._derivations)
+                    self._derivations = 0
 
     def _process_decided_rounds(self) -> None:
         """The commit loop of process_decided_rounds.
@@ -878,6 +917,7 @@ class Hashgraph:
     def _build_frame(self, round_received: int, note: dict) -> Frame:
         """Build and store the frame of a round the store does not hold;
         `note` takes what the `commit.frame` span says of it."""
+        derived_before = self._derivations
         round_info = self.store.get_round(round_received)
         events = [self.store.get_event(eh) for eh in round_info.consensus_events()]
         from .event import by_lamport_key
@@ -920,6 +960,7 @@ class Hashgraph:
         self.store.set_frame(res)
         note["events"] = len(events)
         note["roots_created"] = created
+        note["rounds_derived"] = self._derivations - derived_before
         return res
 
     # ECDSA verifications per process_sig_pool pass. The pass runs under
@@ -1644,12 +1685,9 @@ class Hashgraph:
             self._check_other_parent(ev)
             ev.topological_index = self.topological_index
             self.topological_index += 1
-            # authoritative donor metadata below the scrub ceiling — not
+            # a stamp left below the scrub ceiling is authoritative donor
+            # metadata and the memo of round()/lamport_timestamp(): not
             # recomputed; scrubbed events (None) are re-decided instead
-            if ev.round is not None:
-                self._round_cache[ev.hex()] = ev.round
-            if ev.lamport_timestamp is not None:
-                self._timestamp_cache[ev.hex()] = ev.lamport_timestamp
             self.store.set_event(ev)
             if ev.round_received is None:
                 self.undetermined_events.append(ev.hex())

@@ -114,11 +114,11 @@ class SpanTracer:
             self._next += 1
             self._add_locked(sp.name, sp.duration)
 
-    def _add_locked(self, name: str, seconds: float) -> None:  # requires-lock: _lock
+    def _add_locked(self, name: str, seconds: float, count: int = 1) -> None:  # requires-lock: _lock
         total = self._totals.get(name)
         if total is None:
             total = self._totals[name] = [0, 0.0]
-        total[0] += 1
+        total[0] += count
         total[1] += seconds
 
     def _copy_locked(self) -> Dict[str, Tuple[int, float]]:  # requires-lock: _lock
@@ -171,12 +171,13 @@ class SpanTracer:
 
     # -- totals ------------------------------------------------------------
 
-    def add(self, name: str, seconds: float) -> None:
-        """Add one occurrence of `seconds` to `name`'s totals and write no
-        ring entry: for work done once per event, where a span each would
-        wrap the ring within one sync."""
+    def add(self, name: str, seconds: float, count: int = 1) -> None:
+        """Add `count` occurrences that took `seconds` together to `name`'s
+        totals and write no ring entry: for work done once per event,
+        where a span each would wrap the ring within one sync, and for a
+        count the program kept in a plain integer through a call."""
         with self._lock:
-            self._add_locked(name, seconds)
+            self._add_locked(name, seconds, count)
 
     def totals(self) -> Dict[str, Tuple[int, float]]:
         """Cumulative (count, seconds) per span name since the tracer was

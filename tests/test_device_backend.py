@@ -21,7 +21,9 @@ from babble_tpu.net import InmemTransport
 from babble_tpu.node import Config, Node
 from babble_tpu.peers import Peer, Peers
 from babble_tpu.proxy import InmemDummyClient
+from babble_tpu.tpu import run_consensus_device
 
+import dsl
 from test_node import (
     bombard_and_wait,
     check_gossip,
@@ -29,6 +31,31 @@ from test_node import (
     shutdown_nodes,
 )
 from test_fastsync import connect_transport, first_available_block
+from test_tpu_differential import clone_hashgraph
+
+
+@pytest.mark.parametrize("fixture", ["consensus", "funky", "sparse"])
+def test_one_shot_frames_read_the_stamped_rounds(fixture):
+    """The one-shot write-back (run_consensus_device) stamps events and
+    fills no memo dict: frame building reads the stamps, derives no round,
+    and the block bodies stay the host engine's byte for byte."""
+    init = getattr(dsl, f"init_{fixture}_hashgraph")
+    hg = (init(full=True) if fixture == "funky" else init())[0]
+    cpu, dev = clone_hashgraph(hg), clone_hashgraph(hg)
+    cpu_blocks, dev_blocks = [], []
+    cpu.commit_callback = cpu_blocks.append
+    dev.commit_callback = dev_blocks.append
+    cpu.run_consensus()
+    run_consensus_device(dev)
+
+    frames = [s for s in dev.obs.tracer.spans() if s.name == "commit.frame"]
+    assert len(frames) >= len(dev_blocks) > 0
+    assert [s.attrs["rounds_derived"] for s in frames] == [0] * len(frames)
+    totals = dev.obs.tracer.totals()
+    assert "round.derive" not in totals and totals["round.stamp"][0] > 0
+    assert cpu.obs.tracer.totals()["round.derive"][0] > 0
+    assert [b.body.marshal() for b in dev_blocks] \
+        == [b.body.marshal() for b in cpu_blocks]
 
 
 def make_config(backend="tpu", sync_limit=150):

@@ -536,6 +536,111 @@ class TestConsensusPipeline:
 
 
 # ---------------------------------------------------------------------------
+# an event's stamp is the memo of round() and lamport_timestamp()
+# ---------------------------------------------------------------------------
+
+EXPECTED = {  # name: (lamport timestamp, round), as test_divide_rounds_bis
+    "e0": (0, 0), "e21b": (3, 0), "f1": (5, 1), "f0x": (8, 1),
+    "g1": (12, 2), "g02": (16, 2), "h10": (19, 3), "i2": (23, 4),
+}
+
+
+def drop_memos(h):
+    """What a device write-back leaves: stamps on the events, no memo."""
+    h._round_cache.clear()
+    h._timestamp_cache.clear()
+
+
+class TestStampIsTheMemo:
+    @pytest.fixture(autouse=True)
+    def setup(self):
+        self.h, self.index, self.ordered = init_consensus_hashgraph()
+
+    @pytest.mark.parametrize("name", sorted(EXPECTED))
+    def test_unstamped_event_still_derives(self, name):
+        h, x = self.h, self.index[name]
+        h.divide_rounds()
+        drop_memos(h)
+        derived = h._derivations
+        ev = h.store.get_event(x)
+        ev.set_round(None)
+        ev.set_lamport_timestamp(None)
+        ts, r = EXPECTED[name]
+        assert (h.lamport_timestamp(x), h.round(x)) == (ts, r)
+        # e0 hangs on its root: a lookup, not a derivation; the others are
+        # derived once, from their parents' stamps
+        parents = sum(p in self.index.values() for p in ev.body.parents)
+        assert h._derivations - derived == (name != "e0")
+        assert h._stamp_reads == parents
+        assert ev.round is None  # asking stamps nothing
+
+    @pytest.mark.parametrize("name", sorted(EXPECTED))
+    def test_stamped_event_is_not_rederived(self, name):
+        h, x = self.h, self.index[name]
+        h.divide_rounds()
+        drop_memos(h)
+        derived = h._derivations
+        ts, r = EXPECTED[name]
+        assert (h.lamport_timestamp(x), h.round(x)) == (ts, r)
+        assert (h._derivations, h._stamp_reads) == (derived, 1)
+        assert h.round(x) == r and h._stamp_reads == 1  # remembered
+        # the stamp, not a second derivation, is what was read
+        drop_memos(h)
+        ev = h.store.get_event(x)
+        ev.set_round(r + 40)
+        ev.set_lamport_timestamp(ts + 40)
+        assert (h.lamport_timestamp(x), h.round(x)) == (ts + 40, r + 40)
+        assert h._derivations == derived
+
+    def test_frames_from_stamps_equal_frames_from_memos(self):
+        from babble_tpu.hashgraph import Event
+
+        bodies, totals = [], []
+        for memos in (True, False):
+            h = Hashgraph(self.h.participants,
+                          InmemStore(self.h.participants, CACHE_SIZE))
+            blocks = []
+            h.commit_callback = lambda b, blocks=blocks: blocks.append(b.body.marshal())
+            for ev in self.ordered:
+                h.insert_event(Event.from_json(ev.to_json()), True)
+            h.divide_rounds()
+            h.decide_fame()
+            h.decide_round_received()
+            if not memos:
+                drop_memos(h)
+            h.process_decided_rounds()
+            frames = [s for s in h.obs.tracer.spans() if s.name == "commit.frame"]
+            assert [s.attrs["rounds_derived"] for s in frames] == [0, 0, 0]
+            assert (h._stamp_reads, h._derivations) == (0, 0)  # handed over
+            bodies.append(blocks)
+            totals.append(h.obs.tracer.totals())
+        assert bodies[0] == bodies[1] and len(bodies[0]) == 2
+        assert "round.stamp" not in totals[0] and totals[1]["round.stamp"][0] > 0
+        assert totals[0]["round.derive"] == totals[1]["round.derive"]
+
+    def test_reset_leaves_neither_stamp_nor_memo(self):
+        from babble_tpu.hashgraph import Frame
+
+        h = self.h
+        h.run_consensus()
+        block = h.store.get_block(1)
+        frame = h.get_frame(block.round_received())
+        rounds = {ev.hex(): (h.lamport_timestamp(ev.hex()), h.round(ev.hex()))
+                  for ev in frame.events}
+        assert h._round_cache and h._timestamp_cache
+        h.reset(block, Frame.from_json(frame.to_json()))
+        assert h._round_cache == {} and h._timestamp_cache == {}
+        stored = [h.store.get_event(ev.hex()) for ev in frame.events]
+        assert stored and all(
+            ev.round is None and ev.lamport_timestamp is None for ev in stored
+        )
+        # so the rounds after a reset are derived anew, from the frame's roots
+        for x, known in rounds.items():
+            assert (h.lamport_timestamp(x), h.round(x)) == known
+        assert h._stamp_reads == 0
+
+
+# ---------------------------------------------------------------------------
 # persistence: same pipeline on the SQLite store
 # ---------------------------------------------------------------------------
 

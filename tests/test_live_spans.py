@@ -6,7 +6,8 @@ What is held: every live/device/commit span hangs under a
 `core.run_consensus` root (but for the flush's), the `dispatch` id ties
 one dispatch's launch, fetch and integration across calls, the totals
 count what the program did (events inserted, blocks committed), one
-interval is booked once, and a reception the host rule refuses is counted.
+interval is booked once, a reception the host rule refuses is counted, and
+frame building reads the rounds the engine stamped and derives none.
 """
 
 import pytest
@@ -80,8 +81,14 @@ def drive(backend):
 
 
 @pytest.fixture(scope="module")
-def cpu_blocks():
-    return drive("cpu")[1]
+def cpu_run():
+    core, blocks, _ = drive("cpu")
+    return core, blocks
+
+
+@pytest.fixture(scope="module")
+def cpu_blocks(cpu_run):
+    return cpu_run[1]
 
 
 _RUNS = {}
@@ -187,6 +194,35 @@ def test_totals_count_what_the_program_did(run, cpu_blocks):
                                  labels=("phase",))
     assert hist.stats(phase="process_decided_rounds")[0] \
         == totals["consensus.process_decided_rounds"][0] > 0
+
+
+def test_frames_read_the_stamped_rounds(run, cpu_blocks):
+    """The device write-back's stamp is the memo of `Hashgraph.round`: no
+    frame of the live rung derives a round, every root's rounds are read
+    from stamps, and the bodies (frame hash included) stay the host
+    engine's."""
+    _, core, blocks, _, spans = run
+    frames = named(spans, "commit.frame")
+    assert len(frames) >= len(blocks) > 0  # a frame without events: no block
+    assert [s.attrs["rounds_derived"] for s in frames] == [0] * len(frames)
+    totals = core.hg.obs.tracer.totals()
+    assert "round.derive" not in totals
+    # at the least each frame's roots: their own, self- and other-parent's
+    assert totals["round.stamp"][0] >= N * len(frames)
+    assert totals["round.stamp"][1] == 0.0
+    assert blocks == cpu_blocks
+
+
+def test_host_engine_derives_in_divide_rounds_only(cpu_run):
+    core, blocks = cpu_run
+    tracer = core.hg.obs.tracer
+    frames = named(tracer.spans(), "commit.frame")
+    assert len(frames) >= len(blocks) > 0  # a frame without events: no block
+    assert [s.attrs["rounds_derived"] for s in frames] == [0] * len(frames)
+    totals = tracer.totals()
+    # one derivation an event, but for those that hang on a root
+    assert EVENTS - 2 * N <= totals["round.derive"][0] <= EVENTS
+    assert "round.stamp" not in totals
 
 
 def test_one_interval_is_booked_once(run):
