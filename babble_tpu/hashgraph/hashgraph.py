@@ -135,11 +135,13 @@ class Hashgraph:
         # stamp fills them on a miss (see _memoized)
         self._round_cache: Dict[str, int] = {}
         self._timestamp_cache: Dict[str, int] = {}
-        # round() misses answered from a stamp, and rounds derived by
-        # strongly-see walks, since process_decided_rounds last handed
-        # them to the tracer (totals `round.stamp`, `round.derive`)
+        # round() misses answered from a stamp, rounds derived by
+        # strongly-see walks, and decided rounds queued again for a late
+        # witness, since process_decided_rounds last handed them to the
+        # tracer (totals `round.stamp`, `round.derive`, `fame.reopen`)
         self._stamp_reads = 0
         self._derivations = 0
+        self._reopens = 0
 
         # identities of events below a fast-sync section cut, referenced as
         # other-parents by section events (see section.py); reset_floor is
@@ -582,6 +584,48 @@ class Hashgraph:
     # the five passes
     # ------------------------------------------------------------------
 
+    def queue_round(self, round_number: int, round_info: RoundInfo,
+                    late_witness: bool) -> bool:
+        """Queue the round an event was just divided into: the round's
+        first event queues it, and a witness that arrives after its round
+        was decided and dequeued queues it AGAIN (`late_witness`: the
+        event is a witness whose fame the round does not hold yet). The
+        one place both engines register an event's round, DivideRounds and
+        the device write-back alike. Returns True for a re-open.
+
+        A late witness (a crashed peer's pre-crash tail event surfacing
+        post-restart, a withheld chain revealed rounds later) would
+        otherwise keep fame UNDEFINED forever: decide_fame and the device
+        fame write-back only visit pending rounds, so witnesses_decided()
+        flips false for good and every reception scan crossing this round
+        stalls, while peers that held the event before deciding receive
+        those events normally (the round-5 survivor-side reception
+        divergence). Re-queued, fame resolves; process_decided_rounds
+        drops a settled round again once it is decided, so no block is
+        ever re-minted."""
+        # lower bound prevents reprocessing the base layer after Reset
+        if not round_info.queued and (
+            self.last_consensus_round is None
+            or round_number >= self.last_consensus_round
+        ):
+            self.pending_rounds.append(PendingRound(round_number, False))
+            round_info.queued = True
+            return False
+        if (
+            late_witness
+            and round_info.queued
+            # rounds at or below a fast-sync cut are the donor's to
+            # decide: their votes are not derivable from the scrubbed
+            # DAG, so re-queueing could never resolve
+            and (self.reset_floor is None or round_number > self.reset_floor)
+            and not any(p.index == round_number for p in self.pending_rounds)
+        ):
+            self.pending_rounds.append(PendingRound(round_number, False))
+            self.obs.flightrec.record("fame.reopen", round=round_number)
+            self._reopens += 1
+            return True
+        return False
+
     def divide_rounds(self) -> None:
         """Assign round + lamport timestamp, flag witnesses, queue pending
         rounds (reference: src/hashgraph/hashgraph.go:767-849)."""
@@ -604,43 +648,10 @@ class Hashgraph:
 
                 is_witness = self.witness(hash_)
 
-                # lower bound prevents reprocessing the base layer after Reset
-                if not round_info.queued and (
-                    self.last_consensus_round is None
-                    or round_number >= self.last_consensus_round
-                ):
-                    self.pending_rounds.append(PendingRound(round_number, False))
-                    round_info.queued = True
-                elif (
-                    is_witness
-                    and round_info.queued
-                    and not round_info.is_decided(hash_)
-                    # rounds at or below a fast-sync cut are the donor's to
-                    # decide — their votes are not derivable from the
-                    # scrubbed DAG, so re-queueing could never resolve
-                    and (
-                        self.reset_floor is None
-                        or round_number > self.reset_floor
-                    )
-                    and not any(
-                        p.index == round_number for p in self.pending_rounds
-                    )
-                ):
-                    # A witness arriving AFTER its round was decided and
-                    # dequeued (e.g. a crashed peer's pre-crash tail event
-                    # surfacing post-restart) would otherwise keep fame
-                    # UNDEFINED forever: decide_fame only visits pending
-                    # rounds, so witnesses_decided() flips false for good
-                    # and every reception scan crossing this round stalls —
-                    # while peers that held the event before deciding
-                    # receive those events normally (the round-5 survivor-
-                    # side reception divergence). Re-queue so fame resolves;
-                    # process_decided_rounds drops settled rounds again once
-                    # decided, so no block is ever re-minted.
-                    self.pending_rounds.append(PendingRound(round_number, False))
-                    self.obs.flightrec.record(
-                        "fame.reopen", round=round_number,
-                    )
+                self.queue_round(
+                    round_number, round_info,
+                    late_witness=is_witness and not round_info.is_decided(hash_),
+                )
 
                 round_info.add_event(hash_, is_witness)
                 self.store.set_round(round_number, round_info)
@@ -818,6 +829,9 @@ class Hashgraph:
                 if self._derivations:
                     tracer.add("round.derive", 0.0, count=self._derivations)
                     self._derivations = 0
+                if self._reopens:
+                    tracer.add("fame.reopen", 0.0, count=self._reopens)
+                    self._reopens = 0
 
     def _process_decided_rounds(self) -> None:
         """The commit loop of process_decided_rounds.

@@ -457,7 +457,7 @@ def run_consensus_device(hg, d_max: Optional[int] = None, mesh=None) -> None:
     sharded over its devices (babble_tpu/tpu/sharded.py) — the product
     path behind node.Config.mesh_devices."""
     from ..common import StoreErr, StoreErrType, is_store_err
-    from ..hashgraph import RoundInfo, PendingRound
+    from ..hashgraph import RoundInfo
 
     obs, clock = hg.obs, hg.obs.clock
     _t0 = clock.monotonic()
@@ -607,7 +607,7 @@ def integrate_pass_results(hg, grid, res, topo_hi: Optional[int] = None,
     topo_hi=None (the synchronous one-shot path) every undetermined event
     must be in the grid, as before."""
     from ..common import StoreErr, StoreErrType, is_store_err
-    from ..hashgraph import RoundInfo, PendingRound
+    from ..hashgraph import RoundInfo
 
     # --- write-back: DivideRounds (reference: hashgraph.go:767-849) ---
     # validate the WHOLE batch before stamping anything: a partial stamp
@@ -646,24 +646,12 @@ def integrate_pass_results(hg, grid, res, topo_hi: Optional[int] = None,
                         raise
                     ri = RoundInfo()
                 round_infos[rnum] = ri
-            if not ri.queued and (
-                hg.last_consensus_round is None or rnum >= hg.last_consensus_round
-            ):
-                hg.pending_rounds.append(PendingRound(rnum, False))
-                ri.queued = True
-            elif (
-                bool(res.witness[r])
-                and ri.queued
-                and not ri.is_decided(h)
-                # rounds at/below a fast-sync cut are the donor's to decide
-                and (hg.reset_floor is None or rnum > hg.reset_floor)
-                and not any(p.index == rnum for p in hg.pending_rounds)
-            ):
-                # late witness into a decided-and-dequeued round: re-queue
-                # so fame resolves, mirroring the host divide_rounds rule —
-                # otherwise the cpu engine un-freezes the round this call
-                # and a device-backend node diverges from it
-                hg.pending_rounds.append(PendingRound(rnum, False))
+            # a late witness re-queues a decided and dequeued round, as in
+            # DivideRounds: otherwise the cpu engine un-freezes the round
+            # this call and a device-backend node diverges from it
+            hg.queue_round(
+                rnum, ri,
+                late_witness=bool(res.witness[r]) and not ri.is_decided(h))
             ri.add_event(h, bool(res.witness[r]))
 
     # --- write-back: DecideFame (reference: hashgraph.go:852-947) ---

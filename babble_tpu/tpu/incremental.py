@@ -64,10 +64,16 @@ class IncState(NamedTuple):
     fame_decided: jax.Array  # (R_cap, N) bool
     famous: jax.Array  # (R_cap, N) bool
     rounds_decided: jax.Array  # (R_cap,) bool
+    # per round, witnesses that registered after the round's fame was
+    # decided and re-opened it (cumulative; the host reads the difference
+    # between two fetches)
+    reopened: jax.Array  # (R_cap,) int32
     last_round: jax.Array  # () int32
     count: jax.Array  # () int32 rows in use
-    # latched true if an undetermined row ever slid below the received
-    # window — the window was undersized and results are unreliable
+    # latched true if the windows could not hold the state and results
+    # are unreliable: an undetermined row slid below the received window,
+    # the round axis ran out, or a new row's round lies below the round
+    # window's base (both parents below it)
     stale: jax.Array  # () bool
     # latched true if fame voting ever needed more offsets than the
     # static unroll (deep coin scenarios) — fall back to the full pipeline
@@ -93,6 +99,7 @@ def init_state(n: int, e_cap: int, r_cap: int) -> IncState:
         fame_decided=jnp.zeros((r_cap, n), bool),
         famous=jnp.zeros((r_cap, n), bool),
         rounds_decided=jnp.zeros((r_cap,), bool),
+        reopened=jnp.zeros((r_cap,), jnp.int32),
         last_round=jnp.int32(0),
         count=jnp.int32(0),
         stale=jnp.bool_(False),
@@ -115,6 +122,11 @@ class Batch(NamedTuple):
     upd_col: jax.Array  # (U,) int32
     upd_val: jax.Array  # (U,) int32
     levels: jax.Array  # (L_MAX, W) int32 positions into the batch, -1 padding
+    # lamport timestamps of parents that are no rows of the state (a rebase
+    # pruned them: their round lies below the base, which -1 already says,
+    # but a child's lamport needs the value); -1 where the parent is a row
+    sp_lamport: jax.Array  # (B,) int32
+    op_lamport: jax.Array  # (B,) int32
 
 
 # statically unrolled fame-voting depth: decisions normally land at d<=5;
@@ -242,6 +254,28 @@ def _apply_deltas_and_stage(state: IncState, b):
     return fd, fd_w, la, creator, index, valid, tgt
 
 
+def _reopen_rounds(state: IncState, rounds_b, witness_b, valid):
+    """A witness that registers into a round whose fame is ALREADY DECIDED
+    (a withheld chain revealed rounds later, a laggard's old events)
+    re-opens that round: its decided flag is cleared, so the next
+    `_decide_body` starts its fame window there and votes on the newcomer
+    with the later rounds it holds, and receptions that cross the round
+    wait until it is whole again. That is the decision the host engine
+    takes too (`Hashgraph.queue_round` re-queues the round), and it cannot
+    move a witness that was decided before: fame is a fact of the DAG
+    (`_decide_body`). Returns (rounds_decided, reopened)."""
+    r_cap = state.rounds_decided.shape[0]
+    decided = state.rounds_decided.at[
+        jnp.clip(rounds_b, 0, r_cap - 1)
+    ].get(mode="fill", fill_value=False)
+    late = witness_b & valid & decided & (rounds_b >= 0) & (rounds_b < r_cap)
+    at = jnp.where(late, rounds_b, r_cap)
+    return (
+        state.rounds_decided.at[at].set(False, mode="drop"),
+        state.reopened.at[at].add(1, mode="drop"),
+    )
+
+
 def _step_body(
     state: IncState,
     batch: Batch,
@@ -263,7 +297,8 @@ def _step_body(
     #    buffers. Statically unrolled: level rows are -1-padded, so levels
     #    beyond the batch's real depth are pure no-ops (all scatters drop)
     def level_step(i, carry):
-        rounds, lamport, witness, wtable, w_of_row, la_w, fd_w, idx_w, coin_w = carry
+        (rounds, lamport, witness, wtable, w_of_row, la_w, fd_w, idx_w,
+         coin_w, below_base) = carry
         pos = batch.levels[i]  # (W,) positions into the batch
         pvalid = pos >= 0
         p = jnp.maximum(pos, 0)
@@ -294,9 +329,13 @@ def _step_body(
         fixed = batch.fixed_round[p]
         new_round = jnp.where(fixed >= 0, fixed, new_round)
         new_witness = new_round > sp_round
+        below_base = below_base | jnp.any(
+            pvalid & (fixed < 0) & (parent_round < 0))
 
-        sp_lt = jnp.where(sp >= 0, lamport[jnp.maximum(sp, 0)], -1)
-        op_lt = jnp.where(op >= 0, lamport[jnp.maximum(op, 0)], -1)
+        sp_lt = jnp.where(
+            sp >= 0, lamport[jnp.maximum(sp, 0)], batch.sp_lamport[p])
+        op_lt = jnp.where(
+            op >= 0, lamport[jnp.maximum(op, 0)], batch.op_lamport[p])
         new_lt = jnp.maximum(sp_lt, op_lt) + 1
 
         rounds = rounds.at[rows].set(new_round, mode="drop")
@@ -318,15 +357,16 @@ def _step_body(
         idx_w = idx_w.at[wr, c].set(batch.index[p], mode="drop")
         coin_w = coin_w.at[wr, c].set(batch.coin[p], mode="drop")
         return (rounds, lamport, witness, wtable, w_of_row, la_w, fd_w,
-                idx_w, coin_w)
+                idx_w, coin_w, below_base)
 
     carry = (state.rounds, state.lamport, state.witness, state.wtable,
-             state.w_of_row, state.la_w, fd_w, state.idx_w, state.coin_w)
+             state.w_of_row, state.la_w, fd_w, state.idx_w, state.coin_w,
+             jnp.bool_(False))
     with jax.named_scope("live.levels"):
         for i in range(batch.levels.shape[0]):
             carry = level_step(i, carry)
     (rounds, lamport, witness, wtable, w_of_row, la_w, fd_w, idx_w,
-     coin_w) = carry
+     coin_w, below_base) = carry
     last_round = jnp.maximum(state.last_round, jnp.max(rounds))
     count = state.count + jnp.sum(valid, dtype=jnp.int32)
 
@@ -335,27 +375,25 @@ def _step_body(
     # this deep needs rebasing (engine-level), so flag it as unreliable
     overflow = last_round >= r_cap - 1
 
-    # late-witness latch: a witness landing in an ALREADY-DECIDED round
-    # (a laggard's old events arriving long after the round settled) is a
-    # state the host engine handles by freezing that round's fame and
-    # blocking receptions behind it — semantics the dense window does not
-    # reproduce. Flag it so the caller falls back to the host engine
-    # rather than committing divergent blocks.
-    b_rounds = rounds.at[tgt].get(mode="fill", fill_value=-1)
-    b_witness = witness.at[tgt].get(mode="fill", fill_value=False)
-    rd = state.rounds_decided.at[
-        jnp.clip(b_rounds, 0, r_cap - 1)
-    ].get(mode="fill", fill_value=False)
-    late_witness = jnp.any(
-        b_witness & valid & rd & (b_rounds >= 0) & (b_rounds < r_cap)
+    # base latch: a row whose parents both lie below the round window's
+    # base (or are unknown to it) has a round the window cannot hold; its
+    # computed round would be a guess, so the state is flagged unreliable
+    overflow = overflow | below_base
+
+    # a late witness re-opens its round instead (see _reopen_rounds)
+    rounds_decided, reopened = _reopen_rounds(
+        state,
+        rounds.at[tgt].get(mode="fill", fill_value=-1),
+        witness.at[tgt].get(mode="fill", fill_value=False),
+        valid,
     )
-    overflow = overflow | late_witness
 
     return state._replace(
         la=la, fd=fd, creator=creator, index=index,
         rounds=rounds, lamport=lamport, witness=witness,
         w_of_row=w_of_row, wtable=wtable,
         la_w=la_w, fd_w=fd_w, idx_w=idx_w, coin_w=coin_w,
+        rounds_decided=rounds_decided, reopened=reopened,
         last_round=last_round, count=count,
         stale=state.stale | overflow,
     )
@@ -382,14 +420,15 @@ def _decide_body(
     index, creator, rounds = state.index, state.creator, state.rounds
 
     # fame over the active round window only: rounds below the first
-    # undecided one are SETTLED FOREVER. This freeze is load-bearing for
-    # cross-node agreement, not just an optimization: the host engine
-    # (like the reference) never revisits a round once it left the
-    # pending set, so a witness landing late in an already-decided round
-    # keeps UNDEFINED fame everywhere. Re-deciding it here would leak
-    # through the round-received computation (an internally "decided"
-    # round unblocks receptions the host-engine nodes still hold back)
-    # and commit different blocks.
+    # undecided one keep their stored decisions and are not voted on
+    # again. A decided round comes back into the window only when a late
+    # witness re-opens it (_reopen_rounds): the window then starts at that
+    # round, and every round from there up is decided again from the
+    # tables. Fame is a fact of the DAG, so the witnesses decided before
+    # come out as they were and the newcomer gets its own decision (not
+    # famous: nobody that voted had seen it); until it has one, the round
+    # is undecided and holds back the receptions that cross it, exactly
+    # as the host engine's re-queued round does (Hashgraph.queue_round).
     r_idx = jnp.arange(r_cap)
     undecided = ~state.rounds_decided & (r_idx <= last_round)
     floor_true = jnp.min(jnp.where(undecided, r_idx, last_round))
@@ -622,7 +661,7 @@ def _train_body(state: IncState, train: Train, super_majority: int,
     hi = jax.lax.Precision.HIGHEST
 
     def level_step(carry, pos):
-        rounds_b, lamport_b, witness_b, fd_w_f, wv_f = carry
+        rounds_b, lamport_b, witness_b, fd_w_f, wv_f, below_base = carry
         w = pos.shape[0]
         pvalid = pos >= 0
         p = jnp.maximum(pos, 0)
@@ -664,6 +703,8 @@ def _train_body(state: IncState, train: Train, super_majority: int,
         fixed = train.fixed_round[p]
         new_round = jnp.where(fixed >= 0, fixed, new_round)
         new_witness = new_round > sp_round
+        below_base = below_base | jnp.any(
+            pvalid & (fixed < 0) & (parent_round < 0))
 
         sp_lt = jnp.where(sp_p >= 0, sp_rl[:, 1], sp_lt_pre[p])
         op_lt = jnp.where(op_p >= 0, op_rl[:, 1], op_lt_pre[p])
@@ -694,16 +735,17 @@ def _train_body(state: IncState, train: Train, super_majority: int,
         wv_f = wv_f.reshape(r_cap * n).at[slot].set(
             1.0, mode="drop", unique_indices=True
         ).reshape(r_cap, n)
-        return (rounds_b, lamport_b, witness_b, fd_w_f, wv_f), None
+        return (rounds_b, lamport_b, witness_b, fd_w_f, wv_f,
+                below_base), None
 
     carry0 = (
         jnp.full((kb,), -1, jnp.int32),
         jnp.full((kb,), -1, jnp.int32),
         jnp.zeros((kb,), bool),
-        fd_w_f, wv_f,
+        fd_w_f, wv_f, jnp.bool_(False),
     )
     carry, _ = jax.lax.scan(level_step, carry0, train.levels)
-    rounds_b, lamport_b, witness_b, _, _ = carry
+    rounds_b, lamport_b, witness_b, _, _, below_base = carry
 
     # 5. bulk post-scan registration of this train's witnesses (the scan
     #    only tracked the fp32 compare copies) + one write-back scatter
@@ -747,21 +789,17 @@ def _train_body(state: IncState, train: Train, super_majority: int,
     count = state.count + jnp.sum(valid, dtype=jnp.int32)
     overflow = last_round >= r_cap - 1
 
-    # late-witness latch — see _step_body: a witness registering into an
-    # already-decided round needs the host engine's freeze semantics
-    rd = state.rounds_decided.at[
-        jnp.clip(rounds_b, 0, r_cap - 1)
-    ].get(mode="fill", fill_value=False)
-    late_witness = jnp.any(
-        witness_b & valid & rd & (rounds_b >= 0) & (rounds_b < r_cap)
-    )
-    overflow = overflow | late_witness
+    # base latch and late witnesses: see _step_body
+    overflow = overflow | below_base
+    rounds_decided, reopened = _reopen_rounds(
+        state, rounds_b, witness_b, valid)
 
     return state._replace(
         la=la, fd=fd, creator=creator, index=index,
         rounds=rounds, lamport=lamport, witness=witness,
         w_of_row=w_of_row, wtable=wtable,
         la_w=la_w, fd_w=fd_w, idx_w=idx_w, coin_w=coin_w,
+        rounds_decided=rounds_decided, reopened=reopened,
         last_round=last_round, count=count,
         stale=state.stale | overflow,
     )
@@ -1008,7 +1046,8 @@ L_MAX = 16
 def batches_from_grid(grid: DagGrid, batch_size: int, upd_cap: int, e_cap: int):
     """Slice a recorded synthetic DAG into fixed-shape append batches —
     the host-side work a live node would do during inserts (O(batch)).
-    Batches whose within-batch dependency depth exceeds L_MAX are split."""
+    Batches whose within-batch dependency depth exceeds L_MAX, or whose
+    first-descendant updates exceed upd_cap, are split."""
     assert grid.fd_update_stream is not None, "need record_fd_updates=True"
     spans = [
         (s, min(s + batch_size, grid.e))
@@ -1029,7 +1068,8 @@ def batches_from_grid(grid: DagGrid, batch_size: int, upd_cap: int, e_cap: int):
         op_loc = np.where((op >= start) & (op < end), op - start, -1)
         lvl = _dep_levels(sp_loc, op_loc)
         l_b = int(lvl.max(initial=-1)) + 1 if b else 0
-        if l_b > L_MAX:
+        upd = [t for r in rows for t in grid.fd_update_stream[r]]
+        if l_b > L_MAX or (len(upd) > upd_cap and b > 1):
             mid = (start + end) // 2
             spans[:0] = [(start, mid), (mid, end)]
             continue
@@ -1039,16 +1079,17 @@ def batches_from_grid(grid: DagGrid, batch_size: int, upd_cap: int, e_cap: int):
             levels_full[lvl[k], slot[lvl[k]]] = k
             slot[lvl[k]] += 1
 
-        upd = [t for r in rows for t in grid.fd_update_stream[r]]
         if len(upd) > upd_cap:
             raise ValueError(f"fd update burst {len(upd)} exceeds cap {upd_cap}")
         urow, ucol, uval = _pack_upd(upd, upd_cap, e_cap)
 
+        no_row = np.full(batch_size, -1, dtype=np.int32)  # a grid holds every parent
         out.append(Batch(
             sp_row=_pad1(sp, pad, -1),
             op_row=_pad1(op, pad, -1),
             upd_row=urow, upd_col=ucol, upd_val=uval,
             levels=levels_full,
+            sp_lamport=no_row, op_lamport=no_row,
             **_grid_slice_fields(grid, rows, pad),
         ))
     return out

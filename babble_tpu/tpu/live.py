@@ -141,6 +141,9 @@ class LiveDeviceEngine:
         # which the pipelined discipline spreads over several calls
         self.calls = 0
         self.dispatch_seq = 0
+        # the most events one call has staged so far: the event axis keeps
+        # room for two more such syncs (_capacity_soft)
+        self.largest_sync = 0
         self._m_dispatch = hg.obs.histogram(
             "babble_device_dispatch_seconds",
             "Host-side device program launch time per advance",
@@ -187,6 +190,8 @@ class LiveDeviceEngine:
         )
         self.layout = "packed" if self.packed else "wide"  # ledger cells
         self.state: IncState = init_state(self.n, self.e_cap, self.r_cap)
+        # the state's `reopened` counts as of the last integration
+        self.reopened_seen = np.zeros(self.r_cap, np.int32)
         self.row_of: Dict[str, int] = {}
         self.hashes: List[str] = []
         self.pending: List[tuple] = []  # (event, fd_writes)
@@ -266,7 +271,8 @@ class LiveDeviceEngine:
         floor - 1 — the rebase invariant: fame voting for round j only
         consults round j-1's witnesses, and an event no decided round
         received can only be received at or after the first undecided
-        round."""
+        round — lowered to the round of the oldest chain head where the
+        windows have room (_held_base)."""
         hg = self.hg
         undecided = [p.index for p in hg.pending_rounds if not p.decided]
         if undecided:
@@ -275,7 +281,61 @@ class LiveDeviceEngine:
             floor = hg.last_consensus_round + 1
         else:
             floor = 0
-        return max(0, floor - 1), floor
+        return self._held_base(max(0, floor - 1), floor), floor
+
+    def _held_base(self, base: int, floor: int) -> int:
+        """`base`, or as far below it as the round of the oldest chain
+        head, while the kept rows fit the received window and the rounds
+        a quarter of the round window. A validator's next event is at or
+        above its head's round, so what a silent or withholding validator
+        shows next then lands inside the round window: a late witness
+        re-opens a round the state still holds, instead of a round below
+        the base, which the state can only answer by latching `stale`.
+        Honest heads are within a round or two of the frontier and hold
+        nothing back."""
+        from ..common import StoreErr
+
+        hg = self.hg
+
+        def known_round(h: str):
+            try:
+                return hg.store.get_event(h).round
+            except StoreErr:
+                return None
+
+        oldest = base
+        for p in hg.participants.to_peer_slice():
+            try:
+                h, is_root = hg.store.last_event_from(p.pub_key_hex)
+            except StoreErr:
+                continue
+            head_round = None if is_root else known_round(h)
+            if head_round is not None:
+                oldest = min(oldest, head_round)
+        # inserted and not staged yet (the pipelined discipline rebases
+        # before it stages the call's events): such a chain's first event
+        # lands at or above the highest round among its parents
+        unstaged = {ev.hex() for ev, _ in self.pending}
+        for ev, _ in self.pending:
+            parents = [h for h in (ev.self_parent(), ev.other_parent()) if h]
+            if not any(h in unstaged for h in parents):
+                rounds = [r for r in map(known_round, parents) if r is not None]
+                if rounds:
+                    oldest = min(oldest, max(rounds))
+        # rows a base of r keeps: the undetermined events, and the events
+        # of the decided rounds from r up (those of the two that are both
+        # are counted twice: the bound errs on the small side)
+        rows = len(hg.undetermined_events)
+        room = self.e_win - 2 * self.batch_cap
+        for r in range(floor - 1, oldest - 1, -1):
+            try:
+                rows += len(hg.store.get_round(r).events)
+            except StoreErr:
+                break
+            if rows > room or floor - r > self.r_win // 4:
+                break
+            base = min(base, r)
+        return max(base, self.round_base)
 
     def _attach_from_frontier(self) -> None:
         """Fresh attach from the undecided frontier: walk each validator's
@@ -386,13 +446,20 @@ class LiveDeviceEngine:
 
         undet = set(hg.undetermined_events)
         kept: List[tuple] = []  # (hash, event)
-        try:
-            for h in self.hashes:
+        for h in self.hashes:
+            try:
                 ev = hg.store.get_event(h)
-                if (ev.round is not None and ev.round >= base) or h in undet:
-                    kept.append((h, ev))
-        except StoreErr as e:
-            raise GridUnsupported(f"rebase: frontier event evicted ({e})")
+            except StoreErr:
+                # below the store's window: the store evicts only events
+                # that were received long ago (it pins the undetermined
+                # ones and every chain's tail), so this row is decided
+                # history, as in _attach_from_frontier. A state that has
+                # grown past the store's cache since its last rebase (an
+                # event axis of 65,536 rows over a cache of 50,000) meets
+                # this on every rebase of the event axis
+                continue
+            if (ev.round is not None and ev.round >= base) or h in undet:
+                kept.append((h, ev))
         self._install_state(base, floor, kept)
         self.rebases += 1
         self._m_rebase.inc()
@@ -420,8 +487,10 @@ class LiveDeviceEngine:
             if h in undet and ev.round is not None:
                 min_undet_round = min(min_undet_round, ev.round)
 
-        # host-frozen rounds: a round below the frontier whose witness set
-        # gained a late member has UNDEFINED fame forever on the host and
+        # host-frozen rounds: a round below the frontier whose fame the
+        # host cannot decide (at or under a fast-sync cut a late witness is
+        # the donor's to decide and is never re-queued; everywhere else
+        # queue_round re-queues the round and the floor stays under it)
         # blocks receptions of older events behind it. The rebased state
         # cannot represent that block (the round is below the base), so
         # refuse and let the host engine carry this hashgraph.
@@ -549,6 +618,7 @@ class LiveDeviceEngine:
             fame_decided=jax.device_put(fame_decided),
             famous=jax.device_put(famous),
             rounds_decided=jax.device_put(rounds_decided),
+            reopened=jax.device_put(np.zeros(r_cap, np.int32)),
             last_round=jax.device_put(np.int32(last_abs - base)),
             count=jax.device_put(np.int32(len(kept))),
             stale=jax.device_put(np.bool_(False)),
@@ -557,6 +627,7 @@ class LiveDeviceEngine:
         self.row_of = new_row_of
         self.hashes = new_hashes
         self.round_base = base
+        self.reopened_seen = np.zeros(r_cap, np.int32)
 
     # -- advancing ---------------------------------------------------------
 
@@ -568,8 +639,9 @@ class LiveDeviceEngine:
         through the straight-line ``step`` program (cheapest per small
         append); a catch-up burst (3+ batches) is stacked into
         ``multi_step`` trains — one device program per up to 16 batches —
-        padded with no-op batches to two fixed shapes (K=4/K=16) so the
-        live path compiles at most three programs."""
+        padded with no-op batches to two fixed shapes (K=4 for up to four
+        batches, K=16 beyond) so the live path compiles at most three
+        programs."""
         if not self.pending:
             return []
         obs = self.hg.obs
@@ -578,31 +650,39 @@ class LiveDeviceEngine:
             with obs.span("live.stage",
                           ledger=("live", "stage", self.layout)) as stage:
                 drained, self.pending = self.pending, []
+                self.largest_sync = max(self.largest_sync, len(drained))
                 stage.attrs["events"] = len(drained)
                 stage.attrs["fd_updates"] = sum(len(w) for _, w in drained)
                 new_rows: List[int] = []
                 if len(self.hashes) + len(drained) > self.e_cap:
                     raise GridUnsupported("device event capacity exhausted")
 
-                # greedy chunking: cap both the batch size and the
-                # within-batch dependency depth (a creator chaining deeply
-                # in one sync would otherwise exceed the level table)
+                # greedy chunking: cap the batch size, the within-batch
+                # dependency depth (a creator chaining deeply in one sync
+                # would otherwise exceed the level table) and the batch's
+                # first-descendant updates (a wide validator set, or a
+                # withheld chain revealed at once, bursts past the staging)
                 built: List[Batch] = []
                 pos = 0
                 while pos < len(drained):
                     chunk = drained[pos : pos + self.batch_cap]
-                    chunk = self._depth_cut(chunk)
+                    chunk = self._cut(chunk)
                     pos += len(chunk)
                     batch, rows = self._build_batch(chunk)
                     built.append(batch)
                     new_rows.extend(rows)
                 sp.attrs["batches"] = len(built)
-                # trains: up to 16 batches, padded to K=4 or K=16, stacked
+                # trains: up to 16 batches, padded to K=4 or K=16, stacked.
+                # One shape per dispatch: where a sync overflows one K=16
+                # train (a revealed chain is cut every L_MAX events), its
+                # last few batches ride a second K=16 train and not the
+                # K=4 program, which a node with large syncs never
+                # compiled
                 trains = []
                 if len(built) > 2:
-                    for i in range(0, len(built), 16):
-                        group = built[i : i + 16]
-                        k = 4 if len(group) <= 4 else 16
+                    k = 4 if len(built) <= 4 else 16
+                    for i in range(0, len(built), k):
+                        group = built[i : i + k]
                         group = group + [self._empty_batch()] * (k - len(group))
                         trains.append((k, stack_batches(group)))
 
@@ -647,20 +727,27 @@ class LiveDeviceEngine:
             upd_col=np.zeros(self.upd_cap, dtype=np.int32),
             upd_val=np.zeros(self.upd_cap, dtype=np.int32),
             levels=np.full((L_MAX, b_cap), -1, dtype=np.int32),
+            sp_lamport=np.full(b_cap, -1, dtype=np.int32),
+            op_lamport=np.full(b_cap, -1, dtype=np.int32),
         )
         self._empty_batch_cache = b
         return b
 
-    def _depth_cut(self, chunk):
+    def _cut(self, chunk):
         """Longest prefix of `chunk` whose within-chunk dependency depth
-        stays under the level-table height."""
+        stays under the level-table height and whose first-descendant
+        updates fit the staging (`upd_cap`; the count here includes
+        updates to pruned rows, which _build_batch drops: an upper
+        bound). One event over the cap alone is left to _build_batch."""
         depth: Dict[str, int] = {}
-        for k, (ev, _) in enumerate(chunk):
+        updates = 0
+        for k, (ev, fd_writes) in enumerate(chunk):
             d = 0
             for parent in (ev.self_parent(), ev.other_parent()):
                 if parent in depth:
                     d = max(d, depth[parent] + 1)
-            if d >= L_MAX:
+            updates += len(fd_writes)
+            if d >= L_MAX or (k and updates > self.upd_cap):
                 return chunk[:k]
             depth[ev.hex()] = d
         return chunk
@@ -676,6 +763,7 @@ class LiveDeviceEngine:
         la_rows = np.full((b_cap, n), -1, dtype=np.int32)
         coin = np.zeros(b_cap, dtype=bool)
         fixed_round = np.full(b_cap, -1, dtype=np.int32)
+        parent_lamport = np.full((2, b_cap), -1, dtype=np.int32)
         upd: List[Tuple[int, int, int]] = []
 
         from ..hashgraph.hashgraph import middle_bit
@@ -691,12 +779,15 @@ class LiveDeviceEngine:
             index[k] = ev.index()
             sp = self.row_of.get(ev.self_parent(), -1)
             op = self.row_of.get(ev.other_parent(), -1)
+            # a rebase dropped decided history, and an event may still name
+            # it (a creator reviving after rounds of silence, a withheld
+            # chain revealed late): the parent's round lies below the base,
+            # which "no row" already says to the device, and its lamport
+            # timestamp is the host's stamp
             if sp < 0 and ev.index() != 0:
-                # a rebased engine dropped decided history: a creator
-                # reviving after rounds of silence has a pruned self-parent
-                raise GridUnsupported("self-parent outside device state")
+                parent_lamport[0, k] = self._pruned_lamport(ev.self_parent())
             if op < 0 and ev.other_parent() != "":
-                raise GridUnsupported("other-parent outside device state")
+                parent_lamport[1, k] = self._pruned_lamport(ev.other_parent())
             if sp < 0 and ev.other_parent() == "":
                 # directly root-attached: round forced to the base root's
                 # next_round (reference: hashgraph.go:207-236); first
@@ -732,7 +823,7 @@ class LiveDeviceEngine:
                 if parent >= base_row:
                     d = max(d, lvl[parent - base_row] + 1)
             lvl[k] = d
-        # caller (_depth_cut) guarantees depth < L_MAX
+        # caller (_cut) guarantees depth < L_MAX
         levels = np.full((L_MAX, b_cap), -1, dtype=np.int32)
         slot = np.zeros(L_MAX, dtype=np.int64)
         for k in range(b):
@@ -753,9 +844,26 @@ class LiveDeviceEngine:
                 sp_row=sp_row, op_row=op_row, la_rows=la_rows, coin=coin,
                 fixed_round=fixed_round,
                 upd_row=urow, upd_col=ucol, upd_val=uval, levels=levels,
+                sp_lamport=parent_lamport[0], op_lamport=parent_lamport[1],
             ),
             rows,
         )
+
+    def _pruned_lamport(self, parent: str) -> int:
+        """The host's lamport stamp of a parent that is no row of the
+        device state. A rebase runs with nothing in flight, so whatever it
+        pruned the host has stamped; anything else is not a pruned row."""
+        from ..common import StoreErr
+
+        if self.round_base == 0:
+            raise GridUnsupported("parent outside device state")
+        try:
+            lamport = self.hg.store.get_event(parent).lamport_timestamp
+        except StoreErr:
+            lamport = None
+        if lamport is None:
+            raise GridUnsupported("parent outside device state")
+        return lamport
 
 
 import functools
@@ -783,6 +891,7 @@ def _pack_results(st: IncState, lo, e_win: int, r_cap: int, n: int):
             st.famous.astype(jnp.int32).reshape(-1),
             jnp.stack([st.stale.astype(jnp.int32),
                        st.fame_lag.astype(jnp.int32), st.last_round]),
+            st.reopened,
         ])
 
 
@@ -801,9 +910,10 @@ def _unpack_results(packed, e_win: int, r_cap: int, n: int):
     fame_decided = take(r_cap * n, (r_cap, n)).astype(bool)
     famous = take(r_cap * n, (r_cap, n)).astype(bool)
     flags = take(3)
+    reopened = take(r_cap)
     return (rounds_w, lamport_w, witness_w, received_w, wtable,
             fame_decided, famous, bool(flags[0]), bool(flags[1]),
-            int(flags[2]))
+            int(flags[2]), reopened)
 
 
 def run_consensus_live(hg, queue_depth: int = None,
@@ -1092,7 +1202,7 @@ def _write_back(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
     and those are simply not covered here (the next integration handles
     them)."""
     from ..common import StoreErr, StoreErrType, is_store_err
-    from ..hashgraph import PendingRound, RoundInfo
+    from ..hashgraph import RoundInfo
 
     count, lo, base = snap["count"], snap["lo"], snap["base"]
     if base != eng.round_base:
@@ -1102,7 +1212,7 @@ def _write_back(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
             f"integration base {base} != engine base {eng.round_base}"
         )
     (rounds_w, lamport_w, witness_w, received_w, wtable, fame_decided,
-     famous, stale, fame_lag, last_round_rel) = _unpack_results(
+     famous, stale, fame_lag, last_round_rel, reopened) = _unpack_results(
         packed, eng.e_win, eng.r_cap, eng.n)
     hashes = snap["hashes"]
     new_rows = snap["new_rows"]
@@ -1180,13 +1290,10 @@ def _write_back(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
                         raise
                     ri = RoundInfo()
                 round_infos[rnum] = ri
-            if not ri.queued and (
-                hg.last_consensus_round is None
-                or rnum >= hg.last_consensus_round
-            ):
-                hg.pending_rounds.append(PendingRound(rnum, False))
-                ri.queued = True
-            ri.add_event(h, bool(at(row, witness_w)))
+            is_witness = bool(at(row, witness_w))
+            hg.queue_round(
+                rnum, ri, late_witness=is_witness and not ri.is_decided(h))
+            ri.add_event(h, is_witness)
 
     # --- DecideFame write-back (pending rounds only) ----------------------
     delegated = hg.reset_floor is not None
@@ -1200,7 +1307,6 @@ def _write_back(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
             hg.store.set_round(rnum, ri)
         hg.decide_fame()
         hg.decide_round_received()
-    decided_rounds = set()
     for pr in ([] if delegated else hg.pending_rounds):
         ri = round_infos.get(pr.index)
         if ri is None:
@@ -1218,11 +1324,11 @@ def _write_back(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
                         hashes[wrow], pr.index, bool(famous[sh, c]),
                         engine="live",
                     )
-        if ri.witnesses_decided():
-            decided_rounds.add(pr.index)
-    for pr in hg.pending_rounds:
-        if pr.index in decided_rounds:
-            pr.decided = True
+        # recompute, not just promote (as decide_fame does): a late
+        # witness in a round that is decided and still queued must unset
+        # the flag, or process_decided_rounds could settle the round
+        # around an undefined fame
+        pr.decided = ri.witnesses_decided()
 
     # --- DecideRoundReceived write-back (undetermined only) ---------------
     from .engine import admissible_receptions
@@ -1299,6 +1405,17 @@ def _write_back(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
                     hg.store.set_round(rnum, ri)
                 hg.decide_round_received()
 
+    late = reopened - eng.reopened_seen
+    if late.any():
+        # the device re-opened decided rounds for witnesses that registered
+        # late, and this write-back has served them in place: a marker
+        eng.reopened_seen = reopened.copy()  # not a view of the fetch
+        hg.obs.tracer.record(
+            "live.late_witness", hg.obs.clock.monotonic(), 0.0,
+            {"dispatch": snap["dispatch"],
+             "round": int(np.flatnonzero(late)[0]) + base,
+             "witnesses": int(late.sum())},
+        )
     if prov_cells:
         prov.mark("prov.capture", engine="live", cells=prov_cells)
     return last_round_rel
@@ -1306,12 +1423,17 @@ def _write_back(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
 
 def _capacity_soft(eng: LiveDeviceEngine, last_round_rel: int) -> bool:
     """Soft capacity-pressure predicate: the round axis needs headroom
-    for fame-decision lag (~8 rounds), the event axis for the next few
-    syncs' appends. len(eng.hashes) is the LIVE count, so rows appended
-    by still-queued dispatches are included (conservative)."""
+    for fame-decision lag (~8 rounds), the event axis for the next syncs'
+    appends: two syncs of the largest size seen, since `advance` refuses a
+    sync that does not fit and the rebase that makes room runs only after
+    a sync (a margin of four batches let a 500-event sync at 32-row
+    batches run into the end of the axis with no rebase ever tried).
+    len(eng.hashes) is the LIVE count, so rows appended by still-queued
+    dispatches are included (conservative)."""
+    margin = max(4 * eng.batch_cap, 2 * eng.largest_sync)
     return (
         last_round_rel >= eng.r_cap - 8
-        or len(eng.hashes) >= eng.e_cap - 4 * eng.batch_cap
+        or len(eng.hashes) >= eng.e_cap - margin
     )
 
 

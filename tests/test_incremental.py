@@ -217,3 +217,28 @@ def test_frontier_live_l_over_latch():
     for t in trains:
         state = frontier_train_step(state, t, grid.super_majority, grid.n)
     assert bool(state.l_over)
+
+
+def test_batches_split_at_the_update_cap():
+    """A batch whose first-descendant updates pass `upd_cap` is halved
+    instead of refused, and the halves give the state the whole gave."""
+    n, e = 8, 512
+    grid = synthetic_grid(n, e, seed=5, zipf_a=1.1, record_fd_updates=True)
+    whole = batches_from_grid(grid, 32, 8192, e)
+    burst = max(int((np.asarray(b.upd_row) != e).sum()) for b in whole)
+    cap = burst // 2
+    halves = batches_from_grid(grid, 32, cap, e)
+    assert len(halves) > len(whole)
+    assert all(int((np.asarray(b.upd_row) != e).sum()) <= cap for b in halves)
+
+    def run(batches):
+        st = init_state(n, e, 64)
+        for b in batches:
+            st = step(st, b, grid.super_majority, n, e_win=512)
+        return st
+
+    one, two = run(whole), run(halves)
+    for f in ("rounds", "lamport", "witness", "received", "fd"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(one, f)), np.asarray(getattr(two, f)), f)
+    assert not bool(two.stale) and not bool(two.fame_lag)
