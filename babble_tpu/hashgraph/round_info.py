@@ -39,24 +39,39 @@ class RoundEvent:
 class RoundInfo:
     events: Dict[str, RoundEvent] = field(default_factory=dict)
     queued: bool = False
+    # witnesses whose fame is undefined: derived from `events` like `queued`
+    # is from the pipeline, kept by the three methods that write a
+    # RoundEvent's `witness`/`famous` (add_event, set_fame, from_json), so
+    # that witnesses_decided() does not scan the round
+    _undecided: int = field(default=0, init=False, repr=False, compare=False)
+
+    def __post_init__(self) -> None:
+        self._undecided = sum(
+            1 for e in self.events.values()
+            if e.witness and e.famous == Trilean.UNDEFINED
+        )
 
     def add_event(self, x: str, witness: bool) -> None:
         if x not in self.events:
             self.events[x] = RoundEvent(witness=witness)
+            if witness:
+                self._undecided += 1
 
     def set_consensus_event(self, x: str) -> None:
         e = self.events.setdefault(x, RoundEvent())
         e.consensus = True
 
     def set_fame(self, x: str, famous: bool) -> None:
-        e = self.events.setdefault(x, RoundEvent(witness=True))
+        e = self.events.get(x)
+        if e is None:
+            e = self.events[x] = RoundEvent(witness=True)
+        elif e.witness and e.famous == Trilean.UNDEFINED:
+            self._undecided -= 1
         e.famous = Trilean.TRUE if famous else Trilean.FALSE
 
     def witnesses_decided(self) -> bool:
         """True if no witness's fame is left undefined."""
-        return all(
-            not e.witness or e.famous != Trilean.UNDEFINED for e in self.events.values()
-        )
+        return self._undecided == 0
 
     def witnesses(self) -> List[str]:
         return [x for x, e in self.events.items() if e.witness]
@@ -88,9 +103,13 @@ class RoundInfo:
 
     @classmethod
     def from_json(cls, d: dict) -> "RoundInfo":
-        ri = cls(queued=False)
-        for x, e in d.get("Events", {}).items():
-            ri.events[x] = RoundEvent(
-                consensus=e["Consensus"], witness=e["Witness"], famous=Trilean(e["Famous"])
-            )
-        return ri
+        return cls(
+            events={
+                x: RoundEvent(
+                    consensus=e["Consensus"], witness=e["Witness"],
+                    famous=Trilean(e["Famous"]),
+                )
+                for x, e in d.get("Events", {}).items()
+            },
+            queued=False,
+        )

@@ -13,7 +13,7 @@ output is byte-identical by construction once rounds/fame/received match.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional
+from typing import Dict, Optional
 
 import numpy as np
 
@@ -422,27 +422,58 @@ def admissible_receptions(hg, round_infos, proposed) -> bool:
     composition and diverge block bodies from a host-engine peer."""
     from ..common import StoreErr
 
+    def _decided(i) -> bool:
+        ri = round_infos.get(i)
+        if ri is None:
+            try:
+                ri = hg.store.get_round(i)
+            except StoreErr:
+                return hg.reset_floor is not None and i <= hg.reset_floor
+        return ri.witnesses_decided()
+
+    # the answer for a round is the same for every proposal of one call:
+    # each round the proposals cross is resolved once, at this call,
+    # against the host's state, and the proposals read the table
+    table: Dict[int, bool] = {}
+    try:
+        for h, rr in proposed:
+            r0 = hg.store.get_event(h).round
+            if r0 is None:
+                # the host rule checks every round in (round(x), rr]; with
+                # the event's round unknown that range is unknowable — force
+                # the host's own reception pass rather than guess
+                # (DivideRounds write-back normally runs first, but nothing
+                # enforces it)
+                return False
+            for i in range(r0 + 1, rr + 1):
+                ok = table.get(i)
+                if ok is None:
+                    ok = table[i] = _decided(i)
+                if not ok:
+                    return False
+        return True
+    finally:
+        if table:
+            hg.obs.tracer.add("admissible.rounds", 0.0, count=len(table))
+
+
+def stamp_receptions(hg, round_infos, proposed) -> int:
+    """Stamp the receptions `admissible_receptions` admitted into their
+    events and rounds (`round_infos` collects the rounds touched; the caller
+    stores them). Returns the provenance cells noted."""
+    prov = hg.obs.provenance
+    cells = 0
     for h, rr in proposed:
         ev = hg.store.get_event(h)
-        if ev.round is None:
-            # the host rule checks every round in (round(x), rr]; with the
-            # event's round unknown that range is unknowable — force the
-            # host's own reception pass rather than guess (DivideRounds
-            # write-back normally runs first, but nothing enforces it)
-            return False
-        r0 = ev.round
-        for i in range(r0 + 1, rr + 1):
-            ri = round_infos.get(i)
-            if ri is None:
-                try:
-                    ri = hg.store.get_round(i)
-                except StoreErr:
-                    if hg.reset_floor is not None and i <= hg.reset_floor:
-                        continue
-                    return False
-            if not ri.witnesses_decided():
-                return False
-    return True
+        ev.set_round_received(rr)
+        cells += prov.note_received(h, rr)
+        hg.store.set_event(ev)
+        tri = round_infos.get(rr)
+        if tri is None:
+            tri = hg.store.get_round(rr)
+            round_infos[rr] = tri
+        tri.set_consensus_event(h)
+    return cells
 
 
 def run_consensus_device(hg, d_max: Optional[int] = None, mesh=None) -> None:
@@ -732,34 +763,17 @@ def integrate_pass_results(hg, grid, res, topo_hi: Optional[int] = None,
                 return None
         raise GridUnsupported(f"undetermined event unmodeled ({h[:18]}…)")
 
-    def _proposed():
-        for h in hg.undetermined_events:
-            row = _covered(h)
-            if row is None:
-                continue
-            rr = int(res.received[row])
-            if rr >= 0:
-                yield h, rr
-
-    rr_clean = admissible_receptions(hg, round_infos, _proposed())
-    if rr_clean:
-        new_undetermined = []
-        for h in hg.undetermined_events:
-            row = _covered(h)
-            rr = -1 if row is None else int(res.received[row])
-            if rr >= 0:
-                ev = hg.store.get_event(h)
-                ev.set_round_received(rr)
-                prov_cells += prov.note_received(h, rr)
-                hg.store.set_event(ev)
-                tri = round_infos.get(rr)
-                if tri is None:
-                    tri = hg.store.get_round(rr)
-                    round_infos[rr] = tri
-                tri.set_consensus_event(h)
-            else:
-                new_undetermined.append(h)
-        hg.undetermined_events = new_undetermined
+    proposed, left = [], []
+    for h in hg.undetermined_events:
+        row = _covered(h)
+        rr = -1 if row is None else int(res.received[row])
+        if rr >= 0:
+            proposed.append((h, rr))
+        else:
+            left.append(h)
+    if admissible_receptions(hg, round_infos, proposed):
+        prov_cells += stamp_receptions(hg, round_infos, proposed)
+        hg.undetermined_events = left
 
         for rnum, ri in round_infos.items():
             hg.store.set_round(rnum, ri)

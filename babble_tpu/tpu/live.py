@@ -1331,7 +1331,7 @@ def _write_back(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
         pr.decided = ri.witnesses_decided()
 
     # --- DecideRoundReceived write-back (undetermined only) ---------------
-    from .engine import admissible_receptions
+    from .engine import admissible_receptions, stamp_receptions
 
     def _covered(h):
         """Row for h in THIS dispatch, None if h postdates it (pipelined
@@ -1358,39 +1358,23 @@ def _write_back(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
         # block composition)
         raise GridUnsupported(f"undetermined event unmodeled ({h[:18]}…)")
 
-    def _proposed_receptions():
-        for h in hg.undetermined_events:
-            row = _covered(h)
-            if row is None:
-                continue
-            rr = int(at(row, received_w))
-            if rr >= 0:
-                yield h, rr + base
-
     if not delegated:
+        # one walk over the undetermined events: what it proposes is what
+        # the gate checks and, admitted, what is stamped
         with hg.obs.span("live.admissible") as sp:
-            proposed = list(_proposed_receptions())
-            sp.attrs["proposed"] = len(proposed)
-            admissible = admissible_receptions(hg, round_infos, proposed)
-        if admissible:
-            new_undetermined = []
+            proposed, left = [], []
             for h in hg.undetermined_events:
                 row = _covered(h)
                 rr = -1 if row is None else int(at(row, received_w))
                 if rr >= 0:
-                    rr += base
-                    ev = hg.store.get_event(h)
-                    ev.set_round_received(rr)
-                    prov_cells += prov.note_received(h, rr)
-                    hg.store.set_event(ev)
-                    tri = round_infos.get(rr)
-                    if tri is None:
-                        tri = hg.store.get_round(rr)
-                        round_infos[rr] = tri
-                    tri.set_consensus_event(h)
+                    proposed.append((h, rr + base))
                 else:
-                    new_undetermined.append(h)
-            hg.undetermined_events = new_undetermined
+                    left.append(h)
+            sp.attrs["proposed"] = len(proposed)
+            admissible = admissible_receptions(hg, round_infos, proposed)
+        if admissible:
+            prov_cells += stamp_receptions(hg, round_infos, proposed)
+            hg.undetermined_events = left
 
             for rnum, ri in round_infos.items():
                 hg.store.set_round(rnum, ri)

@@ -12,7 +12,6 @@ from babble_tpu.common import StoreErr
 from babble_tpu.hashgraph import (
     Hashgraph,
     InmemStore,
-    RoundEvent,
     RoundInfo,
     SQLiteStore,
     Trilean,
@@ -152,7 +151,7 @@ class TestRoundDag:
     def _set_round0_witnesses(self):
         ri = RoundInfo()
         for name in ("e0", "e1", "e2"):
-            ri.events[self.index[name]] = RoundEvent(witness=True)
+            ri.add_event(self.index[name], True)
         self.h.store.set_round(0, ri)
 
     def test_insert_event_coordinates(self):
@@ -246,7 +245,7 @@ class TestRoundDag:
     def test_witness(self):
         self._set_round0_witnesses()
         ri = RoundInfo()
-        ri.events[self.index["f1"]] = RoundEvent(witness=True)
+        ri.add_event(self.index["f1"], True)
         self.h.store.set_round(1, ri)
 
         for name, val in [
@@ -533,6 +532,82 @@ class TestConsensusPipeline:
         assert len(blocks1) == len(blocks2) > 0
         for b1, b2 in zip(blocks1, blocks2):
             assert b1.body.marshal() == b2.body.marshal()
+
+
+# ---------------------------------------------------------------------------
+# RoundInfo's decided state: a count kept where the flags change, equal to
+# the scan over the round's events after every step
+# ---------------------------------------------------------------------------
+
+
+def scan_witnesses_decided(ri):
+    """The scan `RoundInfo.witnesses_decided` was before it kept a count."""
+    return all(
+        not e.witness or e.famous != Trilean.UNDEFINED for e in ri.events.values()
+    )
+
+
+ROUND_INFO_SEQUENCES = {
+    "witnesses_then_fame": [
+        ("add", "w0", True), ("add", "x0", False), ("add", "w1", True),
+        ("fame", "w0", True), ("add", "x1", False), ("fame", "w1", False),
+    ],
+    "known_event_added_again": [
+        ("add", "w0", True), ("add", "w0", True), ("add", "w0", False),
+        ("fame", "w0", True), ("add", "w0", True),
+    ],
+    "fame_set_twice": [
+        ("add", "w0", True), ("fame", "w0", True), ("fame", "w0", False),
+        ("fame", "w0", False),
+    ],
+    "late_witness_reopens_a_decided_round": [
+        ("add", "w0", True), ("add", "w1", True), ("fame", "w0", True),
+        ("fame", "w1", True), ("consensus", "x0"), ("add", "late", True),
+        ("json",), ("fame", "late", False),
+    ],
+    "fame_on_an_unseen_witness": [
+        ("fame", "u0", True), ("add", "u0", True), ("add", "w0", True),
+        ("fame", "u1", False), ("fame", "w0", True),
+    ],
+    "consensus_event_first": [
+        ("consensus", "c0"), ("add", "c0", True), ("fame", "c0", True),
+        ("add", "w0", True), ("consensus", "w0"), ("fame", "w0", False),
+    ],
+    "fame_on_a_plain_event": [
+        ("add", "x0", False), ("fame", "x0", True), ("add", "w0", True),
+        ("fame", "x0", False), ("fame", "w0", True),
+    ],
+    "store_round_trips": [
+        ("json",), ("add", "w0", True), ("json",), ("add", "w1", True),
+        ("fame", "w0", True), ("json",), ("fame", "w1", True), ("json",),
+        ("add", "late", True), ("json",), ("fame", "late", True),
+    ],
+}
+
+
+@pytest.mark.parametrize("name", sorted(ROUND_INFO_SEQUENCES))
+def test_round_info_count_equals_the_scan(name):
+    ri = RoundInfo()
+    assert ri.witnesses_decided() and scan_witnesses_decided(ri)
+    seen = set()
+    for step, op in enumerate(ROUND_INFO_SEQUENCES[name]):
+        if op[0] == "add":
+            ri.add_event(op[1], op[2])
+        elif op[0] == "fame":
+            ri.set_fame(op[1], op[2])
+        elif op[0] == "consensus":
+            ri.set_consensus_event(op[1])
+        else:
+            wire = ri.to_json()
+            assert set(wire) == {"Events"}  # the count is derived, not stored
+            assert all(set(e) == {"Consensus", "Witness", "Famous"}
+                       for e in wire["Events"].values())
+            back = RoundInfo.from_json(wire)
+            assert back.to_json() == wire and back.events == ri.events
+            ri = back
+        assert ri.witnesses_decided() == scan_witnesses_decided(ri), (name, step, op)
+        seen.add(ri.witnesses_decided())
+    assert seen == {True, False}, name  # every sequence opens and closes
 
 
 # ---------------------------------------------------------------------------

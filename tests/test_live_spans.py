@@ -6,8 +6,9 @@ What is held: every live/device/commit span hangs under a
 `core.run_consensus` root (but for the flush's), the `dispatch` id ties
 one dispatch's launch, fetch and integration across calls, the totals
 count what the program did (events inserted, blocks committed), one
-interval is booked once, a reception the host rule refuses is counted, and
-frame building reads the rounds the engine stamped and derives none.
+interval is booked once, a reception the host rule refuses is counted,
+frame building reads the rounds the engine stamped and derives none, and
+the admissibility gate looks each round up once an integration.
 """
 
 import pytest
@@ -16,6 +17,7 @@ from babble_tpu.crypto import derive_key, pub_key_bytes
 from babble_tpu.hashgraph import Event, InmemStore, root_self_parent
 from babble_tpu.node import Core
 from babble_tpu.peers import Peer, Peers
+from babble_tpu.tpu import engine as engine_mod
 from babble_tpu.tpu import live as live_mod
 from babble_tpu.tpu.grid import synthetic_grid
 
@@ -281,6 +283,41 @@ def test_refused_reception_is_counted_and_repaired(monkeypatch, cpu_blocks):
     assert obs.tracer.totals()["live.host_repair"][0] == repaired
     assert core.ladder_rung() == "live"
     assert blocks == cpu_blocks
+
+
+@pytest.mark.parametrize("discipline", ["sync", "pipelined"])
+def test_gate_asks_each_round_once(discipline, monkeypatch, cpu_blocks):
+    """`admissible.rounds` counts the gate's round look-ups: in every
+    integration at most one for each distinct round its proposals cross
+    (all of them when it admits), whatever the number of proposals."""
+    monkeypatch.setitem(live_mod.ENGINE_DEFAULTS, "async_fetch",
+                        discipline == "pipelined")
+    real = engine_mod.admissible_receptions
+    calls = []
+
+    def gate(hg, round_infos, proposed):
+        def lookups():
+            return hg.obs.tracer.totals().get("admissible.rounds", (0, 0.0))[0]
+
+        crossed = {i for h, rr in proposed
+                   for i in range(hg.store.get_event(h).round + 1, rr + 1)}
+        before = lookups()
+        admitted = real(hg, round_infos, proposed)
+        calls.append((len(proposed), len(crossed), lookups() - before, admitted))
+        return admitted
+
+    monkeypatch.setattr(engine_mod, "admissible_receptions", gate)
+    core, blocks, _ = drive("tpu")
+    assert core.ladder_rung() == "live" and core.live_demotions == 0
+    assert blocks == cpu_blocks
+    assert all(looked <= crossed for _, crossed, looked, _ in calls)
+    assert all(looked == crossed for _, crossed, looked, ok in calls if ok)
+    assert any(n > crossed > 0 for n, crossed, _, _ in calls)  # rounds are shared
+    totals = core.hg.obs.tracer.totals()
+    assert totals["admissible.rounds"] == (sum(c[2] for c in calls), 0.0)
+    assert sum(s.attrs["proposed"] for s in named(
+        core.hg.obs.tracer.spans(), "live.admissible")) == sum(c[0] for c in calls)
+    assert "live.host_repair" not in totals
 
 
 def test_rebase_has_a_span(monkeypatch, cpu_blocks):
