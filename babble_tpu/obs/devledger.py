@@ -13,7 +13,7 @@ per (rung, pass, layout, component) with component one of
     integrate host write-back of pass results
 
 — by wrapping every host call into a staged callable in a *seam*
-(`ledger_call` / `DeviceLedger.call`). The 24 `# kernel-contract:`
+(`ledger_call` / `DeviceLedger.call`). The 19 `# kernel-contract:`
 entry points (analysis/staged.py, PR 18) map onto seams via
 `ENTRY_INFO`: entries whose trace lives inside another staged body
 (e.g. `_divide_rounds` inside `consensus_pipeline`) carry a
@@ -90,15 +90,9 @@ ENTRY_INFO: Dict[str, Tuple[str, str, Optional[str]]] = {
     "build_inv": ("frontier", "inv", None),
     "_frontier_rounds": ("frontier", "walk", "frontier_pipeline"),
     "frontier_pipeline": ("frontier", "pipeline", None),
-    # tpu/frontier_live.py — frontier train steps
-    "_decide": ("frontier_live", "decide", "frontier_train_step"),
-    "frontier_train_step": ("frontier_live", "train", None),
-    "frontier_multi_train": ("frontier_live", "multi_train", None),
     # tpu/incremental.py — resident live-engine steps
     "_step_full": ("incremental", "step", None),
     "multi_step": ("incremental", "multi_step", None),
-    "train_step": ("incremental", "train", None),
-    "multi_train": ("incremental", "multi_train", None),
     # tpu/doubling.py — log-diameter cold path
     "_closure_la": ("doubling", "closure", None),
     "_walk_chunk": ("doubling", "walk", None),

@@ -217,48 +217,24 @@ def _m0_binsearch(fd_w, w_ok, rb, chain_len, la, super_majority: int, l: int):
     return jnp.where(hi < chain_len, hi, sent)
 
 
-def make_walk_step(inv_f32, rows_by, fd, la, super_majority: int,
-                   m0_mode: str = "auto"):
+def make_walk_step(inv_f32, rows_by, fd, la, super_majority: int):
     """Build the one-round frontier transition X(r) -> X(r+1) over the
-    given tables. Shared by the full walk (_frontier_rounds) and the
-    warm-start windowed walk of the live engine (frontier_live.py).
-    m0_mode: "auto" picks by N (M0_BINSEARCH_MIN_N), or force
-    "binsearch"/"sort".
-
-    fd may be None: first-descendant rows are then derived from INV via
-    the identity fd[e, p] == INV[p, creator(e), index(e)] (the first
-    chain-p index whose creator(e)-coordinate reaches index(e) IS e's
-    first descendant on chain p) — the frontier-live engine maintains only
-    INV and never materializes an fd matrix."""
+    given tables, for the walk of `_frontier_rounds`. The first chain
+    index that strongly sees the frontier is found by binary search from
+    M0_BINSEARCH_MIN_N validators up (it needs `la`), by the sort-based
+    einsum below that."""
     n, l = rows_by.shape
     sent = jnp.int32(l)
     rb = jnp.maximum(rows_by, 0)
     cc = jnp.arange(n)
     vv = jnp.arange(l)
-    use_binsearch = (
-        m0_mode == "binsearch"
-        or (m0_mode == "auto" and n >= M0_BINSEARCH_MIN_N and la is not None)
-    )
+    use_binsearch = n >= M0_BINSEARCH_MIN_N and la is not None
     chain_len = jnp.sum(rows_by >= 0, axis=1).astype(jnp.int32)
 
     def step(x_cur):
         w_ok = x_cur < sent
-        if fd is None:
-            # fd_w[c, p] = INV[p, c, x_cur[c]] — one-hot contraction over
-            # the value axis; INV's sentinel l maps to "no descendant"
-            oh_x = (
-                jnp.clip(x_cur, 0, l - 1)[:, None] == vv[None, :]
-            ).astype(jnp.float32)  # (C, V)
-            fdw = jnp.einsum(
-                "cv,pcv->cp", oh_x, inv_f32,
-                precision=jax.lax.Precision.HIGHEST,
-            ).astype(jnp.int32)
-            fd_w = jnp.where(
-                w_ok[:, None] & (fdw < sent), fdw, MAX_INT32
-            )  # (N_w, N_p)
-        else:
-            w_row = rb[cc, jnp.clip(x_cur, 0, l - 1)]  # (N,)
-            fd_w = jnp.where(w_ok[:, None], fd[w_row], MAX_INT32)  # (N_w, N_p)
+        w_row = rb[cc, jnp.clip(x_cur, 0, l - 1)]  # (N,)
+        fd_w = jnp.where(w_ok[:, None], fd[w_row], MAX_INT32)  # (N_w, N_p)
 
         if use_binsearch:
             m0 = _m0_binsearch(

@@ -1,7 +1,18 @@
-"""Incremental device consensus: persistent on-device DAG state advanced by
-gossip-sized append batches (SURVEY §7 hard-part #2; the reference's
+"""The live rung's device program: persistent on-device DAG state advanced
+by gossip-sized append batches (SURVEY §7 hard-part #2; the reference's
 UndeterminedEvents + memo-cache discipline, src/hashgraph/hashgraph.go:36-40,
 767-780, recast as device-resident buffers + delta scatters).
+
+What the file holds, in order: the state (`IncState`), one append batch
+(`Batch`), the append body and the fame + round-received body
+(`_step_body`, `_decide_body`), the two jitted programs over them — `step`
+(one batch, then decide) and `multi_step` (a scan of K batches, then one
+decide) — and the host-side builder that slices a recorded grid into
+batches (`batches_from_grid`). `tpu/live.py` is the caller: its
+`LiveDeviceEngine` builds the same `Batch` from a node's inserts, launches
+`step` for a sync of one or two batches and `multi_step` trains padded to
+K=4 or K=16 beyond that, and fetches the decisions. It is the one append
+engine of the package.
 
 Per batch the host ships only O(batch) data:
 - the new rows' coordinates (lastAncestors), identity and parent pointers;
@@ -19,10 +30,12 @@ row->witness-slot map. This removes the per-step dynamic row gathers
 remaining index-domain lookup (creator -> column of min_la) is a one-hot
 matmul on the MXU.
 
-The jitted step donates the state pytree, so XLA updates the buffers in
+The jitted programs donate the state pytree, so XLA updates the buffers in
 place: no reupload, no growth in host<->device traffic with DAG size.
-Bit-exactness: bench_incremental.py checks final rounds/lamport/witness/
-received equality against the one-shot pipeline on the same DAG.
+Bit-exactness: tests/test_incremental.py holds the final rounds/lamport/
+witness/received equal to the one-shot pipeline's on the same DAG, through
+`step` and through `multi_step`; on the chip every benchmark cell's
+`correct` holds them to the plain reference.
 """
 
 from __future__ import annotations
@@ -37,12 +50,6 @@ import numpy as np
 from .kernels import MAX_INT32, received_core, suffix_min
 from .grid import DagGrid
 from .packed import pack_bits, pack_votes_t, packed_count, packed_tally, popcount_sum
-
-# cap for "no first descendant yet" sentinels on the fp32/MXU compare path:
-# every real event index is < 2^24 (fp32-exact), so a 2^24 sentinel loses
-# exactly like MAX_INT32 against any real last-ancestor index
-FD_CLAMP = np.int32(1 << 24)
-
 
 class IncState(NamedTuple):
     """Device-resident DAG state (E_cap rows, R_cap rounds)."""
@@ -225,8 +232,7 @@ def _fame_window(w_valid, la_w, fd_w, idx_w, coin_w, last_round_rel,
 
 @jax.named_scope("live.deltas")
 def _apply_deltas_and_stage(state: IncState, b):
-    """Shared front half of the per-batch and train bodies (`b` is a Batch
-    or a Train — same field names):
+    """Front half of the append body (`_step_body`):
 
     1. min-scatter the whole batch's first-descendant deltas (each cell is
        written at most once, ever, so the scatter is order-free), mirrored
@@ -578,323 +584,6 @@ def stack_batches(batches):
     ])
 
 
-class Train(NamedTuple):
-    """A flattened run of append batches processed as ONE device program.
-
-    Unlike ``multi_step`` (a scan of per-batch bodies, each scattering into
-    the full (E_cap, N) state arrays), a Train keeps the new rows' rounds/
-    lamport/witness in small (KB,) train-local buffers during the level
-    scan and writes the big arrays exactly once at the end — the per-level
-    work touches only the dense witness buffers. Level table positions are
-    train-local; ``sp_pos``/``op_pos`` point at in-train parents (-1 when
-    the parent is pre-train state, in which case the pre-gathered state
-    values are used)."""
-
-    rows: jax.Array  # (KB,) int32 target rows, -1 padding
-    creator: jax.Array  # (KB,) int32
-    index: jax.Array  # (KB,) int32 (MAX = padding)
-    sp_row: jax.Array  # (KB,) int32 global row (-1 = root-attached)
-    op_row: jax.Array  # (KB,) int32 global row (-1 = none)
-    sp_pos: jax.Array  # (KB,) int32 train-local position (-1 = pre-train)
-    op_pos: jax.Array  # (KB,) int32
-    la_rows: jax.Array  # (KB, N) int32
-    coin: jax.Array  # (KB,) bool
-    fixed_round: jax.Array  # (KB,) int32 (-1 = compute)
-    upd_row: jax.Array  # (U,) int32 fd-update rows (E_cap = padding)
-    upd_col: jax.Array  # (U,) int32
-    upd_val: jax.Array  # (U,) int32
-    levels: jax.Array  # (T, W) int32 train-local positions, -1 padding
-    # host-maintained lamport timestamps (the insert path knows parents'
-    # lamports at insert time); the level-scan train body computes its own
-    # on device and ignores this, the frontier-live engine consumes it
-    lamport: jax.Array  # (KB,) int32
-
-
-def _train_body(state: IncState, train: Train, super_majority: int,
-                n_participants: int, packed: bool = False) -> IncState:
-    """Append a whole train: deltas + row staging once, then a level scan
-    over small buffers, then one write-back scatter. Bit-identical to
-    running the constituent batches through ``_step_body`` one by one
-    (gated by tests): fd cells are write-once so pre-applying the train's
-    deltas is order-insensitive, and ``la_e >= fd`` is exact DAG
-    reachability whenever the referenced events exist — which topological
-    insert order guarantees."""
-    e_cap, n = state.la.shape
-    r_cap = state.wtable.shape[0]
-    kb = train.rows.shape[0]
-    assert e_cap < int(FD_CLAMP), "event capacity exceeds fp32-exact range"
-
-    # 1-2. deltas + row staging, shared with the per-batch body. In-train
-    #      witnesses copy a fully-updated fd row at registration, so the
-    #      slot-map mirror only has to cover pre-train witnesses.
-    fd, fd_w, la, creator, index, valid, tgt = _apply_deltas_and_stage(
-        state, train
-    )
-
-    # 3. pre-gathers: per-row fd snapshots (immutable for the rest of the
-    #    train) and pre-train parent rounds/lamports
-    fd_rows_all = fd.at[tgt].get(mode="fill", fill_value=MAX_INT32)  # (KB, N)
-    sp_g = jnp.where(train.sp_row >= 0, train.sp_row, e_cap)
-    op_g = jnp.where(train.op_row >= 0, train.op_row, e_cap)
-    sp_round_pre = state.rounds.at[sp_g].get(mode="fill", fill_value=-1)
-    op_round_pre = state.rounds.at[op_g].get(mode="fill", fill_value=-1)
-    sp_lt_pre = state.lamport.at[sp_g].get(mode="fill", fill_value=-1)
-    op_lt_pre = state.lamport.at[op_g].get(mode="fill", fill_value=-1)
-
-    # 4. level scan. TPU-first formulation: every carry-dependent dynamic
-    #    row gather is a one-hot fp32 matmul on the MXU (a data-dependent
-    #    gather from an HBM-resident buffer serializes into per-row DMAs —
-    #    measured ~180us/step vs ~5us for the matmul form), and the witness
-    #    buffers are NOT written in the scan at all — registrations are
-    #    replayed as one bulk scatter afterwards (each (round, creator)
-    #    witness slot is claimed by at most one event per train, so the
-    #    post-scan replay is order-free). fp32 is exact for every value
-    #    involved: indices and rows are < 2^24 (FD_CLAMP caps the MAX
-    #    sentinels) and -1 is representable.
-    fd_rows_cmp = jnp.minimum(fd_rows_all, FD_CLAMP)
-    fd_w_f = jnp.minimum(fd_w, FD_CLAMP).astype(jnp.float32).reshape(
-        r_cap, n * n
-    )
-    wv_f = (state.wtable >= 0).astype(jnp.float32)  # (R, N)
-    r_iota = jnp.arange(r_cap)
-    kb_iota = jnp.arange(kb)
-    hi = jax.lax.Precision.HIGHEST
-
-    def level_step(carry, pos):
-        rounds_b, lamport_b, witness_b, fd_w_f, wv_f, below_base = carry
-        w = pos.shape[0]
-        pvalid = pos >= 0
-        p = jnp.maximum(pos, 0)
-
-        sp_p = train.sp_pos[p]
-        op_p = train.op_pos[p]
-        # parent rounds/lamports from the train-local carry, via one-hot
-        # matvecs against the stacked (KB, 2) table
-        rl = jnp.stack([rounds_b, lamport_b], axis=1).astype(jnp.float32)
-        oh_sp = (jnp.maximum(sp_p, 0)[:, None] == kb_iota[None, :]).astype(
-            jnp.float32)
-        oh_op = (jnp.maximum(op_p, 0)[:, None] == kb_iota[None, :]).astype(
-            jnp.float32)
-        sp_rl = jnp.matmul(oh_sp, rl, precision=hi).astype(jnp.int32)
-        op_rl = jnp.matmul(oh_op, rl, precision=hi).astype(jnp.int32)
-        sp_round = jnp.where(sp_p >= 0, sp_rl[:, 0], sp_round_pre[p])
-        op_round = jnp.where(op_p >= 0, op_rl[:, 0], op_round_pre[p])
-        parent_round = jnp.maximum(sp_round, op_round)
-
-        pr = jnp.clip(parent_round, 0, r_cap - 1)
-        oh_pr = (pr[:, None] == r_iota[None, :]).astype(jnp.float32)  # (W,R)
-        fd_ws = jnp.matmul(oh_pr, fd_w_f, precision=hi).reshape(w, n, n)
-        wvalid = (
-            (jnp.matmul(oh_pr, wv_f, precision=hi) > 0.5)
-            & (parent_round[:, None] >= 0)
-        )  # (W, N)
-        la_e_f = train.la_rows[p].astype(jnp.float32)  # (W, N)
-        if packed:
-            counts = packed_count(la_e_f[:, None, :] >= fd_ws)
-            ss = (counts >= super_majority) & wvalid
-            c_seen = packed_count(ss)
-        else:
-            counts = jnp.sum(
-                la_e_f[:, None, :] >= fd_ws, axis=-1, dtype=jnp.int32)
-            ss = (counts >= super_majority) & wvalid
-            c_seen = jnp.sum(ss, axis=-1, dtype=jnp.int32)
-
-        new_round = parent_round + (c_seen >= super_majority).astype(jnp.int32)
-        fixed = train.fixed_round[p]
-        new_round = jnp.where(fixed >= 0, fixed, new_round)
-        new_witness = new_round > sp_round
-        below_base = below_base | jnp.any(
-            pvalid & (fixed < 0) & (parent_round < 0))
-
-        sp_lt = jnp.where(sp_p >= 0, sp_rl[:, 1], sp_lt_pre[p])
-        op_lt = jnp.where(op_p >= 0, op_rl[:, 1], op_lt_pre[p])
-        new_lt = jnp.maximum(sp_lt, op_lt) + 1
-
-        # padded entries get DISTINCT out-of-range targets so every scatter
-        # can promise unique indices to XLA (a duplicate dropped index
-        # would be UB under unique_indices=True)
-        iota_w = jnp.arange(w)
-        tp = jnp.where(pvalid, p, kb + iota_w)
-        rounds_b = rounds_b.at[tp].set(
-            new_round, mode="drop", unique_indices=True)
-        lamport_b = lamport_b.at[tp].set(
-            new_lt, mode="drop", unique_indices=True)
-        witness_b = witness_b.at[tp].set(
-            new_witness, mode="drop", unique_indices=True)
-
-        w_mask = pvalid & new_witness
-        c = train.creator[p]
-        wr = jnp.clip(new_round, 0, r_cap - 1)
-        # creators within a level are distinct (same-creator events chain
-        # through self-parents into deeper levels), so slots are unique
-        slot = jnp.where(w_mask, wr * n + c, r_cap * n + iota_w)
-        fd_w_f = fd_w_f.reshape(r_cap * n, n).at[slot].set(
-            fd_rows_cmp[p].astype(jnp.float32), mode="drop",
-            unique_indices=True,
-        ).reshape(r_cap, n * n)
-        wv_f = wv_f.reshape(r_cap * n).at[slot].set(
-            1.0, mode="drop", unique_indices=True
-        ).reshape(r_cap, n)
-        return (rounds_b, lamport_b, witness_b, fd_w_f, wv_f,
-                below_base), None
-
-    carry0 = (
-        jnp.full((kb,), -1, jnp.int32),
-        jnp.full((kb,), -1, jnp.int32),
-        jnp.zeros((kb,), bool),
-        fd_w_f, wv_f, jnp.bool_(False),
-    )
-    carry, _ = jax.lax.scan(level_step, carry0, train.levels)
-    rounds_b, lamport_b, witness_b, _, _, below_base = carry
-
-    # 5. bulk post-scan registration of this train's witnesses (the scan
-    #    only tracked the fp32 compare copies) + one write-back scatter
-    #    into the big arrays
-    # registration only for rounds within capacity: clipping an overflowed
-    # round onto row r_cap-1 could alias two same-creator witnesses into
-    # one slot and break the uniqueness promise below. Such a state is
-    # already latched unreliable (the overflow flag fires at r_cap-1), so
-    # dropping the overflow registrations loses nothing.
-    w_mask_b = witness_b & valid & (rounds_b < r_cap)
-    wr_b = jnp.clip(rounds_b, 0, r_cap - 1)
-    slot_b = jnp.where(
-        w_mask_b, wr_b * n + train.creator, r_cap * n + jnp.arange(kb)
-    )
-    wtable = state.wtable.reshape(r_cap * n).at[slot_b].set(
-        train.rows, mode="drop", unique_indices=True
-    ).reshape(r_cap, n)
-    la_w = state.la_w.reshape(r_cap * n, n).at[slot_b].set(
-        train.la_rows, mode="drop", unique_indices=True
-    ).reshape(r_cap, n, n)
-    fd_w = fd_w.reshape(r_cap * n, n).at[slot_b].set(
-        fd_rows_cmp, mode="drop", unique_indices=True
-    ).reshape(r_cap, n, n)
-    idx_w = state.idx_w.reshape(r_cap * n).at[slot_b].set(
-        train.index, mode="drop", unique_indices=True
-    ).reshape(r_cap, n)
-    coin_w = state.coin_w.reshape(r_cap * n).at[slot_b].set(
-        train.coin, mode="drop", unique_indices=True
-    ).reshape(r_cap, n)
-
-    rounds = state.rounds.at[tgt].set(rounds_b, mode="drop")
-    lamport = state.lamport.at[tgt].set(lamport_b, mode="drop")
-    witness = state.witness.at[tgt].set(witness_b, mode="drop")
-    w_of_row = state.w_of_row.at[
-        jnp.where(w_mask_b, tgt, e_cap)
-    ].set(wr_b * n + train.creator, mode="drop")
-
-    last_round = jnp.maximum(
-        state.last_round, jnp.max(jnp.where(valid, rounds_b, -1))
-    )
-    count = state.count + jnp.sum(valid, dtype=jnp.int32)
-    overflow = last_round >= r_cap - 1
-
-    # base latch and late witnesses: see _step_body
-    overflow = overflow | below_base
-    rounds_decided, reopened = _reopen_rounds(
-        state, rounds_b, witness_b, valid)
-
-    return state._replace(
-        la=la, fd=fd, creator=creator, index=index,
-        rounds=rounds, lamport=lamport, witness=witness,
-        w_of_row=w_of_row, wtable=wtable,
-        la_w=la_w, fd_w=fd_w, idx_w=idx_w, coin_w=coin_w,
-        rounds_decided=rounds_decided, reopened=reopened,
-        last_round=last_round, count=count,
-        stale=state.stale | overflow,
-    )
-
-
-# kernel-contract: train_step
-#   in: state:pytree train:pytree
-#   static: super_majority n_participants r_win e_win packed
-#   donate: state
-#   rung: incremental
-#   out: IncState after one whole append train + one decide
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "super_majority", "n_participants", "r_win", "e_win", "packed",
-    ),
-    donate_argnames=("state",),
-)
-def train_step(
-    state: IncState,
-    train: Train,
-    super_majority: int,
-    n_participants: int,
-    r_win: int = 32,
-    e_win: int = 8192,
-    packed: bool = False,
-) -> IncState:
-    """One whole append train + one fame/round-received pass, as a single
-    device program. The throughput path of the incremental engine."""
-    return _decide_body(
-        _train_body(state, train, super_majority, n_participants,
-                    packed=packed),
-        super_majority, n_participants, r_win=r_win, e_win=e_win,
-        packed=packed,
-    )
-
-
-# kernel-contract: multi_train
-#   in: state:pytree stacked:pytree
-#   static: super_majority n_participants r_win e_win packed
-#   donate: state
-#   rung: incremental
-#   out: IncState after K scanned trains + one decide
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "super_majority", "n_participants", "r_win", "e_win", "packed",
-    ),
-    donate_argnames=("state",),
-)
-def multi_train(
-    state: IncState,
-    stacked: Train,  # every field stacked along a leading K axis
-    super_majority: int,
-    n_participants: int,
-    r_win: int = 32,
-    e_win: int = 8192,
-    packed: bool = False,
-) -> IncState:
-    """Apply K whole trains in ONE device program (scan of _train_body)
-    followed by one fame + round-received pass. The offline-replay
-    throughput path: amortizes the per-execute launch cost over
-    K*train_size events. Bit-identical to per-train train_step calls
-    (decisions are timing-independent, see _decide_body)."""
-
-    def body(st, t):
-        return _train_body(st, t, super_majority, n_participants,
-                           packed=packed), None
-
-    out, _ = jax.lax.scan(body, state, stacked)
-    return _decide_body(out, super_majority, n_participants,
-                        r_win=r_win, e_win=e_win, packed=packed)
-
-
-def stack_trains(trains):
-    """Host-side: stack equal-shape Train pytrees along axis 0, padding
-    level tables to the tallest member first."""
-    t_max = max(t.levels.shape[0] for t in trains)
-    w = trains[0].levels.shape[1]
-
-    def padded(t):
-        lv = np.asarray(t.levels)
-        if lv.shape[0] < t_max:
-            lv = np.concatenate(
-                [lv, np.full((t_max - lv.shape[0], w), -1, dtype=np.int32)]
-            )
-        return t._replace(levels=lv)
-
-    ts = [padded(t) for t in trains]
-    return Train(*[
-        np.stack([np.asarray(getattr(t, f)) for t in ts])
-        for f in Train._fields
-    ])
-
-
 def _pad1(a, pad, fill, dtype=np.int32):
     a = np.asarray(a, dtype=dtype)
     return np.concatenate([a, np.full(pad, fill, dtype=dtype)])
@@ -911,22 +600,6 @@ def _pack_upd(upd, upd_cap, e_cap):
     return urow, ucol, uval
 
 
-def _grid_slice_fields(grid: DagGrid, rows: "np.ndarray", pad: int):
-    """The Batch/Train fields both builders stage identically for a
-    contiguous grid slice, padded to the static shape."""
-    return dict(
-        rows=_pad1(rows, pad, -1),
-        creator=_pad1(grid.creator[rows], pad, 0),
-        index=_pad1(grid.index[rows], pad, MAX_INT32),
-        la_rows=np.concatenate(
-            [grid.last_ancestors[rows],
-             np.full((pad, grid.n), -1, dtype=np.int32)]
-        ),
-        coin=_pad1(grid.coin_bit[rows], pad, False, dtype=bool),
-        fixed_round=_pad1(grid.fixed_round[rows], pad, -1),
-    )
-
-
 def _dep_levels(sp_pos: "np.ndarray", op_pos: "np.ndarray") -> "np.ndarray":
     """Dependency depth of each slice member over slice-LOCAL parent
     positions (-1 = parent outside the slice): parents always land on
@@ -940,102 +613,6 @@ def _dep_levels(sp_pos: "np.ndarray", op_pos: "np.ndarray") -> "np.ndarray":
                 d = max(d, lvl[parent] + 1)
         lvl[k] = d
     return lvl
-
-
-def _pack_levels(lvl: "np.ndarray", w_cap: int):
-    """Pack dependency levels into a (T, w_cap) position table, splitting
-    levels wider than w_cap across consecutive table rows (always safe:
-    moving a row later never breaks the parents-before-children order)."""
-    table_rows = []
-    depth = int(lvl.max(initial=-1)) + 1
-    for d in range(depth):
-        members = np.nonzero(lvl == d)[0].astype(np.int32)
-        for s in range(0, len(members), w_cap):
-            chunk = members[s : s + w_cap]
-            row = np.full(w_cap, -1, dtype=np.int32)
-            row[: len(chunk)] = chunk
-            table_rows.append(row)
-    if not table_rows:
-        return np.full((1, w_cap), -1, dtype=np.int32)
-    return np.stack(table_rows)
-
-
-def _pad_rows(table: "np.ndarray", t_cap: int, bucket: int = 32):
-    """Pad the level table height to the next bucket multiple (not t_cap):
-    the level scan's step count is the table height, so padding to the cap
-    would run the worst case every train. Buckets bound recompiles."""
-    t, w = table.shape
-    t_pad = min(-(-t // bucket) * bucket, t_cap)
-    if t == t_pad:
-        return table
-    return np.concatenate(
-        [table, np.full((t_pad - t, w), -1, dtype=np.int32)]
-    )
-
-
-def trains_from_grid(grid: DagGrid, train_size: int, upd_cap: int,
-                     e_cap: int, w_cap: int = 64, t_cap: int = 96):
-    """Slice a recorded synthetic DAG into fixed-shape Trains (the
-    whole-train analog of batches_from_grid). Trains whose dependency
-    depth or fd-update burst exceeds the caps are split in half."""
-    assert grid.fd_update_stream is not None, "need record_fd_updates=True"
-    from .frontier import level_lamport
-
-    lamport_all = level_lamport(grid)
-    spans = [
-        (s, min(s + train_size, grid.e))
-        for s in range(0, grid.e, train_size)
-    ]
-    out = []
-    while spans:
-        start, end = spans.pop(0)
-        rows = np.arange(start, end)
-        b = len(rows)
-        pad = train_size - b
-
-        sp = np.asarray(grid.self_parent[rows], dtype=np.int32)
-        op = np.asarray(grid.other_parent[rows], dtype=np.int32)
-        sp_pos = np.where((sp >= start) & (sp < end), sp - start, -1)
-        op_pos = np.where((op >= start) & (op < end), op - start, -1)
-
-        # global (train-wide) dependency levels
-        lvl = _dep_levels(sp_pos, op_pos)
-        table = _pack_levels(lvl, w_cap)
-        # the device program's unique_indices promises rest on one creator
-        # per level row (guaranteed fork-free: same-creator events chain
-        # through self-parents into deeper levels) — refuse forked input
-        # rather than hand XLA undefined scatter behavior
-        for row in table:
-            members = row[row >= 0]
-            cs = grid.creator[rows[members]]
-            if len(np.unique(cs)) != len(cs):
-                raise ValueError(
-                    "forked creator within a dependency level; "
-                    "train path requires fork-free grids"
-                )
-        upd = [t for r in rows for t in grid.fd_update_stream[r]]
-        if table.shape[0] > t_cap or len(upd) > upd_cap:
-            if b <= 1:
-                raise ValueError(
-                    f"single-event train exceeds caps (depth "
-                    f"{table.shape[0]}/{t_cap}, upd {len(upd)}/{upd_cap})"
-                )
-            mid = (start + end) // 2
-            spans[:0] = [(start, mid), (mid, end)]
-            continue
-        urow, ucol, uval = _pack_upd(upd, upd_cap, e_cap)
-
-        out.append(Train(
-            sp_row=_pad1(sp, pad, -1),
-            op_row=_pad1(op, pad, -1),
-            sp_pos=_pad1(sp_pos, pad, -1),
-            op_pos=_pad1(op_pos, pad, -1),
-            upd_row=urow, upd_col=ucol, upd_val=uval,
-            levels=_pad_rows(table, t_cap),
-            lamport=_pad1(lamport_all[rows], pad, -1),
-            **_grid_slice_fields(grid, rows, pad),
-        ))
-    return out
 
 
 # static height of the within-batch level table; a gossip batch deeper
@@ -1085,11 +662,19 @@ def batches_from_grid(grid: DagGrid, batch_size: int, upd_cap: int, e_cap: int):
 
         no_row = np.full(batch_size, -1, dtype=np.int32)  # a grid holds every parent
         out.append(Batch(
+            rows=_pad1(rows, pad, -1),
+            creator=_pad1(grid.creator[rows], pad, 0),
+            index=_pad1(grid.index[rows], pad, MAX_INT32),
             sp_row=_pad1(sp, pad, -1),
             op_row=_pad1(op, pad, -1),
+            la_rows=np.concatenate(
+                [grid.last_ancestors[rows],
+                 np.full((pad, grid.n), -1, dtype=np.int32)]
+            ),
+            coin=_pad1(grid.coin_bit[rows], pad, False, dtype=bool),
+            fixed_round=_pad1(grid.fixed_round[rows], pad, -1),
             upd_row=urow, upd_col=ucol, upd_val=uval,
             levels=levels_full,
             sp_lamport=no_row, op_lamport=no_row,
-            **_grid_slice_fields(grid, rows, pad),
         ))
     return out

@@ -197,9 +197,8 @@ def _fame_setup_tables(wvalid, la_w, fd_w, idx_w, coin_w, super_majority: int,
                        packed: bool = False):
     """DecideFame preamble from prebuilt per-witness tables: the
     round-adjacent strongly-see tensor and the d=1 ancestry votes
-    (reference: hashgraph.go:875-884). Split out so callers that keep
-    dense witness buffers (frontier_live.py, which derives fd_w from INV)
-    can skip the row gathers. With `packed` the ancestry-comparison tally
+    (reference: hashgraph.go:875-884); `_fame_setup` gathers the tables
+    from the flat event arrays. With `packed` the ancestry-comparison tally
     runs as a popcount over uint32 lanes (tpu/packed.py) — integer-equal
     to the wide sum."""
     r_max, n = wvalid.shape
@@ -351,7 +350,7 @@ def _decide_fame(
 def _received_tables_from(wvalid, la_w, decided, famous, rounds_decided,
                           last_round):
     """Per-round received-search tables from prebuilt per-witness tables
-    (for callers that keep dense witness buffers)."""
+    (`_received_tables` gathers them)."""
     r_max = wvalid.shape[0]
     is_famous = decided & famous & wvalid  # (R, N)
     famous_count = jnp.sum(is_famous, axis=1)  # (R,)

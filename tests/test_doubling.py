@@ -171,39 +171,6 @@ def test_sharded_doubling_matches():
     assert_matches(res, run_passes(sec), "sharded section")
 
 
-def test_bootstrap_frontier_state_matches_oneshot():
-    """The cold-started incremental frontier state must carry exactly the
-    decision tables the one-shot pipeline computes, with every divergence
-    latch clear — i.e. a deep joining node can adopt the live engine
-    without replaying append trains."""
-    from babble_tpu.tpu.frontier_live import bootstrap_frontier_state
-
-    grid = synthetic_grid(8, 600, seed=4, zipf_a=1.1)
-    ref = run_frontier_passes(grid)
-    st = bootstrap_frontier_state(
-        grid, e_cap=grid.e + 64, l_cap=int(grid.index.max()) + 32,
-        r_cap=256, n_participants=grid.n,
-    )
-    np.testing.assert_array_equal(np.asarray(st.rounds)[:grid.e], ref.rounds)
-    np.testing.assert_array_equal(np.asarray(st.witness)[:grid.e], ref.witness)
-    np.testing.assert_array_equal(np.asarray(st.received)[:grid.e], ref.received)
-    assert int(st.last_round) == int(ref.last_round)
-    assert int(st.count) == grid.e
-    assert not bool(st.l_over) and not bool(st.r_over)
-    assert not bool(st.frozen_violation)
-
-
-def test_bootstrap_frontier_state_rejects_seeded():
-    from babble_tpu.tpu.frontier_live import bootstrap_frontier_state
-
-    grid = synthetic_deep_grid(6, 96, seed=2, zipf_a=1.0)
-    sec = section_grid(grid, run_passes(grid), grid.num_levels // 2)
-    with pytest.raises(GridUnsupported):
-        bootstrap_frontier_state(
-            sec, e_cap=sec.e + 64, l_cap=4096, r_cap=256, n_participants=6,
-        )
-
-
 def test_observe_catchup_emits_record_and_series():
     from babble_tpu.obs import Observability
     from babble_tpu.tpu.doubling import observe_catchup

@@ -250,41 +250,41 @@ def test_sharded_2d_mesh_packed_matches_wide(dv, dr):
         )
 
 
-def test_incremental_step_and_train_packed_match_wide():
+def test_incremental_step_and_multi_step_packed_match_wide():
     from babble_tpu.tpu.incremental import (
-        batches_from_grid, init_state, step, train_step, trains_from_grid,
+        batches_from_grid, init_state, multi_step, stack_batches, step,
     )
 
     n, e = 7, 512
     grid = synthetic_grid(n, e, seed=3, zipf_a=1.1, record_fd_updates=True)
     sm = grid.super_majority
+    batches = batches_from_grid(grid, 32, 8192, e)
+    assert len(batches) >= 16
 
-    arms = {}
-    for packed in (False, True):
-        st = init_state(n, e, 64)
-        for b in batches_from_grid(grid, 32, 8192, e):
+    def per_batch(st, packed):
+        for b in batches:
             st = step(st, b, sm, n, e_win=512, packed=packed)
-        arms[packed] = st
-    for f in ("rounds", "lamport", "witness", "received", "wtable",
-              "fame_decided", "famous", "rounds_decided"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(arms[False], f)),
-            np.asarray(getattr(arms[True], f)), f,
-        )
-    assert int(arms[True].last_round) == int(arms[False].last_round)
+        return st
 
-    tr_arms = {}
-    for packed in (False, True):
-        st = init_state(n, e, 64)
-        for t in trains_from_grid(grid, 128, 8192, e, w_cap=16, t_cap=64):
-            st = train_step(st, t, sm, n, e_win=512, packed=packed)
-        tr_arms[packed] = st
-    for f in ("rounds", "lamport", "witness", "received", "wtable",
-              "fame_decided", "famous", "rounds_decided"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(tr_arms[False], f)),
-            np.asarray(getattr(tr_arms[True], f)), f,
-        )
+    def per_train(st, packed):
+        # whole K=16 trains; what is left of the stream goes batch by batch
+        whole = len(batches) - len(batches) % 16
+        for i in range(0, whole, 16):
+            st = multi_step(st, stack_batches(batches[i : i + 16]), sm, n,
+                            e_win=512, packed=packed)
+        for b in batches[whole:]:
+            st = step(st, b, sm, n, e_win=512, packed=packed)
+        return st
+
+    for run in (per_batch, per_train):
+        wide, packed = (run(init_state(n, e, 64), p) for p in (False, True))
+        for f in ("rounds", "lamport", "witness", "received", "wtable",
+                  "fame_decided", "famous", "rounds_decided"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(wide, f)), np.asarray(getattr(packed, f)),
+                f"{run.__name__}: {f}",
+            )
+        assert int(packed.last_round) == int(wide.last_round)
 
 
 # ---------------------------------------------------------------------------

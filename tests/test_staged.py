@@ -12,6 +12,7 @@ sync with the generator.
 
 from __future__ import annotations
 
+import ast
 import textwrap
 from pathlib import Path
 
@@ -297,7 +298,7 @@ def test_every_engine_rung_carries_checked_contracts():
     assert {"one-shot", "frontier", "doubling", "sharded",
             "incremental", "live"} <= rungs
     by_name = {rec.name: c for _rel, rec, c in rows}
-    assert len(by_name) == 24
+    assert len(by_name) == 19
     duals = {
         name for name, c in by_name.items()
         if any(v.layout == "dual" for v in c.args.values())
@@ -307,13 +308,90 @@ def test_every_engine_rung_carries_checked_contracts():
         name for name, c in by_name.items() if "packed" in c.statics
     }
     assert {"consensus_pipeline", "frontier_pipeline", "_fame_received",
-            "_step_full", "multi_step", "train_step", "multi_train",
-            "frontier_train_step", "frontier_multi_train",
-            "_decide"} <= packed_statics
+            "_step_full", "multi_step"} <= packed_statics
     donated = {name for name, c in by_name.items() if c.donate}
-    assert {"local_fame", "local_received", "_step_full", "train_step",
-            "multi_step", "multi_train", "frontier_train_step",
-            "frontier_multi_train"} <= donated
+    assert {"local_fame", "local_received", "_step_full",
+            "multi_step"} <= donated
+
+
+def _package_modules():
+    """{relative path: parsed module} of the package, without the two
+    places that only describe the contract surface."""
+    pkg = Path(REPO_ROOT) / "babble_tpu"
+    out = {}
+    for path in sorted(pkg.rglob("*.py")):
+        rel = path.relative_to(REPO_ROOT).as_posix()
+        if (rel == "babble_tpu/obs/devledger.py"
+                or rel.startswith("babble_tpu/analysis/")):
+            continue
+        out[rel] = ast.parse(path.read_text())
+    return out
+
+
+def _names_taken_from(module: str, others) -> set:
+    """Names that other modules import from `module` (its basename, as in
+    `from .incremental import step`) or read off it (`kernels.x`)."""
+    taken = set()
+    for tree in others:
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.ImportFrom) and node.module
+                    and node.module.split(".")[-1] == module):
+                taken.update(a.name for a in node.names)
+            elif (isinstance(node, ast.Attribute)
+                    and isinstance(node.value, ast.Name)
+                    and node.value.id == module):
+                taken.add(node.attr)
+    return taken
+
+
+def _reached(tree: ast.Module, taken: set) -> set:
+    """Top-level names of a module that code outside it can arrive at:
+    those `taken` by other modules, those a bare module-level statement
+    reads, and, from there on, whatever a reached definition reads."""
+    defs, reached = {}, set(taken)
+    for stmt in tree.body:
+        if isinstance(stmt, (ast.FunctionDef, ast.ClassDef)):
+            bound = [stmt.name]
+        elif isinstance(stmt, (ast.Assign, ast.AnnAssign)):
+            targets = stmt.targets if isinstance(stmt, ast.Assign) else [stmt.target]
+            bound = [t.id for t in targets if isinstance(t, ast.Name)]
+        else:
+            bound = []
+        # what the statement reads, and the functions nested in it (a
+        # shard_map body lives inside its factory)
+        holds = {n.id for n in ast.walk(stmt) if isinstance(n, ast.Name)}
+        holds |= {n.name for n in ast.walk(stmt)
+                  if isinstance(n, ast.FunctionDef)}
+        if not bound:
+            reached |= holds
+        for name in bound:
+            defs[name] = holds - {name}
+    frontier = [n for n in reached if n in defs]
+    while frontier:
+        new = defs[frontier.pop()] - reached
+        reached |= new
+        frontier += [n for n in new if n in defs]
+    return reached
+
+
+def test_every_contract_entry_is_reached_by_package_code():
+    """A `# kernel-contract:` entry is a program some module of the
+    package can launch: another module imports it (or the wrapper that
+    binds it, or the factory that holds it), or a definition that is so
+    reached calls it. Its own definition, the ledger's table
+    (obs/devledger.py) and the checker (analysis/) do not count: an
+    engine that only tests and root scripts call has no contract here."""
+    modules = _package_modules()
+    reached = {}  # per module that holds a contract
+    unreached = []
+    for rel, rec, _c in collect_contracts(REPO_ROOT):
+        if rel not in reached:
+            others = [t for r, t in modules.items() if r != rel]
+            reached[rel] = _reached(
+                modules[rel], _names_taken_from(Path(rel).stem, others))
+        if rec.name not in reached[rel]:
+            unreached.append(f"{rel}:{rec.name}")
+    assert unreached == []
 
 
 def test_contract_table_embed_in_sync_with_docs():
