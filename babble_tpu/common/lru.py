@@ -45,6 +45,12 @@ class LRU:
             return None, False
         return self._items[key], True
 
+    def fetch(self, key):
+        """The value, recency refreshed; KeyError when absent. `get`
+        without the pair, for a caller that treats a miss as an error."""
+        self._items.move_to_end(key)
+        return self._items[key]
+
     def peek(self, key):
         """Returns (value, True) without refreshing recency."""
         if key in self._items:

@@ -140,10 +140,22 @@ class Event:
         r, s = crypto.sign(key, self.body.hash())
         self.signature = crypto.encode_signature(r, s)
 
-    def verify(self) -> bool:
-        pub = crypto.pub_key_from_bytes(self.body.creator)
+    def verify(self, pub=None) -> bool:
+        """Check the signature over a digest computed from the body as it
+        stands now, never one cached earlier: a body altered after `hex()`
+        fails here. That digest is left as the event's cached hash, so
+        `hash()` and `hex()` afterwards marshal nothing again. `pub` is the
+        creator's parsed public key where the caller holds it (the
+        Hashgraph keeps its validators'); parsed from the body's bytes
+        otherwise."""
+        if pub is None:
+            pub = crypto.pub_key_from_bytes(self.body.creator)
         r, s = crypto.decode_signature(self.signature)
-        return crypto.verify(pub, self.body.hash(), r, s)
+        digest = self.body.hash()
+        if digest != self._hash:
+            self._hash = digest
+            self._hex = ""
+        return crypto.verify(pub, digest, r, s)
 
     # -- consensus metadata ------------------------------------------------
 

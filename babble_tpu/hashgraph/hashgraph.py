@@ -26,6 +26,7 @@ from __future__ import annotations
 import math
 from typing import Callable, Dict, List, Optional, Tuple
 
+from .. import crypto
 from ..common import StoreErr, StoreErrType, is_store_err
 from ..peers import Peers
 from .block import Block, BlockSignature, new_block_from_frame
@@ -130,6 +131,13 @@ class Hashgraph:
         self._pos_by_id: Dict[int, int] = {
             p.id: i for i, p in enumerate(participants.to_peer_slice())
         }
+        # each validator's public key as the signature check takes it,
+        # parsed on its first event and kept under the creator's exact
+        # bytes (see _creator_key); the inserts that found theirs there
+        # since process_decided_rounds last handed the count to the tracer
+        # (total `insert.key_hit`)
+        self._validator_keys: Dict[bytes, object] = {}
+        self._key_hits = 0
 
         # memo caches (unbounded dicts; cleared on Reset). An event's own
         # stamp fills them on a miss (see _memoized)
@@ -380,12 +388,30 @@ class Hashgraph:
     # insertion (reference: src/hashgraph/hashgraph.go:398-544,714-761)
     # ------------------------------------------------------------------
 
-    def _check_self_parent(self, event: Event) -> None:
-        creator_last_known, _ = self.store.last_event_from(event.creator())
+    def _creator_key(self, event: Event):
+        """The creator's public key, parsed. A validator's is parsed once
+        and kept under the creator's exact bytes, so the table holds at most
+        n keys; anyone else's is parsed for this call and kept nowhere (the
+        self-parent check refuses the event after its signature)."""
+        raw = event.body.creator
+        pub = self._validator_keys.get(raw)
+        if pub is None:
+            pub = crypto.pub_key_from_bytes(raw)
+            if event.creator() in self._pos_by_pubkey:
+                self._validator_keys[raw] = pub
+        return pub
+
+    def _check_self_parent(self, event: Event) -> bool:
+        """Raises unless the self-parent is the creator's last known event;
+        returns whether that is its root's."""
+        creator_last_known, is_root = self.store.last_event_from(event.creator())
         if event.self_parent() != creator_last_known:
             raise ValueError("Self-parent not last known event by creator")
+        return is_root
 
-    def _check_other_parent(self, event: Event) -> None:
+    def _check_other_parent(self, event: Event, root: Root) -> None:
+        """Raises unless the other-parent is in the store, a frozen ref, or
+        the one `root` (the creator's) names for this event."""
         other_parent = event.other_parent()
         if other_parent == "":
             return
@@ -395,26 +421,26 @@ class Hashgraph:
         except StoreErr:
             if other_parent in self.frozen_refs:
                 return
-            root = self.store.get_root(event.creator())
             other = root.others.get(event.hex())
             if other is not None and other.hash == other_parent:
                 return
             raise ValueError("Other-parent not known")
 
-    def _init_event_coordinates(self, event: Event) -> None:
+    def _stored_event(self, key: str) -> Optional[Event]:
+        try:
+            return self.store.get_event(key)
+        except StoreErr:
+            return None
+
+    def _init_event_coordinates(
+        self, event: Event, sp: Optional[Event], op: Optional[Event],
+        pos: int, coords: Tuple[int, str],
+    ) -> None:
+        """`sp`, `op`: the parents as the store holds them (None: not
+        there); `pos`, `coords`: the creator's position and the event's
+        own (index, hash)."""
         n = len(self.participants)
         event.first_descendants = [(MAX_INT32, "")] * n
-
-        sp: Optional[Event] = None
-        op: Optional[Event] = None
-        try:
-            sp = self.store.get_event(event.self_parent())
-        except StoreErr:
-            pass
-        try:
-            op = self.store.get_event(event.other_parent())
-        except StoreErr:
-            pass
 
         if sp is None and op is None:
             event.last_ancestors = [(-1, "")] * n
@@ -428,32 +454,36 @@ class Hashgraph:
                 for a, b in zip(sp.last_ancestors, op.last_ancestors)
             ]
 
-        pos = self._pos_by_pubkey[event.creator()]
-        coords = (event.index(), event.hex())
         event.first_descendants[pos] = coords
         event.last_ancestors[pos] = coords
 
-    def _update_ancestor_first_descendant(self, event: Event) -> List[tuple]:
+    def _update_ancestor_first_descendant(
+        self, event: Event, pos: int, coords: Tuple[int, str],
+    ) -> List[tuple]:
         """Walk each last-ancestor's self-parent chain marking this event as
         first descendant (reference: src/hashgraph/hashgraph.go:510-544).
         Returns the (ancestor_hash, creator_pos, index) cells written — the
-        delta stream an incremental device engine replays."""
-        pos = self._pos_by_pubkey[event.creator()]
-        coords = (event.index(), event.hex())
+        delta stream an incremental device engine replays. A step reads its
+        ancestor once (`get_event`: one look-up, one recency refresh) and
+        tells the store of the cell it wrote (`update_event`: nothing for a
+        store that holds the object, the write-back for one that persists)."""
+        get_event = self.store.get_event
+        update_event = self.store.update_event
+        index = coords[0]
         writes: List[tuple] = []
         for _, ah in event.last_ancestors:
             while ah != "":
                 try:
-                    a = self.store.get_event(ah)
+                    a = get_event(ah)
                 except StoreErr:
                     break
-                if a.first_descendants[pos][0] == MAX_INT32:
-                    a.first_descendants[pos] = coords
-                    self.store.set_event(a)
-                    writes.append((ah, pos, coords[0]))
-                    ah = a.self_parent()
-                else:
+                cells = a.first_descendants
+                if cells[pos][0] != MAX_INT32:
                     break
+                cells[pos] = coords
+                update_event(a)
+                writes.append((ah, pos, index))
+                ah = a.body.parents[0]
         return writes
 
     def insert_event(self, event: Event, set_wire_info: bool) -> None:
@@ -462,28 +492,43 @@ class Hashgraph:
         # ring within one sync
         now = self.obs.clock.monotonic
         t_insert = now()
-        if not event.verify():
+        # every event's signature is checked, over a digest of the body as
+        # handed in; only the parsing of a validator's key is not repeated
+        if event.body.creator in self._validator_keys:
+            self._key_hits += 1
+        if not event.verify(self._creator_key(event)):
             raise ValueError("Invalid Event signature")
         t_verified = now()
 
-        self._check_self_parent(event)
-        self._check_other_parent(event)
+        # the creator's root and the stored parents, fetched once for the
+        # wire info and the coordinates. The store's recency order decides
+        # where: a refused event leaves it alone, so the self-parent is read
+        # after the other-parent's check (which keeps its own look-up), and
+        # an accepted one leaves the self-parent older than the other-parent
+        last_is_root = self._check_self_parent(event)
+        creator = event.creator()
+        root = self.store.get_root(creator)
+        self._check_other_parent(event, root)
+        sp = self._stored_event(event.self_parent())
+        op = self._stored_event(event.other_parent())
 
         event.topological_index = self.topological_index
         self.topological_index += 1
 
         if set_wire_info:
-            self._set_wire_info(event)
+            self._set_wire_info(event, sp, op, root, last_is_root)
 
-        self._init_event_coordinates(event)
+        pos = self._pos_by_pubkey[creator]
+        coords = (event.index(), event.hex())
+        self._init_event_coordinates(event, sp, op, pos, coords)
         self.store.set_event(event)
         t_fd = now()
-        fd_writes = self._update_ancestor_first_descendant(event)
+        fd_writes = self._update_ancestor_first_descendant(event, pos, coords)
         t_fd_done = now()
         if self.insert_listener is not None:
             self.insert_listener(event, fd_writes)
 
-        self.undetermined_events.append(event.hex())
+        self.undetermined_events.append(coords[1])
         if event.is_loaded():
             self.pending_loaded_events += 1
         self.sig_pool.extend(event.block_signatures())
@@ -496,31 +541,36 @@ class Hashgraph:
         tracer.add("insert.fd", t_fd_done - t_fd)
         tracer.add("insert", now() - t_insert)
 
-    def _set_wire_info(self, event: Event) -> None:
-        self_parent_index = -1
+    def _set_wire_info(
+        self, event: Event, sp: Optional[Event], op: Optional[Event],
+        root: Root, last_is_root: bool,
+    ) -> None:
+        """`sp`, `op`, `root`, `last_is_root`: what insert_event fetched
+        (the stored parents, the creator's root, whether the self-parent is
+        the root's). A parent that is needed here and is not in the store
+        raises the store's own error."""
         other_parent_creator_id = -1
         other_parent_index = -1
 
-        last_from, is_root = self.store.last_event_from(event.creator())
-        if is_root and last_from == event.self_parent():
-            root = self.store.get_root(event.creator())
+        if last_is_root:
             self_parent_index = root.self_parent.index
         else:
-            self_parent = self.store.get_event(event.self_parent())
-            self_parent_index = self_parent.index()
+            if sp is None:
+                sp = self.store.get_event(event.self_parent())
+            self_parent_index = sp.index()
 
         if event.other_parent() != "":
-            root = self.store.get_root(event.creator())
             other = root.others.get(event.hex())
             if other is not None and other.hash == event.other_parent():
                 other_parent_creator_id = other.creator_id
                 other_parent_index = other.index
             else:
-                other_parent = self.store.get_event(event.other_parent())
+                if op is None:
+                    op = self.store.get_event(event.other_parent())
                 other_parent_creator_id = self.participants.by_pub_key[
-                    other_parent.creator()
+                    op.creator()
                 ].id
-                other_parent_index = other_parent.index()
+                other_parent_index = op.index()
 
         event.set_wire_info(
             self_parent_index,
@@ -832,6 +882,9 @@ class Hashgraph:
                 if self._reopens:
                     tracer.add("fame.reopen", 0.0, count=self._reopens)
                     self._reopens = 0
+                if self._key_hits:
+                    tracer.add("insert.key_hit", 0.0, count=self._key_hits)
+                    self._key_hits = 0
 
     def _process_decided_rounds(self) -> None:
         """The commit loop of process_decided_rounds.
@@ -1512,7 +1565,7 @@ class Hashgraph:
         to stay inside the window only delays the joiner, which picks up
         the rest through ordinary gossip."""
         for ev in section.events:
-            if not ev.verify():
+            if not ev.verify(self._creator_key(ev)):
                 raise ValueError("Invalid Event signature in fast-sync section")
 
         # frames must be the contiguous round range above the anchor (the
@@ -1696,7 +1749,7 @@ class Hashgraph:
         # the dominant ECDSA cost of catch-up
         for ev in events:
             self._check_self_parent(ev)
-            self._check_other_parent(ev)
+            self._check_other_parent(ev, self.store.get_root(ev.creator()))
             ev.topological_index = self.topological_index
             self.topological_index += 1
             # a stamp left below the scrub ceiling is authoritative donor

@@ -82,17 +82,20 @@ class InmemStore(Store):
         return self._roots_by_self_parent
 
     def get_event(self, key: str) -> Event:
-        res, ok = self.event_cache.get(key)
-        if not ok:
-            raise StoreErr("EventCache", StoreErrType.KEY_NOT_FOUND, key)
-        return res
+        try:
+            return self.event_cache.fetch(key)
+        except KeyError:
+            raise StoreErr("EventCache", StoreErrType.KEY_NOT_FOUND, key) from None
 
     def set_event(self, event: Event) -> None:
         key = event.hex()
-        _, ok = self.event_cache.get(key)
-        if not ok:
+        if key not in self.event_cache:
             self._add_participant_event(event.creator(), key, event.index())
         self.event_cache.add(key, event)
+
+    def update_event(self, event: Event) -> None:
+        """Nothing: the cache holds the very object `get_event` handed out,
+        so the caller's mutation is already the stored state."""
 
     def _add_participant_event(self, participant: str, hash_: str, index: int) -> None:
         self.participant_events_cache.set(participant, hash_, index)

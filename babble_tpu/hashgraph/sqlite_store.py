@@ -159,34 +159,45 @@ class SQLiteStore(Store):
             return Event.from_store_json(json.loads(row[0]))
 
     def set_event(self, event: Event) -> None:
-        with self.db:
-            row = self.db.execute(
-                "SELECT topo_index FROM events WHERE hex = ?", (event.hex(),)
-            ).fetchone()
-            peer = self.inmem.participants().by_pub_key[event.creator()]
-            last_known = self.inmem.participant_events_cache.known().get(peer.id, -1)
-            if event.index() > last_known:
-                # advances the creator's sequence: register in the
-                # participant rolling index
+        peer = self.inmem.participants().by_pub_key[event.creator()]
+        last_known = self.inmem.participant_events_cache.known().get(peer.id, -1)
+        if event.index() > last_known:
+            # advances the creator's sequence: register in the
+            # participant rolling index
+            with self.db:
                 self.inmem.set_event(event)
-            else:
-                # write-back of an already-registered event (possibly
-                # LRU-evicted meanwhile): refresh the cache only —
-                # re-registering would hit a rolled participant window
-                self.inmem.event_cache.add(event.hex(), event)
-            topo = row[0] if row else self._topo_counter
-            if row is None:
-                self._topo_counter += 1
-            self.db.execute(
-                "INSERT OR REPLACE INTO events VALUES (?, ?, ?, ?, ?)",
-                (
-                    event.hex(),
-                    topo,
-                    event.creator(),
-                    event.index(),
-                    json.dumps(event.to_store_json()),
-                ),
-            )
+                self._db_put_event(event)
+        else:
+            self.update_event(event)
+
+    def update_event(self, event: Event) -> None:
+        """Write-back of an already-registered event (possibly LRU-evicted
+        meanwhile, so the object may be a copy read from disk): put it in
+        the cache, which re-registers nothing (that would hit a rolled
+        participant window), and write it through."""
+        with self.db:
+            self.inmem.event_cache.add(event.hex(), event)
+            self._db_put_event(event)
+
+    def _db_put_event(self, event: Event) -> None:
+        """The event's row, under the topological index it was first
+        written with."""
+        row = self.db.execute(
+            "SELECT topo_index FROM events WHERE hex = ?", (event.hex(),)
+        ).fetchone()
+        topo = row[0] if row else self._topo_counter
+        if row is None:
+            self._topo_counter += 1
+        self.db.execute(
+            "INSERT OR REPLACE INTO events VALUES (?, ?, ?, ?, ?)",
+            (
+                event.hex(),
+                topo,
+                event.creator(),
+                event.index(),
+                json.dumps(event.to_store_json()),
+            ),
+        )
 
     def participant_events(self, participant: str, skip: int) -> List[str]:
         try:

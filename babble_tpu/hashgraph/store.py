@@ -30,6 +30,13 @@ class Store(ABC):
     def set_event(self, event: Event) -> None: ...
 
     @abstractmethod
+    def update_event(self, event: Event) -> None:
+        """Keep a change made to an event this store handed out (`get_event`)
+        or already holds: whatever the store needs beyond the caller having
+        mutated the object. It registers nothing and, for a cached event,
+        moves nothing in the cache."""
+
+    @abstractmethod
     def participant_events(self, participant: str, skip: int) -> List[str]: ...
 
     @abstractmethod

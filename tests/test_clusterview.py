@@ -371,7 +371,17 @@ def test_live_service_cluster_endpoints_and_renderer():
             idx = cur
             time.sleep(0.05)
 
-        digest = _get(base + "/health/digest")
+        # the cluster keeps gossiping and may commit once more between
+        # two requests: take the triple between two equal readings of the
+        # frontier gauge (it only rises), so that the three are compared
+        # at one index
+        g = nodes[0].obs.registry.get("babble_commit_frontier_block")
+        for _ in range(50):
+            gauge = int(g.value())
+            digest = _get(base + "/health/digest")
+            stats = _get(base + "/stats")
+            if int(g.value()) == gauge:
+                break
         assert digest["addr"] == nodes[0].local_addr
         assert digest["v"] >= 1
         assert isinstance(digest["block"], int) and digest["block"] >= 1
@@ -381,10 +391,8 @@ def test_live_service_cluster_endpoints_and_renderer():
         )
 
         # one source of truth: digest block == frontier gauge == /stats
-        stats = _get(base + "/stats")
-        g = nodes[0].obs.registry.get("babble_commit_frontier_block")
         assert int(stats["commit_frontier_block"]) == digest["block"]
-        assert int(g.value()) == digest["block"]
+        assert gauge == digest["block"]
         assert int(stats["commit_frontier_round"]) == digest["round"]
 
         # gossip has run to a committed block, so the fleet table
