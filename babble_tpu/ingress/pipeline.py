@@ -96,6 +96,20 @@ def verdict_from_wire(res: Any) -> IngressVerdict:
     return IngressVerdict(verdict=VERDICT_SHED, reason="rejected")
 
 
+class IngressBatch(list):
+    """A released batch: the transactions, as the list the downstream has
+    always been handed, and beside them `admitted_at`, per transaction the
+    Clock reading at which its client was answered. The node hands it on
+    to `Core.add_transactions`, which totals the waits as `ingress.wait`
+    when the self-event that carries them is made."""
+
+    __slots__ = ("admitted_at",)
+
+    def __init__(self, txs=(), admitted_at=()):
+        super().__init__(txs)
+        self.admitted_at: List[float] = list(admitted_at)
+
+
 class SubmitRejected(RuntimeError):
     """A submission did not land: `verdict` distinguishes server-side
     backpressure (``shed`` — retry later, the node is protecting itself)
@@ -135,13 +149,13 @@ class TokenBucket:
 
 
 class _ClientQueue:
-    """Pending (tx, paid) entries for one client plus its DRR deficit.
+    """Pending (tx, paid, admitted at) entries for one client plus its DRR deficit.
     All access under the pipeline lock."""
 
     __slots__ = ("entries", "deficit")
 
     def __init__(self) -> None:
-        self.entries: Deque[Tuple[bytes, bool]] = deque()
+        self.entries: Deque[Tuple[bytes, bool, float]] = deque()
         self.deficit = 0.0
 
 
@@ -199,7 +213,7 @@ class IngressPipeline:
         self._queues: Dict[str, _ClientQueue] = {}  # guarded-by: _lock
         self._pending = 0  # guarded-by: _lock
         # the open batch: released txs waiting for size/deadline flush
-        self._batch: List[bytes] = []  # guarded-by: _lock
+        self._batch = IngressBatch()  # guarded-by: _lock
         self._batch_size = 0  # guarded-by: _lock
         self._batch_open_t = 0.0  # guarded-by: _lock
         # shed-storm detection window state
@@ -323,7 +337,7 @@ class IngressPipeline:
         q = self._queues.get(client_id)
         if q is None:
             q = self._queues[client_id] = _ClientQueue()
-        q.entries.append((tx, paid))
+        q.entries.append((tx, paid, now))
         self._pending += 1
         self._dedup.add(tid, True)
         verdict = VERDICT_ACCEPTED if paid else VERDICT_QUEUED
@@ -393,7 +407,7 @@ class IngressPipeline:
                     continue
                 q.deficit += self.drr_quantum
                 while q.entries:
-                    tx, paid = q.entries[0]
+                    tx, paid, admitted = q.entries[0]
                     oversize = len(tx) >= self.batch_bytes
                     if not oversize and q.deficit < len(tx):
                         deficit_starved = True
@@ -419,12 +433,13 @@ class IngressPipeline:
                         if self._batch:
                             out.append(self._close_batch_locked())
                         self._observe_batch([tx])
-                        out.append([tx])
+                        out.append(IngressBatch([tx], [admitted]))
                         continue
                     q.deficit -= len(tx)
                     if not self._batch:
                         self._batch_open_t = now
                     self._batch.append(tx)
+                    self._batch.admitted_at.append(admitted)
                     self._batch_size += len(tx)
                     if self._batch_size >= self.batch_bytes:
                         out.append(self._close_batch_locked())
@@ -439,9 +454,9 @@ class IngressPipeline:
             out.append(self._close_batch_locked())
         return out
 
-    def _close_batch_locked(self) -> List[bytes]:  # requires-lock: _lock
+    def _close_batch_locked(self) -> IngressBatch:  # requires-lock: _lock
         batch = self._batch
-        self._batch = []
+        self._batch = IngressBatch()
         self._batch_size = 0
         self._observe_batch(batch)
         return batch

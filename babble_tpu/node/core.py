@@ -55,6 +55,11 @@ class Core:
         self.head: str = ""
         self.seq: int = -1
         self.transaction_pool: List[bytes] = []
+        # per pooled transaction, when the front door answered for it (the
+        # ingress verdict; for a transaction that came another way, when it
+        # was pooled): the next self-event hands the waits over as the
+        # total `ingress.wait`
+        self._pooled_at: List[float] = []
         self.block_signature_pool: List[BlockSignature] = []
         if consensus_backend not in ("cpu", "tpu"):
             raise ValueError(f"unknown consensus backend: {consensus_backend!r}")
@@ -235,11 +240,42 @@ class Core:
         bodies the diff builds on, and the node-level missing-parent
         escape (node._gossip) needs to see that error to flip the node
         into CatchingUp and rebuild the store."""
+        obs = self.hg.obs
+        spent = [0.0, 0.0, 0]  # s in read_wire_info, s in insert_event, inserted
+        with obs.span("core.sync", events=len(unknown_events)) as sp:
+            try:
+                other_head = self._insert_wire_events(unknown_events, spent)
+            finally:
+                # decode and insert alternate (a wire event names its
+                # parents by creator and index, which the events before it
+                # resolve), so their times are summed over the loop and
+                # handed over once a call, laid end to end from the head
+                # of the span they are children of
+                decode_s, insert_s, inserted = spent
+                obs.tracer.record("sync.decode", sp.start, decode_s)
+                obs.tracer.record("sync.insert", sp.start + decode_s, insert_s)
+                obs.tracer.add("sync.events", 0.0, inserted)
+            self.add_self_event(other_head)
+
+    def _insert_wire_events(
+        self, unknown_events: List[WireEvent], spent: list
+    ) -> str:
+        """The insert loop of `sync`; returns the batch head and adds to
+        `spent` the seconds in `read_wire_info`, the seconds in
+        `insert_event` and the events inserted."""
+        now = self.hg.obs.clock.monotonic
         other_head = ""
         for we in unknown_events:
+            t0 = now()
             ev = self.hg.read_wire_info(we)
+            t1 = now()
+            spent[0] += t1 - t0
             try:
-                self.insert_event(ev, False)
+                try:
+                    self.insert_event(ev, False)
+                    spent[2] += 1
+                finally:
+                    spent[1] += now() - t1
             except ValueError as e:
                 if "Self-parent not last known event" not in str(e):
                     raise
@@ -277,7 +313,7 @@ class Core:
                     continue
                 # already present: overlapping delivery, still batch head
             other_head = ev.hex()
-        self.add_self_event(other_head)
+        return other_head
 
     def prepare_fast_forward(
         self, block: Block, frame: Frame, section=None
@@ -354,15 +390,24 @@ class Core:
             and not self.block_signature_pool
         ):
             return
-        new_head = Event(
-            transactions=self.transaction_pool,
-            block_signatures=self.block_signature_pool,
-            parents=[self.head, other_head],
-            creator=self.pub_key(),
-            index=self.seq + 1,
-        )
-        self.sign_and_insert_self_event(new_head)
+        obs = self.hg.obs
+        with obs.span("sync.self_event", txs=len(self.transaction_pool)):
+            new_head = Event(
+                transactions=self.transaction_pool,
+                block_signatures=self.block_signature_pool,
+                parents=[self.head, other_head],
+                creator=self.pub_key(),
+                index=self.seq + 1,
+            )
+            self.sign_and_insert_self_event(new_head)
+        if self._pooled_at:
+            now = obs.clock.monotonic()
+            obs.tracer.add(
+                "ingress.wait", sum(now - t for t in self._pooled_at),
+                len(self._pooled_at))
+            obs.tracer.add("self_event.txs", 0.0, len(self._pooled_at))
         self.transaction_pool = []
+        self._pooled_at = []
         self.block_signature_pool = []
 
     def from_wire(self, wire_events: List[WireEvent]) -> List[Event]:
@@ -687,8 +732,16 @@ class Core:
 
             flush_live_engine(self.hg)
 
-    def add_transactions(self, txs: List[bytes]) -> None:
+    def add_transactions(
+        self, txs: List[bytes], admitted_at: Optional[List[float]] = None
+    ) -> None:
+        """Pool transactions for the next self-event. `admitted_at` is,
+        per transaction, when the ingress pipeline answered its client
+        (`IngressBatch.admitted_at`); without it the wait counts from now."""
         self.transaction_pool.extend(txs)
+        if admitted_at is None:
+            admitted_at = [self.hg.obs.clock.monotonic()] * len(txs)
+        self._pooled_at.extend(admitted_at)
 
     def add_block_signature(self, bs: BlockSignature) -> None:
         self.block_signature_pool.append(bs)

@@ -55,6 +55,29 @@ def _is_benign_race(e: Exception) -> bool:
     return "Self-parent not last known event by creator" in str(e)
 
 
+class _CoreLocked:
+    """`with node._locked:` is `with node.core_lock:` that also adds the
+    time the thread waited for the lock to the tracer's `node.lock_wait`
+    total (count: acquisitions; seconds: only where the lock was held by
+    another thread, so an uncontended acquire reads no clock)."""
+
+    __slots__ = ("lock", "clock", "tracer")
+
+    def __init__(self, lock, clock, tracer):
+        self.lock, self.clock, self.tracer = lock, clock, tracer
+
+    def __enter__(self) -> None:
+        waited = 0.0
+        if not self.lock.acquire(False):
+            t0 = self.clock.monotonic()
+            self.lock.acquire()
+            waited = self.clock.monotonic() - t0
+        self.tracer.add("node.lock_wait", waited)
+
+    def __exit__(self, *exc) -> None:
+        self.lock.release()
+
+
 def _is_missing_parent(e: Exception) -> bool:
     """A sync failed because an event body this store is SUPPOSED to have
     (per its own known-events high-water mark) is gone — the signature of
@@ -120,6 +143,7 @@ class Node(NodeStateMachine):
             obs=self.obs,
         )
         self.core_lock = threading.Lock()
+        self._locked = _CoreLocked(self.core_lock, conf.clock, self.obs.tracer)
         self.selector_lock = threading.Lock()
         self.peer_selector = RandomPeerSelector(  # guarded-by: selector_lock
             participants, self.local_addr, rng=conf.rng
@@ -561,7 +585,7 @@ class Node(NodeStateMachine):
             # (cheap; reads the fleet table the gossip legs maintain)
             self.obs.clusterview.check()
             if self.slo is not None:
-                self.slo.evaluate()
+                self.slo.tick()
             # deadline pump: ship a partial ingress batch whose hold
             # deadline elapsed even when no new submission arrives
             self.ingress.tick()
@@ -651,7 +675,7 @@ class Node(NodeStateMachine):
                 cmd.known, self.conf.sync_limit
             )
         except Exception:  # noqa: BLE001 — racing a reset/rebuild: retry
-            with self.core_lock:  # on the consistent path
+            with self._locked:  # on the consistent path
                 over_sync_limit = self.core.over_sync_limit(
                     cmd.known, self.conf.sync_limit
                 )
@@ -661,16 +685,21 @@ class Node(NodeStateMachine):
             try:
                 resp.known = self.core.known_events()
             except Exception:  # noqa: BLE001 — same racing-reset fallback
-                with self.core_lock:
+                with self._locked:
                     resp.known = self.core.known_events()
             rpc.respond(resp, error=None)
             return
         else:
             try:
-                with self.core_lock:
-                    diff = self.core.event_diff(cmd.known)
-                    exported = self.core.seq
-                resp.events = self.core.to_wire(diff)
+                # `node.serve_sync`: what an inbound request costs this
+                # node, the wait for the lock included: the diff and its
+                # wire form
+                with self.obs.span("node.serve_sync") as sp:
+                    with self._locked:
+                        diff = self.core.event_diff(cmd.known)
+                        exported = self.core.seq
+                    resp.events = self.core.to_wire(diff)
+                    sp.attrs["events"] = len(diff)
                 # piggyback trace contexts for the traced txs the served
                 # diff carries (out-of-band: hash-safe by construction)
                 resp.traces = self.obs.traces.contexts_for(diff)
@@ -687,7 +716,7 @@ class Node(NodeStateMachine):
                 self.logger.error("Calculating Diff: %s", e)
                 resp_err = str(e)
 
-        with self.core_lock:
+        with self._locked:
             resp.known = self.core.known_events()
         rpc.respond(resp, error=resp_err)
 
@@ -700,7 +729,7 @@ class Node(NodeStateMachine):
             self.obs.traces.absorb(cmd.traces)
         if cmd.cluster:
             self.obs.clusterview.absorb(cmd.cluster)
-        with self.core_lock:
+        with self._locked:
             try:
                 self.sync(cmd.events)
             except Exception as e:
@@ -720,7 +749,7 @@ class Node(NodeStateMachine):
         resp = FastForwardResponse(from_id=self.id)
         resp_err: Optional[str] = None
         try:
-            with self.core_lock:
+            with self._locked:
                 # anchor + live section must come from one consistent
                 # snapshot, capped at the app's committed height so the
                 # get_snapshot below cannot race the async commit channel
@@ -779,7 +808,7 @@ class Node(NodeStateMachine):
                 self._last_exported_seq = exported
 
     def _pre_gossip(self) -> bool:
-        with self.core_lock:
+        with self._locked:
             if not (self.core.need_gossip() or self.is_starting()):
                 return False
             return True
@@ -788,20 +817,24 @@ class Node(NodeStateMachine):
         """One pull+push exchange (reference: src/node/node.go:363-395)."""
         self.sync_requests += 1
         start = self.clock.monotonic()
-        try:
-            sync_limit, other_known = self._pull(peer_addr)
-            if sync_limit:
-                self.logger.debug("SyncLimit from %s", peer_addr)
-                self._obs_sync(start, "ok", peer_addr)
-                self.set_state(NodeState.CATCHING_UP)
-                return_event.set()
+        # `node.gossip` is the exchange as a span with totals (children
+        # `node.pull`, `node.push`, `core.sync`); the ring record `gossip`
+        # of _obs_sync carries the peer and the result for /debug/trace
+        with self.obs.span("node.gossip"):
+            try:
+                sync_limit, other_known = self._pull(peer_addr)
+                if sync_limit:
+                    self.logger.debug("SyncLimit from %s", peer_addr)
+                    self._obs_sync(start, "ok", peer_addr)
+                    self.set_state(NodeState.CATCHING_UP)
+                    return_event.set()
+                    return
+                self._push(peer_addr, other_known)
+            except Exception as e:
+                self._obs_sync(start, "error", peer_addr, err=e)
+                if self._gossip_fail(peer_addr, e):
+                    return_event.set()
                 return
-            self._push(peer_addr, other_known)
-        except Exception as e:
-            self._obs_sync(start, "error", peer_addr, err=e)
-            if self._gossip_fail(peer_addr, e):
-                return_event.set()
-            return
         self._obs_sync(start, "ok", peer_addr)
         self._gossip_ok(peer_addr)
 
@@ -885,9 +918,12 @@ class Node(NodeStateMachine):
         self.set_starting(False)
 
     def _pull(self, peer_addr: str) -> Tuple[bool, Dict[int, int]]:
-        with self.core_lock:
+        with self._locked:
             known = self.core.known_events()
-        resp = self.trans.sync(peer_addr, SyncRequest(from_id=self.id, known=known))
+        # time on the wire and in the peer (its lock, diff and encoding)
+        with self.obs.span("node.pull"):
+            resp = self.trans.sync(
+                peer_addr, SyncRequest(from_id=self.id, known=known))
         if resp.sync_limit:
             return True, {}
         self._m_payload.labels(direction="pulled").observe(
@@ -900,14 +936,14 @@ class Node(NodeStateMachine):
         if resp.cluster:
             self.obs.clusterview.absorb(resp.cluster)
         if resp.events:
-            with self.core_lock:
+            with self._locked:
                 self.sync(resp.events)
         return False, resp.known
 
     def _push(self, peer_addr: str, known_events: Dict[int, int]) -> None:
-        with self.core_lock:
+        with self._locked:
             self.core.add_self_event("")
-        with self.core_lock:
+        with self._locked:
             if self.core.over_sync_limit(known_events, self.conf.sync_limit):
                 self.logger.debug("SyncLimit")
                 return
@@ -920,14 +956,17 @@ class Node(NodeStateMachine):
         # r5) — over-counting only refuses rewinds, never licenses one
         self._note_export(exported)
         self._m_payload.labels(direction="pushed").observe(len(wire_events))
-        self.trans.eager_sync(
-            peer_addr,
-            EagerSyncRequest(
-                from_id=self.id, events=wire_events,
-                traces=self.obs.traces.contexts_for(diff),
-                cluster=self.obs.clusterview.wire_digests(),
-            ),
-        )
+        # time on the wire and in the peer (its lock, decode, insert and
+        # consensus call)
+        with self.obs.span("node.push", events=len(wire_events)):
+            self.trans.eager_sync(
+                peer_addr,
+                EagerSyncRequest(
+                    from_id=self.id, events=wire_events,
+                    traces=self.obs.traces.contexts_for(diff),
+                    cluster=self.obs.clusterview.wire_digests(),
+                ),
+            )
 
     def fast_forward(self) -> None:
         """Catch-up via a peer's anchor block + frame + app snapshot
@@ -1026,7 +1065,7 @@ class Node(NodeStateMachine):
             # onto the restored snapshot state — but it must follow
             # validation so a bad donor can't leave the app on a foreign
             # snapshot with the hashgraph unchanged
-            with self.core_lock:
+            with self._locked:
                 validated = self.core.prepare_fast_forward(
                     resp.block, resp.frame, resp.section
                 )
@@ -1051,7 +1090,7 @@ class Node(NodeStateMachine):
                 raise ValueError(
                     "snapshot state hash does not match the signed anchor block"
                 )
-            with self.core_lock:
+            with self._locked:
                 self.core.apply_fast_forward(*validated)
             # serve-availability (code review r5): if the app can serve the
             # snapshot at the anchor we just restored, raise the serving
@@ -1118,11 +1157,13 @@ class Node(NodeStateMachine):
         self.core.run_consensus()
 
     def commit(self, block: Block) -> None:
-        state_hash = self.proxy.commit_block(block)
+        # the application's time: the hand-over of the block and its answer
+        with self.obs.span("commit.deliver", block=block.index()):
+            state_hash = self.proxy.commit_block(block)
         if block.index() > self._app_committed_index:
             self._app_committed_index = block.index()
         block.body.state_hash = state_hash
-        with self.core_lock:
+        with self._locked:
             sig = self.core.sign_block(block)
             self.core.add_block_signature(sig)
         self._observe_commit(block)
@@ -1161,6 +1202,8 @@ class Node(NodeStateMachine):
         """Insert an ingress batch into the pool: one timestamp pass, one
         trace pass, ONE core_lock acquisition for the whole batch — the
         amortization the ingress pipeline exists to buy."""
+        # an IngressBatch says when each client was answered
+        admitted_at = getattr(txs, "admitted_at", None)
         txs = [bytes(tx) for tx in txs]
         now = self.clock.monotonic()
         with self._tx_times_lock:
@@ -1174,8 +1217,8 @@ class Node(NodeStateMachine):
         # idempotent, keeps the earliest submit mark
         for tx in txs:
             self.obs.traces.begin(tx)
-        with self.core_lock:
-            self.core.add_transactions(txs)
+        with self._locked:
+            self.core.add_transactions(txs, admitted_at)
 
     def shutdown(self) -> None:
         if self.get_state() == NodeState.SHUTDOWN:

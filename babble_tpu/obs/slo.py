@@ -122,6 +122,7 @@ class SLOEngine:
         # longest window
         self._samples: Deque[Tuple[float, Dict[str, dict]]] = deque()
         self._t0 = self.clock.monotonic()
+        self._last_tick = float("-inf")  # guarded-by: _lock
         self._breached: Dict[str, bool] = {}  # guarded-by: _lock
         self._g_burn = obs.gauge(
             "babble_slo_burn_rate",
@@ -235,9 +236,24 @@ class SLOEngine:
 
     def evaluate(self) -> Dict[str, Any]:
         """One evaluation pass; returns the same document `status()`
-        serves. Call from the node/sim tick or once for a bench gate."""
+        serves. Call from the sim tick or once for a bench gate."""
         with self._lock:
             return self._evaluate_locked()
+
+    def tick(self) -> None:
+        """`evaluate`, at most once a sixtieth of the shortest window (a
+        second, by default): what a node's heartbeat calls. A pass keeps
+        one sample and walks all it holds, so one a heartbeat (100 a
+        second at the demo tuning) meant 30,000 samples at the longest
+        window's end and a pass of tens of milliseconds on the gossip
+        loop's thread; a burn rate is a mean over a minute or more and
+        gains nothing from them."""
+        with self._lock:
+            now = self.clock.monotonic()
+            if now - self._last_tick < (self.windows or (60.0,))[0] / 60.0:
+                return
+            self._last_tick = now
+            self._evaluate_locked()
 
     def _evaluate_locked(self) -> Dict[str, Any]:  # requires-lock: _lock
         now = self.clock.monotonic()

@@ -705,6 +705,11 @@ class LiveDeviceEngine:
                             r_win=self.r_win, packed=self.packed,
                         )
                     self.dispatches += 1
+            # which program the dispatch launched, as count totals
+            if trains:
+                obs.tracer.add("live.launch.train", 0.0, len(trains))
+            else:
+                obs.tracer.add("live.launch.step", 0.0, len(built))
         return new_rows
 
     def _empty_batch(self) -> Batch:

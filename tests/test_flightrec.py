@@ -138,6 +138,28 @@ def test_flap_detection_dumps_once():
 # SLO engine unit tests
 # ----------------------------------------------------------------------
 
+def test_slo_tick_evaluates_once_a_second_whatever_the_heartbeat():
+    """A node's heartbeat calls `tick`: a pass a sixtieth of the shortest
+    window, so the samples a pass walks stay a few hundred (one a 10 ms
+    heartbeat was 30,000 by the longest window's end, walked on the gossip
+    loop's thread); `evaluate` itself still passes every time."""
+    clock = SimClock()
+    obs = Observability(clock=clock)
+    depth = obs.gauge("babble_device_queue_depth", "x")
+    slo = SLOEngine(obs)
+    slo.objective("queue_depth", series="babble_device_queue_depth",
+                  kind="below", threshold=4.5)
+    depth.set(40.0)
+    for k in range(1000):  # ten seconds of a 10 ms heartbeat
+        clock.advance_to(k * 0.01)
+        slo.tick()
+    assert len(slo._samples) == 10
+    assert slo.breached() == ["queue_depth"]  # and it still judges
+    slo.evaluate()
+    slo.evaluate()
+    assert len(slo._samples) == 12
+
+
 def test_slo_gauge_breach_fires_gauges_counter_and_dump():
     clock = SimClock()
     obs = Observability(clock=clock)

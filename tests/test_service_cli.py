@@ -5,13 +5,16 @@ precedence (run.go:93-155)."""
 
 import json
 import os
+import time
 import urllib.error
 import urllib.request
 
 from babble_tpu.cli import _merge_config_file, build_parser, keygen_command
 from babble_tpu.service import Service
 
-from test_node import bombard_and_wait, init_nodes, run_nodes, shutdown_nodes
+from test_node import (
+    bombard_and_wait, init_nodes, load_scale, run_nodes, shutdown_nodes,
+)
 
 REFERENCE_STAT_KEYS = {
     "last_consensus_round", "last_block_index", "consensus_events",
@@ -122,11 +125,37 @@ def test_service_metrics_and_trace():
         base = f"http://{svc.local_addr()}"
         bombard_and_wait(nodes, proxies, target_block=1)
 
-        req = urllib.request.urlopen(base + "/metrics", timeout=5)
+        # Block 1 on every node does not yet mean that node 0 has observed
+        # a commit of a transaction it submitted itself (the histogram
+        # counts no other: bombard_and_wait picks nodes at random), nor
+        # that a `commit` record still lies in the 4,096-span ring, which
+        # two nodes at a 5 ms heartbeat wrap in about a second. So keep
+        # node 0 committing its own transactions until one scrape shows
+        # both, and assert on that scrape.
+        def scrape():
+            req = urllib.request.urlopen(base + "/metrics", timeout=5)
+            return req, req.read().decode(), _get(base + "/debug/trace")
+
+        def commit_count(text):
+            for ln in text.splitlines():
+                if ln.startswith("babble_commit_latency_seconds_count"):
+                    return int(ln.split()[-1])
+            return 0
+
+        deadline = time.monotonic() + 60 * load_scale()
+        k = 0
+        while True:
+            req, text, trace = scrape()
+            if commit_count(text) >= 1 and any(
+                    e["name"] == "commit" for e in trace["traceEvents"]):
+                break
+            assert time.monotonic() < deadline, "no commit observed on node 0"
+            proxies[0].submit_tx(f"own tx {k}".encode())
+            k += 1
+            time.sleep(0.05)
         assert req.headers["Content-Type"].startswith(
             "text/plain; version=0.0.4"
         )
-        text = req.read().decode()
         # headline + subsystem histograms declared, with valid shape
         for name in (
             "babble_commit_latency_seconds",
@@ -151,7 +180,6 @@ def test_service_metrics_and_trace():
             '{phase="divide_rounds"}'
         ) in text
 
-        trace = _get(base + "/debug/trace")
         assert trace["displayTimeUnit"] == "ms"
         evs = trace["traceEvents"]
         xs = [e for e in evs if e["ph"] == "X"]
