@@ -163,8 +163,11 @@ class Hashgraph:
         # index of the block this hashgraph was last reset() from (-1 if
         # never reset): the anchor-serving walk cannot build frames below it
         self._reset_anchor_index: int = -1
-        # optional hook: called as (event, fd_writes) after every insert —
-        # the incremental device engine's delta feed (babble_tpu/tpu/live.py)
+        # optional hook: called as (event, cells) after every insert, `cells`
+        # the hashes of the ancestors whose first-descendant cell the insert
+        # wrote, in walk order (column and value are the event's own creator
+        # position and index) — the incremental device engine's delta feed
+        # (babble_tpu/tpu/live.py)
         self.insert_listener = None
 
     # ------------------------------------------------------------------
@@ -459,18 +462,19 @@ class Hashgraph:
 
     def _update_ancestor_first_descendant(
         self, event: Event, pos: int, coords: Tuple[int, str],
-    ) -> List[tuple]:
+    ) -> List[str]:
         """Walk each last-ancestor's self-parent chain marking this event as
         first descendant (reference: src/hashgraph/hashgraph.go:510-544).
-        Returns the (ancestor_hash, creator_pos, index) cells written — the
-        delta stream an incremental device engine replays. A step reads its
+        Returns the hashes of the ancestors whose cell was written, in walk
+        order — the delta stream an incremental device engine replays; the
+        cell's column and value are `pos` and the event's index for every
+        one of them, so they are not repeated per cell. A step reads its
         ancestor once (`get_event`: one look-up, one recency refresh) and
         tells the store of the cell it wrote (`update_event`: nothing for a
         store that holds the object, the write-back for one that persists)."""
         get_event = self.store.get_event
         update_event = self.store.update_event
-        index = coords[0]
-        writes: List[tuple] = []
+        writes: List[str] = []
         for _, ah in event.last_ancestors:
             while ah != "":
                 try:
@@ -482,7 +486,7 @@ class Hashgraph:
                     break
                 cells[pos] = coords
                 update_event(a)
-                writes.append((ah, pos, index))
+                writes.append(ah)
                 ah = a.body.parents[0]
         return writes
 
@@ -523,10 +527,10 @@ class Hashgraph:
         self._init_event_coordinates(event, sp, op, pos, coords)
         self.store.set_event(event)
         t_fd = now()
-        fd_writes = self._update_ancestor_first_descendant(event, pos, coords)
+        cells = self._update_ancestor_first_descendant(event, pos, coords)
         t_fd_done = now()
         if self.insert_listener is not None:
-            self.insert_listener(event, fd_writes)
+            self.insert_listener(event, cells)
 
         self.undetermined_events.append(coords[1])
         if event.is_loaded():

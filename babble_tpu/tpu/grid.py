@@ -29,7 +29,7 @@ hashgraph state, including after Reset/fast-sync.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -61,9 +61,10 @@ class DagGrid:
     levels: np.ndarray  # (L, N) int32 event rows, -1 padding
     num_levels: int
     hashes: Optional[List[str]] = None  # row -> event hex (host bookkeeping)
-    # per-event (row, col, value) first-descendant writes caused by that
-    # event's insert — the delta stream for the incremental engine
-    fd_update_stream: Optional[List[List[Tuple[int, int, int]]]] = None
+    # per event, the rows whose first-descendant cell that event's insert
+    # wrote (column and value: the event's own creator and index) — the
+    # delta stream for the incremental engine
+    fd_update_stream: Optional[List[List[int]]] = None
 
     @property
     def r_base(self) -> int:
@@ -594,10 +595,11 @@ def synthetic_grid(
     """
     rng = np.random.default_rng(seed)
     super_majority = 2 * n // 3 + 1
-    # per-event (row, col, value) first-descendant cell writes — the exact
+    # per event, the rows whose first-descendant cell its insert wrote
+    # (column and value: the event's own creator and index) — the exact
     # delta stream an incremental engine replays (own-cell write excluded;
     # it rides with the appended row)
-    fd_updates: List[List[Tuple[int, int, int]]] = [[] for _ in range(e_count)]
+    fd_updates: List[List[int]] = [[] for _ in range(e_count)]
 
     creator = np.zeros(e_count, dtype=np.int32)
     index = np.zeros(e_count, dtype=np.int32)
@@ -682,7 +684,7 @@ def synthetic_grid(
                 if fd[row, c] == MAX_INT32:
                     fd[row, c] = index[i]
                     if record_fd_updates:
-                        fd_updates[i].append((row, c, int(index[i])))
+                        fd_updates[i].append(row)
                     a -= 1
                 else:
                     break

@@ -41,6 +41,7 @@ witness/received equal to the one-shot pipeline's on the same DAG, through
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import NamedTuple
 
 import jax
@@ -48,7 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .kernels import MAX_INT32, received_core, suffix_min
-from .grid import DagGrid
+from .grid import DagGrid, GridUnsupported
 from .packed import pack_bits, pack_votes_t, packed_count, packed_tally, popcount_sum
 
 class IncState(NamedTuple):
@@ -589,35 +590,80 @@ def _pad1(a, pad, fill, dtype=np.int32):
     return np.concatenate([a, np.full(pad, fill, dtype=dtype)])
 
 
-def _pack_upd(upd, upd_cap, e_cap):
-    """Pack an (row, col, val) update list into fixed-shape scatter
-    operands (e_cap rows = dropped padding)."""
+# a batch of this many first-descendant cells or fewer is packed cell by
+# cell. numpy's fixed cost a call is a few microseconds alone and tens in a
+# served node's process, whose other threads run between the calls: the
+# bulk path's seven calls outweigh a loop over a served sync's 14-20 cells
+# (4 validators), and the loop a 32-event batch's 500-2,000 (16 and 64)
+FEW_CELLS = 64
+
+
+def _pack_upd(cell_rows, n_cells, counts, creator, index, upd_cap, e_cap):
+    """One batch's first-descendant scatter operands. `cell_rows` yields
+    the rows of the `n_cells` ancestors whose cell the batch's events
+    wrote, event after event, `counts[k]` of them for event k; a cell's
+    column and value are its writer's own creator position and index,
+    `creator[k]` and `index[k]`. A row of -1 is an ancestor that is no row
+    of the state any more: its fd row is final and can never be read
+    again, so the update is dropped, and the rest keep their order. Over
+    FEW_CELLS the cells are taken in bulk, with no Python object a cell.
+    Returns (urow, ucol, uval), padded to upd_cap with row e_cap (which the
+    scatter drops), and the number of cells staged. What lets the GIL go
+    costs a served node most, since its other threads take it: an
+    allocation of upd_cap elements does (so column and value share one),
+    and so does a mask over a second axis at any size (so everything
+    indexed is one-dimensional); PERF.md section 6, PR 32."""
+    if n_cells <= FEW_CELLS:
+        writers = (
+            w for w, count in zip(zip(creator, index), counts)
+            for _ in range(count)
+        )
+        upd = [(row, *w) for row, w in zip(cell_rows, writers) if row >= 0]
+        rows, cols, vals = zip(*upd) if upd else ((), (), ())
+    else:
+        cell_rows = np.fromiter(cell_rows, np.int32, n_cells)
+        kept = cell_rows >= 0
+        rows = cell_rows[kept]
+        cols = np.repeat(creator, counts)[kept]
+        vals = np.repeat(index, counts)[kept]
+    m = len(rows)
+    if m > upd_cap:
+        raise GridUnsupported("fd update burst exceeds device staging")
     urow = np.full(upd_cap, e_cap, dtype=np.int32)
-    ucol = np.zeros(upd_cap, dtype=np.int32)
-    uval = np.zeros(upd_cap, dtype=np.int32)
-    for k, (r, c, v) in enumerate(upd):
-        urow[k], ucol[k], uval[k] = r, c, v
-    return urow, ucol, uval
-
-
-def _dep_levels(sp_pos: "np.ndarray", op_pos: "np.ndarray") -> "np.ndarray":
-    """Dependency depth of each slice member over slice-LOCAL parent
-    positions (-1 = parent outside the slice): parents always land on
-    strictly earlier levels."""
-    b = len(sp_pos)
-    lvl = np.zeros(b, dtype=np.int64)
-    for k in range(b):
-        d = 0
-        for parent in (int(sp_pos[k]), int(op_pos[k])):
-            if parent >= 0:
-                d = max(d, lvl[parent] + 1)
-        lvl[k] = d
-    return lvl
+    ucol, uval = np.zeros((2, upd_cap), dtype=np.int32)
+    urow[:m] = rows
+    ucol[:m] = cols
+    uval[:m] = vals
+    return urow, ucol, uval, m
 
 
 # static height of the within-batch level table; a gossip batch deeper
 # than this (one creator chaining >L_MAX events) is split automatically
 L_MAX = 16
+
+
+def _dep_levels(sp_pos, op_pos):
+    """Dependency depth of each slice member over slice-LOCAL parent
+    positions (plain ints; negative = parent outside the slice): parents
+    always land on strictly earlier levels."""
+    lvl = []
+    for s, o in zip(sp_pos, op_pos):
+        d = lvl[s] + 1 if s >= 0 else 0
+        if o >= 0 and lvl[o] >= d:
+            d = lvl[o] + 1
+        lvl.append(d)
+    return lvl
+
+
+def _level_table(lvl, batch_size):
+    """(L_MAX, batch_size) table of the slice positions on each level, in
+    slice order, -1 padding. The caller keeps every depth under L_MAX."""
+    levels = np.full((L_MAX, batch_size), -1, dtype=np.int32)
+    slot = [0] * L_MAX
+    for k, l in enumerate(lvl):
+        levels[l, slot[l]] = k
+        slot[l] += 1
+    return levels
 
 
 def batches_from_grid(grid: DagGrid, batch_size: int, upd_cap: int, e_cap: int):
@@ -643,22 +689,19 @@ def batches_from_grid(grid: DagGrid, batch_size: int, upd_cap: int, e_cap: int):
         # within-batch levels: level over batch-local dependency depth
         sp_loc = np.where((sp >= start) & (sp < end), sp - start, -1)
         op_loc = np.where((op >= start) & (op < end), op - start, -1)
-        lvl = _dep_levels(sp_loc, op_loc)
-        l_b = int(lvl.max(initial=-1)) + 1 if b else 0
-        upd = [t for r in rows for t in grid.fd_update_stream[r]]
-        if l_b > L_MAX or (len(upd) > upd_cap and b > 1):
+        lvl = _dep_levels(sp_loc.tolist(), op_loc.tolist())
+        l_b = max(lvl, default=-1) + 1
+        cells = [grid.fd_update_stream[r] for r in range(start, end)]
+        counts = [len(c) for c in cells]
+        n_upd = sum(counts)
+        if l_b > L_MAX or (n_upd > upd_cap and b > 1):
             mid = (start + end) // 2
             spans[:0] = [(start, mid), (mid, end)]
             continue
-        levels_full = np.full((L_MAX, batch_size), -1, dtype=np.int32)
-        slot = np.zeros(max(l_b, 1), dtype=np.int64)
-        for k in range(b):
-            levels_full[lvl[k], slot[lvl[k]]] = k
-            slot[lvl[k]] += 1
-
-        if len(upd) > upd_cap:
-            raise ValueError(f"fd update burst {len(upd)} exceeds cap {upd_cap}")
-        urow, ucol, uval = _pack_upd(upd, upd_cap, e_cap)
+        urow, ucol, uval, _ = _pack_upd(
+            itertools.chain.from_iterable(cells), n_upd,
+            counts, grid.creator[rows], grid.index[rows], upd_cap, e_cap,
+        )
 
         no_row = np.full(batch_size, -1, dtype=np.int32)  # a grid holds every parent
         out.append(Batch(
@@ -674,7 +717,7 @@ def batches_from_grid(grid: DagGrid, batch_size: int, upd_cap: int, e_cap: int):
             coin=_pad1(grid.coin_bit[rows], pad, False, dtype=bool),
             fixed_round=_pad1(grid.fixed_round[rows], pad, -1),
             upd_row=urow, upd_col=ucol, upd_val=uval,
-            levels=levels_full,
+            levels=_level_table(lvl, batch_size),
             sp_lamport=no_row, op_lamport=no_row,
         ))
     return out

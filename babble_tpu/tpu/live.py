@@ -9,8 +9,11 @@ O(batch), mirroring the reference's UndeterminedEvents discipline
 state.
 
 Wiring: the Hashgraph's insert path reports each inserted event plus the
-first-descendant cells its insert wrote (hashgraph.insert_listener);
-run_consensus_live drains that queue into fixed-shape append batches,
+first-descendant cells its insert wrote (hashgraph.insert_listener): the
+hashes of the ancestors written, since a cell's column and value are the
+event's own creator position and index. run_consensus_live drains that
+queue into fixed-shape append batches, a batch's cells going to the three
+update arrays in bulk (`_build_batch`, `incremental._pack_upd`),
 advances the device state, and writes new rounds/fame/received back into
 the store exactly like the one-shot engine. Passes 4-5 stay host-side, so
 blocks remain byte-identical by construction.
@@ -24,6 +27,7 @@ the one-shot device path (which itself falls back to the CPU engine).
 
 from __future__ import annotations
 
+import itertools
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -34,6 +38,9 @@ from .incremental import (
     Batch,
     IncState,
     L_MAX,
+    _dep_levels,
+    _level_table,
+    _pack_upd,
     init_state,
     multi_step,
     stack_batches,
@@ -42,22 +49,23 @@ from .incremental import (
 from .packed import observe_table_bytes, packed_enabled
 
 
-def derive_fd_updates(grid: DagGrid) -> List[List[Tuple[int, int, int]]]:
+def derive_fd_updates(grid: DagGrid) -> List[List[int]]:
     """Reconstruct the per-event first-descendant write stream from a
-    completed grid: cell fd[row, c] == v was written by the insert of the
-    event (creator c, index v). O(E*N)."""
+    completed grid (per event, the rows whose cell it wrote): cell
+    fd[row, c] == v was written by the insert of the event (creator c,
+    index v). O(E*N)."""
     rows_by = np.full(
         (grid.n, int(grid.index.max(initial=0)) + 1), -1, dtype=np.int32
     )
     if grid.e:
         rows_by[grid.creator, grid.index] = np.arange(grid.e, dtype=np.int32)
-    stream: List[List[Tuple[int, int, int]]] = [[] for _ in range(grid.e)]
+    stream: List[List[int]] = [[] for _ in range(grid.e)]
     rows, cols = np.nonzero(grid.first_descendants != MAX_INT32)
     vals = grid.first_descendants[rows, cols]
     for row, c, v in zip(rows.tolist(), cols.tolist(), vals.tolist()):
         updater = int(rows_by[c, v])
         if updater != row:  # own-cell writes ride with the appended row
-            stream[updater].append((int(row), int(c), int(v)))
+            stream[updater].append(row)
     return stream
 
 
@@ -144,6 +152,10 @@ class LiveDeviceEngine:
         # the most events one call has staged so far: the event axis keeps
         # room for two more such syncs (_capacity_soft)
         self.largest_sync = 0
+        # first-descendant cells staged so far (_build_batch; the cells of
+        # pruned ancestors are dropped and not counted): advance hands the
+        # tracer each dispatch's share as the total `stage.cells`
+        self.cells_staged = 0
         self._m_dispatch = hg.obs.histogram(
             "babble_device_dispatch_seconds",
             "Host-side device program launch time per advance",
@@ -194,20 +206,21 @@ class LiveDeviceEngine:
         self.reopened_seen = np.zeros(self.r_cap, np.int32)
         self.row_of: Dict[str, int] = {}
         self.hashes: List[str] = []
-        self.pending: List[tuple] = []  # (event, fd_writes)
+        self.pending: List[tuple] = []  # (event, cells)
         self._bootstrap()
         hg.insert_listener = self._on_insert
 
     # -- hashgraph hooks ---------------------------------------------------
 
-    def _on_insert(self, event, fd_writes) -> None:
-        """Called by Hashgraph.insert_event with the event and the
-        (ancestor_hash, creator_pos, index) first-descendant cells its
-        insert wrote."""
+    def _on_insert(self, event, cells) -> None:
+        """Called by Hashgraph.insert_event with the event and the hashes
+        of the ancestors whose first-descendant cell its insert wrote, in
+        walk order (column and value: the event's own creator position and
+        index)."""
         if not self.pending:
             # batch-deadline anchor, on the injected Clock (sim-safe)
             self._pending_since = self.hg.obs.clock.monotonic()
-        self.pending.append((event, fd_writes))
+        self.pending.append((event, cells))
 
     def detach(self) -> None:
         if getattr(self.hg, "insert_listener", None) is self._on_insert:
@@ -663,7 +676,7 @@ class LiveDeviceEngine:
                 # first-descendant updates (a wide validator set, or a
                 # withheld chain revealed at once, bursts past the staging)
                 built: List[Batch] = []
-                pos = 0
+                pos, staged_before = 0, self.cells_staged
                 while pos < len(drained):
                     chunk = drained[pos : pos + self.batch_cap]
                     chunk = self._cut(chunk)
@@ -705,7 +718,11 @@ class LiveDeviceEngine:
                             r_win=self.r_win, packed=self.packed,
                         )
                     self.dispatches += 1
+            # the cells the stage took in bulk (the pruned ones dropped) and
             # which program the dispatch launched, as count totals
+            obs.tracer.add(
+                "stage.cells", 0.0, self.cells_staged - staged_before,
+            )
             if trains:
                 obs.tracer.add("live.launch.train", 0.0, len(trains))
             else:
@@ -746,110 +763,112 @@ class LiveDeviceEngine:
         bound). One event over the cap alone is left to _build_batch."""
         depth: Dict[str, int] = {}
         updates = 0
-        for k, (ev, fd_writes) in enumerate(chunk):
+        for k, (ev, cells) in enumerate(chunk):
             d = 0
             for parent in (ev.self_parent(), ev.other_parent()):
                 if parent in depth:
                     d = max(d, depth[parent] + 1)
-            updates += len(fd_writes)
+            updates += len(cells)
             if d >= L_MAX or (k and updates > self.upd_cap):
                 return chunk[:k]
             depth[ev.hex()] = d
         return chunk
 
     def _build_batch(self, chunk) -> Tuple[Batch, List[int]]:
+        """One append batch from `chunk` ((event, cells) pairs, as the
+        listener was handed them): the Batch and the rows it appends. Built
+        per batch: a field is a Python list through the event loop and one
+        slice store after it, and the cells of all the chunk's events
+        become the three update arrays in one `_pack_upd`."""
         n, b_cap = self.n, self.batch_cap
         b = len(chunk)
-        rows = []
-        creator = np.zeros(b_cap, dtype=np.int32)
-        index = np.full(b_cap, MAX_INT32, dtype=np.int32)
-        sp_row = np.full(b_cap, -1, dtype=np.int32)
-        op_row = np.full(b_cap, -1, dtype=np.int32)
-        la_rows = np.full((b_cap, n), -1, dtype=np.int32)
-        coin = np.zeros(b_cap, dtype=bool)
+        row_of, position = self.row_of, self.hg.peer_position
+        base_row = len(self.hashes)
+        rows = list(range(base_row, base_row + b))
+        creators, indexes, sp_rows, op_rows, coins = [], [], [], [], []
+        la_flat: List[int] = []
+        cell_hashes: List[str] = []
+        counts: List[int] = []
         fixed_round = np.full(b_cap, -1, dtype=np.int32)
-        parent_lamport = np.full((2, b_cap), -1, dtype=np.int32)
-        upd: List[Tuple[int, int, int]] = []
+        sp_lamport = np.full(b_cap, -1, dtype=np.int32)
+        op_lamport = np.full(b_cap, -1, dtype=np.int32)
 
         from ..hashgraph.hashgraph import middle_bit
 
-        for k, (ev, fd_writes) in enumerate(chunk):
-            row = len(self.hashes)
+        for k, (ev, cells) in enumerate(chunk):
             h = ev.hex()
-            self.row_of[h] = row
+            row_of[h] = base_row + k
             self.hashes.append(h)
-            rows.append(row)
 
-            creator[k] = self.hg.peer_position(ev.creator())
-            index[k] = ev.index()
-            sp = self.row_of.get(ev.self_parent(), -1)
-            op = self.row_of.get(ev.other_parent(), -1)
+            creators.append(position(ev.creator()))
+            idx, sp_hash, op_hash = ev.index(), ev.self_parent(), ev.other_parent()
+            indexes.append(idx)
+            sp = row_of.get(sp_hash, -1)
+            op = row_of.get(op_hash, -1)
             # a rebase dropped decided history, and an event may still name
             # it (a creator reviving after rounds of silence, a withheld
             # chain revealed late): the parent's round lies below the base,
             # which "no row" already says to the device, and its lamport
             # timestamp is the host's stamp
-            if sp < 0 and ev.index() != 0:
-                parent_lamport[0, k] = self._pruned_lamport(ev.self_parent())
-            if op < 0 and ev.other_parent() != "":
-                parent_lamport[1, k] = self._pruned_lamport(ev.other_parent())
-            if sp < 0 and ev.other_parent() == "":
+            if sp < 0 and idx != 0:
+                sp_lamport[k] = self._pruned_lamport(sp_hash)
+            if op < 0 and op_hash != "":
+                op_lamport[k] = self._pruned_lamport(op_hash)
+            if sp < 0 and op_hash == "":
                 # directly root-attached: round forced to the base root's
                 # next_round (reference: hashgraph.go:207-236); first
                 # events WITH an other-parent compute theirs normally.
-                # Rounds are base-relative on device; genesis attachment
+                # Roots here are the genesis base roots (round -1), which
                 # can only occur before any rebase (base 0).
                 if self.round_base > 0:
                     raise GridUnsupported("root attachment after rebase")
                 fixed_round[k] = 0
-            sp_row[k] = sp
-            op_row[k] = op
-            la_rows[k] = [c[0] for c in ev.last_ancestors]
-            coin[k] = middle_bit(h)
-            for ah, pos, val in fd_writes:
-                arow = self.row_of.get(ah)
-                if arow is None:
-                    # pruned-by-rebase ancestor: its fd row is final and
-                    # can never be read again — drop the update. (fd
-                    # writes come from the hashgraph's own insert walk,
-                    # so the hash is always a real ancestor.)
-                    continue
-                upd.append((arow, pos, val))
-
-        if len(upd) > self.upd_cap:
-            raise GridUnsupported("fd update burst exceeds device staging")
-
-        # within-batch levels over batch-local dependencies
-        base_row = rows[0]
-        lvl = np.zeros(b, dtype=np.int64)
-        for k in range(b):
-            d = 0
-            for parent in (int(sp_row[k]), int(op_row[k])):
-                if parent >= base_row:
-                    d = max(d, lvl[parent - base_row] + 1)
-            lvl[k] = d
-        # caller (_cut) guarantees depth < L_MAX
-        levels = np.full((L_MAX, b_cap), -1, dtype=np.int32)
-        slot = np.zeros(L_MAX, dtype=np.int64)
-        for k in range(b):
-            levels[lvl[k], slot[lvl[k]]] = k
-            slot[lvl[k]] += 1
-
-        urow = np.full(self.upd_cap, self.e_cap, dtype=np.int32)
-        ucol = np.zeros(self.upd_cap, dtype=np.int32)
-        uval = np.zeros(self.upd_cap, dtype=np.int32)
-        for k, (r, c, v) in enumerate(upd):
-            urow[k], ucol[k], uval[k] = r, c, v
+            sp_rows.append(sp)
+            op_rows.append(op)
+            la_flat.extend([c[0] for c in ev.last_ancestors])
+            coins.append(middle_bit(h))
+            cell_hashes.extend(cells)
+            counts.append(len(cells))
 
         brows = np.full(b_cap, -1, dtype=np.int32)
         brows[:b] = rows
+        creator = np.zeros(b_cap, dtype=np.int32)
+        creator[:b] = creators
+        index = np.full(b_cap, MAX_INT32, dtype=np.int32)
+        index[:b] = indexes
+        sp_row = np.full(b_cap, -1, dtype=np.int32)
+        sp_row[:b] = sp_rows
+        op_row = np.full(b_cap, -1, dtype=np.int32)
+        op_row[:b] = op_rows
+        coin = np.zeros(b_cap, dtype=bool)
+        coin[:b] = coins
+        la_rows = np.full((b_cap, n), -1, dtype=np.int32)
+        la_rows.reshape(-1)[: b * n] = la_flat
+        # the cells' rows, resolved after the loop so that an ancestor of
+        # the same batch has its row. No row: pruned by a rebase, its fd
+        # row is final, and _pack_upd drops the update. (The cells come
+        # from the hashgraph's own insert walk, so a hash is always a real
+        # ancestor.)
+        urow, ucol, uval, staged = _pack_upd(
+            map(row_of.get, cell_hashes, itertools.repeat(-1)),
+            len(cell_hashes), counts, creators, indexes,
+            self.upd_cap, self.e_cap,
+        )
+        self.cells_staged += staged
+        # within-batch levels over batch-local dependencies (a parent of an
+        # earlier batch is below base_row: negative, outside the slice);
+        # the caller (_cut) guarantees depth < L_MAX
+        lvl = _dep_levels(
+            [p - base_row for p in sp_rows], [p - base_row for p in op_rows],
+        )
         return (
             Batch(
                 rows=brows, creator=creator, index=index,
                 sp_row=sp_row, op_row=op_row, la_rows=la_rows, coin=coin,
                 fixed_round=fixed_round,
-                upd_row=urow, upd_col=ucol, upd_val=uval, levels=levels,
-                sp_lamport=parent_lamport[0], op_lamport=parent_lamport[1],
+                upd_row=urow, upd_col=ucol, upd_val=uval,
+                levels=_level_table(lvl, b_cap),
+                sp_lamport=sp_lamport, op_lamport=op_lamport,
             ),
             rows,
         )
@@ -979,8 +998,17 @@ def run_consensus_live(hg, queue_depth: int = None,
 
 # blocking-fetch cost that flips an engine to the pipelined discipline
 # (3 consecutive calls over the threshold); ENGINE_DEFAULTS["async_fetch"]
-# forces True/False for tests
-ASYNC_FETCH_MIN_S = 0.010
+# forces True/False for tests. The wait is a sync's device time less what
+# the host does between the launch and the fetch. 10 ms until PR 32, when
+# a one-train sync at 64 validators read 7.5 ms because freeing the sync's
+# 32,000 cell tuples ran behind the launch; without the tuples it reads
+# the train itself, ~10 ms, and sat on the threshold. 12 ms keeps such a
+# sync on the synchronous discipline it had; syncs of two trains, or of a
+# narrower state's longer one, wait 13 ms and more and pipeline as before
+# (PERF.md section 6, PR 32: every cell's waits over six runs). A fixed
+# number of milliseconds stays the wrong rule for this trade of the wait
+# against queue_depth - 1 syncs of latency: ROADMAP.md queue A item 7.
+ASYNC_FETCH_MIN_S = 0.012
 
 
 class _AsyncFetch:

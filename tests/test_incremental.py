@@ -99,8 +99,7 @@ def as_an_honest_validator_receives_it(grid, n_byz):
         # a first-descendant write belongs to the descendant, whenever it
         # arrives: only the row numbers move
         fd_update_stream=[
-            [(int(new_row[r]), c, v) for r, c, v in grid.fd_update_stream[i]]
-            for i in order
+            [int(new_row[r]) for r in grid.fd_update_stream[i]] for i in order
         ],
         **per_row,
     )

@@ -206,10 +206,23 @@ def stream8():
 
 def tracked(hg):
     """Record what the insert listener is handed and what the event cache
-    evicts."""
+    evicts. The plain walk hands (ancestor, column, value) triples; the
+    insert hands the ancestors alone, column and value being the event's
+    own creator position and index: spelled out here with the event's own,
+    so that equality holds the ancestors, their order and both to the plain
+    triples."""
     fed, evicted = [], []
-    hg.insert_listener = lambda ev, writes: fed.append((ev.hex(), list(writes)))
-    hg.store.event_cache.on_evict = lambda key, _ev: evicted.append(key)
+    if isinstance(hg, PlainInsert):
+        def listener(ev, writes):
+            fed.append((ev.hex(), list(writes)))
+    else:
+        def listener(ev, cells):
+            assert all(type(ah) is str for ah in cells)
+            pos, index = hg.peer_position(ev.creator()), ev.index()
+            fed.append((ev.hex(), [(ah, pos, index) for ah in cells]))
+    hg.insert_listener = listener
+    cache = getattr(hg.store, "inmem", hg.store).event_cache
+    cache.on_evict = lambda key, _ev: evicted.append(key)
     return fed, evicted
 
 
@@ -439,12 +452,12 @@ def test_sqlite_store_persists_the_walks_cells(stream8, tmp_path):
     hg, plain, ref = (Hashgraph(peers, disk), PlainInsert(peers, plain_disk),
                       Hashgraph(peers, mem))
     fed, _ = tracked(ref)
-    disk_fed = []
-    hg.insert_listener = lambda ev, w: disk_fed.append((ev.hex(), list(w)))
+    disk_fed, _ = tracked(hg)
+    plain_fed, _ = tracked(plain)
     for signed in events:
         for g in (hg, plain, ref):
             g.insert_event(stream8.copy(signed), True)
-    assert disk_fed == fed
+    assert disk_fed == fed == plain_fed
     # what is on disk is what the plain write-back put there, row for row
     assert db_rows(disk) == db_rows(plain_disk)
     assert (disk.inmem.event_cache.keys()
