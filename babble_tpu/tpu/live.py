@@ -71,6 +71,21 @@ def derive_fd_updates(grid: DagGrid) -> List[List[int]]:
 
 # constructor defaults, module-level so tests can shrink the capacities
 # to force rebases quickly.
+# Which capacities follow the validator count n, and which are flat:
+# - e_win, the received window, follows it: the value here is per 32
+#   validators (LiveDeviceEngine.__init__), 32,768 rows at 128;
+# - upd_cap, the first-descendant updates a batch stages, is flat. An
+#   inserted event writes about n cells, so a 32-row batch carries ~2,000
+#   at 64 validators (none over the cap) and ~4,000 at 128, where one
+#   batch in a hundred passes it (worst counted: 12,697): _cut ends such a
+#   batch early and counts it (tracer total `stage.cut`). On the chip at
+#   128 a staging of 16,384 read the same rate as the cuts (PERF.md
+#   section 6, PR 33: most second trains come from the level table's cuts
+#   of revealed chains, not from the cap's), so the cap did not follow;
+# - e_cap, the event axis, is flat: 65,536 rows hold two 500-event syncs,
+#   the held chain heads and the ~21,000-25,000 undetermined rows of a
+#   128-validator withheld stream between rebases (one rebase a window);
+# - r_cap / r_win, batch_cap, queue_depth are flat.
 # r_win (the live-stepping round window) widened 32 -> 64 DELIBERATELY in
 # round 5: post-fast-sync recovery states exhibit round spans past 32
 # that tripped the attach span guard into attach/demote/retry churn
@@ -156,6 +171,10 @@ class LiveDeviceEngine:
         # pruned ancestors are dropped and not counted): advance hands the
         # tracer each dispatch's share as the total `stage.cells`
         self.cells_staged = 0
+        # batches `_cut` ended because their first-descendant updates would
+        # pass the staging (not those it ended for L_MAX): advance hands
+        # the tracer each dispatch's share as the total `stage.cut`
+        self.update_cuts = 0
         self._m_dispatch = hg.obs.histogram(
             "babble_device_dispatch_seconds",
             "Host-side device program launch time per advance",
@@ -677,6 +696,7 @@ class LiveDeviceEngine:
                 # withheld chain revealed at once, bursts past the staging)
                 built: List[Batch] = []
                 pos, staged_before = 0, self.cells_staged
+                cuts_before = self.update_cuts
                 while pos < len(drained):
                     chunk = drained[pos : pos + self.batch_cap]
                     chunk = self._cut(chunk)
@@ -723,6 +743,7 @@ class LiveDeviceEngine:
             obs.tracer.add(
                 "stage.cells", 0.0, self.cells_staged - staged_before,
             )
+            obs.tracer.add("stage.cut", 0.0, self.update_cuts - cuts_before)
             if trains:
                 obs.tracer.add("live.launch.train", 0.0, len(trains))
             else:
@@ -760,7 +781,11 @@ class LiveDeviceEngine:
         stays under the level-table height and whose first-descendant
         updates fit the staging (`upd_cap`; the count here includes
         updates to pruned rows, which _build_batch drops: an upper
-        bound). One event over the cap alone is left to _build_batch."""
+        bound). One event over the cap alone is left to _build_batch.
+        `upd_cap` is flat while an event's cells grow with the validator
+        count (ENGINE_DEFAULTS): from 128 validators on this is what
+        serves the batches that pass it, and `update_cuts` counts them
+        (cuts at the level table's height are not counted)."""
         depth: Dict[str, int] = {}
         updates = 0
         for k, (ev, cells) in enumerate(chunk):
@@ -769,7 +794,10 @@ class LiveDeviceEngine:
                 if parent in depth:
                     d = max(d, depth[parent] + 1)
             updates += len(cells)
-            if d >= L_MAX or (k and updates > self.upd_cap):
+            if d >= L_MAX:
+                return chunk[:k]
+            if k and updates > self.upd_cap:
+                self.update_cuts += 1
                 return chunk[:k]
             depth[ev.hex()] = d
         return chunk

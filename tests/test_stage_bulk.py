@@ -294,7 +294,7 @@ def stage_only(stream, events, sync_sizes, **caps):
     (`_cut`, `_build_batch`) with nothing launched: staging alone, at any
     width, on this CPU."""
     hg = Hashgraph(stream.peers, InmemStore(stream.peers, 50000))
-    eng = LiveDeviceEngine(hg, e_cap=4096, **caps)
+    eng = LiveDeviceEngine(hg, **{"e_cap": 4096, **caps})
     seen = Compared()
     done = 0
     for size in sync_sizes:
@@ -347,6 +347,31 @@ def test_v64_batches_of_32_are_byte_equal(stream64):
     assert seen.batches >= 3000 // 32 and max(seen.events) == 32
     assert seen.dropped == 0 and seen.staged == seen.handed > 3000 * 40
     assert seen.bulk > 80  # whole batches are far over FEW_CELLS
+    assert eng.update_cuts == 0  # ~2,000 cells a batch: none passes 8,192
+
+
+@pytest.fixture(scope="module")
+def stream128():
+    return replay.Stream(128, 6048, 5, 1.1, 1)
+
+
+@pytest.mark.parametrize("cap", [8192, 4096])
+def test_v128_batches_of_32_are_byte_equal(stream128, cap):
+    """128 validators, the cells' lead-in (32-event syncs) and then
+    500-event syncs in 32-event batches: ~100 cells an event on a young
+    honest DAG, ~3,200 a batch. Under the engine's staging of 8,192 no
+    batch of this stream is cut; at half of it `_cut` ends the batches that
+    would pass it, counts them, and what is staged fits."""
+    events = 6048
+    eng, seen = stage_only(stream128, events, [32] * 64 + [500] * 8,
+                           batch_cap=32, e_cap=8192, upd_cap=cap)
+    assert seen.refused == [] and len(eng.hashes) == events
+    assert max(seen.events) == 32
+    assert seen.dropped == 0 and seen.staged == seen.handed > events * 80
+    if cap == 8192:
+        assert eng.update_cuts == 0
+    else:
+        assert 10 < eng.update_cuts <= sum(size < 32 for size in seen.events)
 
 
 @pytest.mark.parametrize("n", [8, 4])
@@ -374,6 +399,8 @@ def test_a_batch_cut_at_the_update_cap_is_byte_equal(stream64):
     # the first batches, of chains a few events long, are whole; most are cut
     assert seen.batches > 1500 // 32 + 20 and min(seen.events) < 20
     assert seen.staged == seen.handed
+    # every batch but a sync's last was ended by the cap, and counted
+    assert eng.update_cuts >= seen.batches - 3 - 1500 // 32
 
 
 def test_one_event_over_the_update_cap_is_refused_alike(stream64):
@@ -494,3 +521,26 @@ def test_an_event_over_the_cap_in_a_grid_is_refused_alike():
     # the one check of the cap is the packer's, for an attach as for a sync
     with pytest.raises(GridUnsupported, match="fd update burst"):
         batches_from_grid(grid, 32, 4, 1024)
+
+
+# ---------------------------------------------------------------------------
+# (d) which capacities follow the validator count
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n,e_win", [(4, 8192), (16, 8192), (64, 16384),
+                                     (128, 32768)])
+def test_capacities_by_width(n, e_win):
+    """The received window follows the validator count (8,192 rows per 32
+    validators); the staging, the event axis and the round axis are flat,
+    so every width's programs keep the shapes they had. At 128 `_cut`
+    serves the batches that pass the staging (above, and
+    tests/test_withheld_stream.py)."""
+    stream = replay.Stream(n, n, 1, 1.1, 1)
+    hg = Hashgraph(stream.peers, InmemStore(stream.peers, 100))
+    eng = LiveDeviceEngine(hg)
+    d = live_mod.ENGINE_DEFAULTS
+    assert (eng.n, eng.e_win) == (n, e_win)
+    assert (eng.upd_cap, eng.e_cap, eng.r_cap, eng.r_win) == (
+        d["upd_cap"], d["e_cap"], d["r_cap"], d["r_win"]) == (8192, 65536, 64, 64)
+    assert eng._empty_batch().upd_row.shape == (8192,)

@@ -25,17 +25,27 @@ SIZES = [(8, 2400, "24-64"), (16, 4000, "12-48")]
 SYNCS = [(32, {}, "step"), (100, {"dispatch_batch_rows": 32}, "multi_step")]
 
 
-def withheld_stream(monkeypatch, n, events, seed, span):
+def withheld_stream(monkeypatch, n, events, seed, span, topology_seed=None):
     cfg = dict(validators=n, events=events, zipf_a=1.1, byzantine=n // 3,
                withhold_span=span, withhold_start_p=1 / 24,
                max_hidden=max(n // 8, 1))
     traffic = replay_adversarial.WithheldTraffic(cfg)
     monkeypatch.setattr(replay, "gen", traffic)
-    stream = replay.Stream(n, events, seed, 1.1, 1)
+    stream = replay.Stream(n, events, seed, 1.1, 1, topology_seed)
     return stream, traffic.drawn
 
 
-def drive(stream, backend, sync, knobs):
+def sync_ranges(total, sync, lead_in=()):
+    """[lo, hi) rows a sync: the lead-in's phases (events, events a sync),
+    as a traffic file states them, then syncs of `sync` events."""
+    lo = 0
+    for events, size in tuple(lead_in) + ((total, sync),):
+        for a, b in gen.syncs(min(events, total - lo), size):
+            yield lo + a, lo + b
+        lo = min(lo + events, total)
+
+
+def drive(stream, backend, sync, knobs, lead_in=()):
     """The whole stream through a fresh observer Core. Returns the Core,
     its blocks, the stamps it left, syncs unserved, and the late witnesses
     it registered (witnesses divided into a round that was whole)."""
@@ -53,7 +63,7 @@ def drive(stream, backend, sync, knobs):
 
     core.hg.queue_round = spy
     served = replay.Served(core)
-    for lo, hi in gen.syncs(len(handed), sync):
+    for lo, hi in sync_ranges(len(handed), sync, lead_in):
         for ev in handed[lo:hi]:
             core.insert_event(ev, True)
         core.run_consensus()
@@ -157,22 +167,71 @@ def test_a_round_below_the_base_still_latches(monkeypatch):
             == [b.body.marshal() for _, b in cpu_blocks])
 
 
-def test_a_batch_is_cut_at_the_update_cap(monkeypatch):
+@pytest.mark.parametrize("n,events,span,cap,knobs", [
+    (8, 1600, "24-64", 256, {}),
+    (16, 2400, "12-48", 256, {"dispatch_batch_rows": 32}),
+], ids=["v8-rows64", "v16-rows32"])
+def test_a_batch_is_cut_at_the_update_cap(monkeypatch, n, events, span, cap,
+                                          knobs):
     """A staging of 256 first-descendant updates a batch: the engine cuts
-    its batches there (a revealed chain bursts past it) instead of
-    demoting, and the decisions stay the host engine's."""
+    its batches there (a revealed chain bursts past it; at 16 validators a
+    32-row batch carries ~500) instead of demoting, counts them in the
+    total `stage.cut`, and the decisions stay the host engine's."""
     monkeypatch.setitem(live_mod.ENGINE_DEFAULTS, "async_fetch", False)
-    monkeypatch.setitem(live_mod.ENGINE_DEFAULTS, "upd_cap", 256)
-    stream, _ = withheld_stream(monkeypatch, 8, 1600, 1, "24-64")
-    _, cpu_blocks, cpu_stamps, _, _ = drive(stream, "cpu", 100, {})
-    tpu, blocks, stamps, unserved, _ = drive(stream, "tpu", 100, {})
+    monkeypatch.setitem(live_mod.ENGINE_DEFAULTS, "upd_cap", cap)
+    stream, _ = withheld_stream(monkeypatch, n, events, 1, span)
+    _, cpu_blocks, cpu_stamps, _, _ = drive(stream, "cpu", 100, knobs)
+    tpu, blocks, stamps, unserved, _ = drive(stream, "tpu", 100, knobs)
     spans = tpu.hg.obs.tracer.spans()
     staged = [s.attrs for s in spans if s.name == "live.stage"]
     batches = [s.attrs["batches"] for s in spans if s.name == "device.dispatch"]
+    rows = knobs.get("dispatch_batch_rows", 64)
     # some sync carried more updates than one batch stages, and was cut
-    assert max(a["fd_updates"] for a in staged) > 2 * 256
-    assert max(batches) > 2  # 100 events are two batches of 64 rows uncut
+    assert max(a["fd_updates"] for a in staged) > 2 * cap
+    assert max(batches) > -(-100 // rows)  # what 100 events are uncut
     assert unserved == 0 and tpu.live_demotions == 0
     assert (stamps == cpu_stamps).all()
     assert ([b.body.marshal() for _, b in blocks]
             == [b.body.marshal() for _, b in cpu_blocks])
+    # the cuts at the staging are counted apart from the level table's and
+    # handed to the tracer once a dispatch
+    cut = tpu.hg.obs.tracer.totals()["stage.cut"][0]
+    assert cut == tpu.hg._live_device_engine.update_cuts > 0
+
+
+def test_width_128_agrees_with_the_reference(monkeypatch):
+    """`v128-byz` as its cell hands it over, at the real width and the
+    engine's own capacities: 128 validators of which 42 withhold (16 at
+    once, 32-128 own events), the configuration's DAG, the traffic file's
+    lead-in (2,048 events in 32-event syncs) and then 500-event syncs in
+    32-row batches, for as long as it takes to commit (the first block
+    comes 13,048 rows in) and to re-open a decided round. Every stamp and
+    every block is the plain reference's; every sync is served."""
+    monkeypatch.setitem(live_mod.ENGINE_DEFAULTS, "async_fetch", False)
+    n, events = 128, 16048
+    stream, drawn = withheld_stream(monkeypatch, n, events, 7, "32-128",
+                                    topology_seed=103)
+    assert drawn.late.sum() > events // 20
+    tpu, blocks, stamps, unserved, late = drive(
+        stream, "tpu", 500, {"dispatch_batch_rows": 32}, lead_in=[(2048, 32)])
+    assert unserved == 0 and tpu.ladder_rung() == "live"
+    assert (tpu.live_demotions, tpu.device_consensus_fallbacks,
+            tpu.device_attach_failures) == (0, 0, 0)
+    want = reference.order(*replay.reference_inputs(stream, events))
+    handed_over = [(b.index(), b.round_received(), b.transactions())
+                   for _, b in blocks]
+    assert replay.mismatches((stamps, handed_over), want) == {
+        "events_mismatched": 0, "blocks_mismatched": 0}
+    assert len(blocks) >= 1 and len(blocks[0][1].transactions()) > 1000
+    totals = tpu.hg.obs.tracer.totals()
+    # the mechanism ran: a witness landed in a decided round, served in place
+    assert len(late) > 0 and totals["fame.reopen"][0] > 0
+    assert totals["live.late_witness"][0] > 0
+    assert "live.host_repair" not in totals
+    # the programs the cell launches, and the staging's cuts counted: at this
+    # width a 32-row batch carries ~4,000 cells and now and then over 8,192
+    eng = tpu.hg._live_device_engine
+    assert totals["live.launch.step"][0] >= 60  # the attach is not in it
+    assert totals["live.launch.train"][0] >= 28
+    assert totals["stage.cut"][0] == eng.update_cuts
+    assert totals["stage.cells"][0] == eng.cells_staged > 100 * events
