@@ -61,7 +61,8 @@ class Event:
         "lamport_timestamp",
         "round_received",
         "last_ancestors",
-        "first_descendants",
+        "coordinates",
+        "_first_descendants",
         "_creator",
         "_hash",
         "_hex",
@@ -91,7 +92,12 @@ class Event:
         # the vector-clock-like structures making ancestry O(1)
         # (reference: src/hashgraph/event.go:115-116)
         self.last_ancestors: Optional[List[Tuple[int, str]]] = None
-        self.first_descendants: Optional[List[Tuple[int, str]]] = None
+        # the first descendants live in the graph's table
+        # (coordinates.CoordinateTable), which `coordinates` names once the
+        # graph has taken the event; `_first_descendants` is the list form
+        # an event was read back, shipped or released with
+        self.coordinates = None
+        self._first_descendants: Optional[List[Tuple[int, str]]] = None
         self._creator: str = ""
         self._hash: bytes = b""
         self._hex: str = ""
@@ -158,6 +164,22 @@ class Event:
         return crypto.verify(pub, digest, r, s)
 
     # -- consensus metadata ------------------------------------------------
+
+    @property
+    def first_descendants(self) -> Optional[List[Tuple[int, str]]]:
+        """[peer position] -> (index, hash) of that validator's first event
+        descending from this one, built from the graph's table while it
+        holds this event's row; the list this event carries otherwise."""
+        table = self.coordinates
+        if table is not None:
+            s = table.slot_of(self)
+            if s >= 0:
+                return table.cells(s)
+        return self._first_descendants
+
+    @first_descendants.setter
+    def first_descendants(self, cells) -> None:
+        self._first_descendants = cells
 
     def set_round(self, r: int) -> None:
         self.round = r

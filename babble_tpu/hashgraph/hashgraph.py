@@ -10,10 +10,13 @@ This scalar engine is the semantic oracle: the TPU engine
 order on every DAG, enforced by differential tests.
 
 Design deltas from the reference (deliberate, TPU-first):
-- dense coordinates: last_ancestors / first_descendants are lists indexed by
-  peer *position* in the sorted validator set (the reference uses ordered
+- dense coordinates: last ancestors / first descendants are indexed by peer
+  *position* in the sorted validator set (the reference uses ordered
   (participantId, coords) pairs, reference: src/hashgraph/event.go:62-99);
   position indexing is what the device grids use, so both engines share it.
+  The first descendants of every held event are the rows of one int32 table
+  (coordinates.CoordinateTable) and an insert writes its cells as ranges;
+  an event keeps its last ancestors as a list.
 - deterministic iteration everywhere (Python dicts are insertion-ordered;
   the reference relies on order-independence of random Go map iteration).
 - memoization in plain dicts cleared on Reset (the reference uses bounded
@@ -26,10 +29,13 @@ from __future__ import annotations
 import math
 from typing import Callable, Dict, List, Optional, Tuple
 
+import numpy as np
+
 from .. import crypto
 from ..common import StoreErr, StoreErrType, is_store_err
 from ..peers import Peers
 from .block import Block, BlockSignature, new_block_from_frame
+from .coordinates import MAX_INT32, CoordinateTable
 from .event import Event, WireEvent, root_self_parent
 from .frame import Frame
 from .root import Root, RootEvent
@@ -37,7 +43,6 @@ from .round_info import PendingRound, RoundInfo
 from .section import FrozenRef, Section
 from .store import Store
 
-MAX_INT32 = 2**31 - 1
 MIN_INT32 = -(2**31)
 
 
@@ -138,6 +143,8 @@ class Hashgraph:
         # (total `insert.key_hit`)
         self._validator_keys: Dict[bytes, object] = {}
         self._key_hits = 0
+        # the first descendants of every held event
+        self._coords = self._new_coordinates()
 
         # memo caches (unbounded dicts; cleared on Reset). An event's own
         # stamp fills them on a miss (see _memoized)
@@ -165,10 +172,30 @@ class Hashgraph:
         self._reset_anchor_index: int = -1
         # optional hook: called as (event, cells) after every insert, `cells`
         # the hashes of the ancestors whose first-descendant cell the insert
-        # wrote, in walk order (column and value are the event's own creator
-        # position and index) — the incremental device engine's delta feed
-        # (babble_tpu/tpu/live.py)
+        # wrote, chain by chain and top down (column and value are the
+        # event's own creator position and index) — the incremental device
+        # engine's delta feed (babble_tpu/tpu/live.py)
         self.insert_listener = None
+
+    def _new_coordinates(self) -> CoordinateTable:
+        table = CoordinateTable(
+            len(self.participants), self.store.cache_size(),
+            self._oldest_undetermined, self.store.keep_first_descendants,
+        )
+        self.store.coordinates = table
+        return table
+
+    def _oldest_undetermined(self) -> int:
+        """The topological index below which every event has its round
+        received (`undetermined_events` is in insertion order): the table
+        releases no row from there on."""
+        if not self.undetermined_events:
+            return self.topological_index
+        try:
+            oldest = self.store.get_event(self.undetermined_events[0])
+        except StoreErr:
+            return 0
+        return oldest.topological_index
 
     # ------------------------------------------------------------------
     # positions
@@ -208,12 +235,23 @@ class Hashgraph:
         descendant (reference: src/hashgraph/hashgraph.go:172-191)."""
         ex = self.store.get_event(x)
         ey = self.store.get_event(y)
-        c = sum(
-            1
-            for la, fd in zip(ex.last_ancestors, ey.first_descendants)
-            if la[0] >= fd[0]
-        )
+        table = self._coords
+        sy = table.slot_of(ey)
+        if sy >= 0:
+            fd = table.fd[:, sy].tolist()
+        else:
+            fd = [cell[0] for cell in ey.first_descendants]
+        c = sum(1 for la, f in zip(ex.last_ancestors, fd) if la[0] >= f)
         return c >= self.super_majority
+
+    def coordinate_rows(self, events: List[Event]):
+        """(last ancestors, first descendants) of `events` as two
+        (len, n) int32 arrays: what a device attach or rebase uploads."""
+        la = np.empty((len(events), len(self.participants)), np.int32)
+        # a row at a time: one nested list of them all is twice as slow
+        for k, ev in enumerate(events):
+            la[k] = [c[0] for c in ev.last_ancestors]
+        return la, self._coords.rows(events)
 
     # -- round ----------------------------------------------------------
 
@@ -438,12 +476,11 @@ class Hashgraph:
     def _init_event_coordinates(
         self, event: Event, sp: Optional[Event], op: Optional[Event],
         pos: int, coords: Tuple[int, str],
-    ) -> None:
+    ) -> int:
         """`sp`, `op`: the parents as the store holds them (None: not
         there); `pos`, `coords`: the creator's position and the event's
-        own (index, hash)."""
+        own (index, hash). Returns the event's slot in the table."""
         n = len(self.participants)
-        event.first_descendants = [(MAX_INT32, "")] * n
 
         if sp is None and op is None:
             event.last_ancestors = [(-1, "")] * n
@@ -457,38 +494,28 @@ class Hashgraph:
                 for a, b in zip(sp.last_ancestors, op.last_ancestors)
             ]
 
-        event.first_descendants[pos] = coords
         event.last_ancestors[pos] = coords
+        return self._coords.begin(event, pos)
 
     def _update_ancestor_first_descendant(
-        self, event: Event, pos: int, coords: Tuple[int, str],
-    ) -> List[str]:
-        """Walk each last-ancestor's self-parent chain marking this event as
-        first descendant (reference: src/hashgraph/hashgraph.go:510-544).
-        Returns the hashes of the ancestors whose cell was written, in walk
-        order — the delta stream an incremental device engine replays; the
-        cell's column and value are `pos` and the event's index for every
-        one of them, so they are not repeated per cell. A step reads its
-        ancestor once (`get_event`: one look-up, one recency refresh) and
-        tells the store of the cell it wrote (`update_event`: nothing for a
-        store that holds the object, the write-back for one that persists)."""
-        get_event = self.store.get_event
-        update_event = self.store.update_event
-        writes: List[str] = []
-        for _, ah in event.last_ancestors:
-            while ah != "":
-                try:
-                    a = get_event(ah)
-                except StoreErr:
-                    break
-                cells = a.first_descendants
-                if cells[pos][0] != MAX_INT32:
-                    break
-                cells[pos] = coords
-                update_event(a)
-                writes.append(ah)
-                ah = a.body.parents[0]
-        return writes
+        self, event: Event, pos: int, slot: int,
+    ) -> Optional[List[str]]:
+        """Mark this event as first descendant down each last ancestor's
+        self-parent chain (reference: src/hashgraph/hashgraph.go:510-544),
+        as ranges of the table: for every chain the indices past the
+        frontier up to the last ancestor (CoordinateTable.write). Returns the
+        hashes of the ancestors whose cell was written, chain by chain and
+        top down — the delta stream an incremental device engine replays;
+        the cell's column and value are `pos` and the event's index for
+        every one of them, so they are not repeated per cell. None where
+        nobody listens. The table never asks the store: no ancestor is
+        read, none has its recency refreshed, and a range stops at the
+        oldest row the table holds."""
+        table = self._coords
+        slots = table.write(event, pos, slot)
+        if self.insert_listener is None:
+            return None
+        return list(map(table.hashes.__getitem__, slots))
 
     def insert_event(self, event: Event, set_wire_info: bool) -> None:
         # per-event work is timed into the tracer's totals only (`insert`,
@@ -524,10 +551,10 @@ class Hashgraph:
 
         pos = self._pos_by_pubkey[creator]
         coords = (event.index(), event.hex())
-        self._init_event_coordinates(event, sp, op, pos, coords)
+        slot = self._init_event_coordinates(event, sp, op, pos, coords)
         self.store.set_event(event)
         t_fd = now()
-        cells = self._update_ancestor_first_descendant(event, pos, coords)
+        cells = self._update_ancestor_first_descendant(event, pos, slot)
         t_fd_done = now()
         if self.insert_listener is not None:
             self.insert_listener(event, cells)
@@ -1325,6 +1352,11 @@ class Hashgraph:
         participants = self.participants.to_peer_slice()
         root_map = {participants[pos].pub_key_hex: root for pos, root in enumerate(frame.roots)}
         self.store.reset(root_map)
+        # what the store still keeps of the events so far (a persisting
+        # store's rows) keeps their final cells; the inserts below fill a
+        # new table
+        self._coords.release(self._coords.top)
+        self._coords = self._new_coordinates()
         self.store.set_block(block)
         # keep the received frame servable: it IS the frame at the anchor's
         # round_received, already validated against the block's signed
@@ -1756,6 +1788,8 @@ class Hashgraph:
             self._check_other_parent(ev, self.store.get_root(ev.creator()))
             ev.topological_index = self.topological_index
             self.topological_index += 1
+            # the donor's coordinate rows, as shipped
+            self._coords.adopt(ev, self._pos_by_pubkey[ev.creator()])
             # a stamp left below the scrub ceiling is authoritative donor
             # metadata and the memo of round()/lamport_timestamp(): not
             # recomputed; scrubbed events (None) are re-decided instead

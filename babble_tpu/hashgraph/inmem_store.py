@@ -93,9 +93,14 @@ class InmemStore(Store):
             self._add_participant_event(event.creator(), key, event.index())
         self.event_cache.add(key, event)
 
-    def update_event(self, event: Event) -> None:
-        """Nothing: the cache holds the very object `get_event` handed out,
-        so the caller's mutation is already the stored state."""
+    def keep_first_descendants(self, keys, cells_of) -> None:
+        """The cached ones alone (an event pinned past the table's rows, or
+        refreshed since): an evicted event is gone with its cells."""
+        peek = self.event_cache.peek
+        for k, key in enumerate(keys):
+            ev, ok = peek(key)
+            if ok:
+                ev.first_descendants = cells_of(k)
 
     def _add_participant_event(self, participant: str, hash_: str, index: int) -> None:
         self.participant_events_cache.set(participant, hash_, index)

@@ -156,7 +156,11 @@ class SQLiteStore(Store):
             row = self.db.execute("SELECT data FROM events WHERE hex = ?", (key,)).fetchone()
             if row is None:
                 raise StoreErr("SQLite.Events", StoreErrType.KEY_NOT_FOUND, key)
-            return Event.from_store_json(json.loads(row[0]))
+            event = Event.from_store_json(json.loads(row[0]))
+            # its cells are the table's while the table holds its row, and
+            # the row's own after that
+            event.coordinates = self.coordinates
+            return event
 
     def set_event(self, event: Event) -> None:
         peer = self.inmem.participants().by_pub_key[event.creator()]
@@ -168,16 +172,30 @@ class SQLiteStore(Store):
                 self.inmem.set_event(event)
                 self._db_put_event(event)
         else:
-            self.update_event(event)
+            # an event already registered (possibly LRU-evicted meanwhile,
+            # so the object may be a copy read from disk): put it in the
+            # cache, which registers nothing again (that would hit a rolled
+            # participant window), and write it through
+            with self.db:
+                self.inmem.event_cache.add(event.hex(), event)
+                self._db_put_event(event)
 
-    def update_event(self, event: Event) -> None:
-        """Write-back of an already-registered event (possibly LRU-evicted
-        meanwhile, so the object may be a copy read from disk): put it in
-        the cache, which re-registers nothing (that would hit a rolled
-        participant window), and write it through."""
+    def keep_first_descendants(self, keys, cells_of) -> None:
+        """Every one of them: the cached objects, and each event's row, so
+        that an event read back after the graph released its cells still
+        has them all. The one rewrite of a row for its cells (the graph's
+        table is the truth while it holds them, and a restart rebuilds
+        it: `Hashgraph.bootstrap`): the one field patched in place, the
+        whole block in one statement."""
+        self.inmem.keep_first_descendants(keys, cells_of)
         with self.db:
-            self.inmem.event_cache.add(event.hex(), event)
-            self._db_put_event(event)
+            self.db.executemany(
+                "UPDATE events SET data = "
+                "json_set(data, '$.Meta.FirstDescendants', json(?)) "
+                "WHERE hex = ?",
+                ((json.dumps(cells_of(k)), key)
+                 for k, key in enumerate(keys) if key),
+            )
 
     def _db_put_event(self, event: Event) -> None:
         """The event's row, under the topological index it was first

@@ -4,7 +4,7 @@
 from __future__ import annotations
 
 from abc import ABC, abstractmethod
-from typing import Dict, List, Tuple
+from typing import Callable, Dict, List, Tuple
 
 from .block import Block
 from .event import Event
@@ -14,6 +14,12 @@ from .round_info import RoundInfo
 
 
 class Store(ABC):
+    # the graph's table of first descendants (coordinates.CoordinateTable),
+    # set by the Hashgraph that owns this store: an event a store builds
+    # anew (read back from disk) is handed it, so that it answers as the
+    # live object does
+    coordinates = None
+
     @abstractmethod
     def cache_size(self) -> int: ...
 
@@ -30,11 +36,13 @@ class Store(ABC):
     def set_event(self, event: Event) -> None: ...
 
     @abstractmethod
-    def update_event(self, event: Event) -> None:
-        """Keep a change made to an event this store handed out (`get_event`)
-        or already holds: whatever the store needs beyond the caller having
-        mutated the object. It registers nothing and, for a cached event,
-        moves nothing in the cache."""
+    def keep_first_descendants(
+        self, keys: List[str], cells_of: Callable[[int], list],
+    ) -> None:
+        """The graph is about to release the first-descendant rows of the
+        events `keys` (its oldest, in insertion order; "" for none):
+        whatever this store can still hand out of them keeps its final
+        cells as the list `cells_of(k)` builds for `keys[k]`."""
 
     @abstractmethod
     def participant_events(self, participant: str, skip: int) -> List[str]: ...

@@ -132,8 +132,6 @@ def grid_from_hashgraph(hg) -> DagGrid:
     index = np.zeros(e_count, dtype=np.int32)
     self_parent = np.full(e_count, -1, dtype=np.int32)
     other_parent = np.full(e_count, -1, dtype=np.int32)
-    la = np.full((e_count, n), -1, dtype=np.int32)
-    fd = np.full((e_count, n), MAX_INT32, dtype=np.int32)
     coin = np.zeros(e_count, dtype=bool)
     fixed_round = np.full(e_count, -1, dtype=np.int32)
     ext_sp_round = np.full(e_count, -1, dtype=np.int32)
@@ -197,9 +195,9 @@ def grid_from_hashgraph(hg) -> DagGrid:
         if ev.lamport_timestamp is not None:
             fixed_lamport[i] = ev.lamport_timestamp
 
-        la[i] = [c[0] for c in ev.last_ancestors]
-        fd[i] = [c[0] for c in ev.first_descendants]
         coin[i] = middle_bit(ev.hex())
+
+    la, fd = hg.coordinate_rows(events)
 
     levels, num_levels = build_levels(n, self_parent, other_parent)
 

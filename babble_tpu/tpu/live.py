@@ -587,13 +587,15 @@ class LiveDeviceEngine:
         new_row_of: Dict[str, int] = {}
         new_hashes: List[str] = []
         last_abs = base
+        # the kept events' coordinates: row slices of the graph's table
+        la[: len(kept)], fd[: len(kept)] = hg.coordinate_rows(
+            [ev for _, ev in kept]
+        )
         for k, (h, ev) in enumerate(kept):
             new_row_of[h] = k
             new_hashes.append(h)
             creator[k] = hg.peer_position(ev.creator())
             index[k] = ev.index()
-            la[k] = [c[0] for c in ev.last_ancestors]
-            fd[k] = [c[0] for c in ev.first_descendants]
             if ev.round is not None:
                 if ev.round >= base:
                     rounds[k] = ev.round - base

@@ -27,6 +27,7 @@ from babble_tpu.tpu.incremental import (
 from babble_tpu.tpu.live import LiveDeviceEngine, derive_fd_updates
 from benchmark.entries import replay
 
+from test_insert_once import PlainInmemStore, PlainInsert
 from test_withheld_stream import drive, withheld_stream
 
 
@@ -372,6 +373,45 @@ def test_v128_batches_of_32_are_byte_equal(stream128, cap):
         assert eng.update_cuts == 0
     else:
         assert 10 < eng.update_cuts <= sum(size < 32 for size in seen.events)
+
+
+@pytest.mark.parametrize("n", [64, 128])
+def test_update_arrays_are_the_plain_walks(n, stream64, stream128):
+    """The insert writes its cells as ranges of one table; the plain walk
+    (`tests/test_insert_once.py PlainInsert`: a look-up and a list cell an
+    ancestor) on the same stream hands its listener the same ancestors in
+    the same order, event for event, and the batches staged from either are
+    byte-equal in every field, the three update arrays among them."""
+    stream = stream64 if n == 64 else stream128
+    peers, events = stream.peers, 3000
+    hg = Hashgraph(peers, InmemStore(peers, 50000))
+    eng = LiveDeviceEngine(hg, e_cap=4096, batch_cap=32)
+    plain = PlainInsert(peers, PlainInmemStore(peers, 50000))
+    walked = []
+    plain.insert_listener = lambda ev, writes: walked.append(list(writes))
+    done = batches = 0
+    for lo in range(0, events, 500):
+        for signed in stream.signed[lo : lo + 500]:
+            hg.insert_event(stream.copy(signed), True)
+            plain.insert_event(stream.copy(signed), True)
+        drained, eng.pending = eng.pending, []
+        pos = 0
+        while pos < len(drained):
+            chunk = eng._cut(drained[pos : pos + eng.batch_cap])
+            pos += len(chunk)
+            triples = list(zip((ev for ev, _ in chunk),
+                               walked[done : done + len(chunk)]))
+            for (ev, cells), (_, writes) in zip(chunk, triples):
+                assert cells == [ah for ah, _, _ in writes]
+                assert {(hg.peer_position(ev.creator()), ev.index())} >= {
+                    (pos_, val) for _, pos_, val in writes}
+            done += len(chunk)
+            want, _ = PlainStage(eng).build_batch(triples)
+            got, _ = ENGINE_BUILD(eng, chunk)
+            assert_same_batch(got, want, f"batch {batches}")
+            batches += 1
+    assert done == events and batches >= events // 32
+    assert eng.cells_staged == sum(map(len, walked)) > events * 40
 
 
 @pytest.mark.parametrize("n", [8, 4])
