@@ -143,6 +143,17 @@ class Hashgraph:
         # (total `insert.key_hit`)
         self._validator_keys: Dict[bytes, object] = {}
         self._key_hits = 0
+        # the inserts since hand_over_inserts last gave them to the tracer
+        # (totals `insert` and its parts): their count, how many of them a
+        # listener was handed, and the seconds of each part
+        self._inserts = 0
+        self._listened = 0
+        self._insert_s = 0.0
+        self._verify_s = 0.0
+        self._lookup_s = 0.0
+        self._coords_s = 0.0
+        self._fd_s = 0.0
+        self._listener_s = 0.0
         # the first descendants of every held event
         self._coords = self._new_coordinates()
 
@@ -518,9 +529,11 @@ class Hashgraph:
         return list(map(table.hashes.__getitem__, slots))
 
     def insert_event(self, event: Event, set_wire_info: bool) -> None:
-        # per-event work is timed into the tracer's totals only (`insert`,
-        # `insert.verify`, `insert.fd`): a ring span each would wrap the
-        # ring within one sync
+        # per-event work is timed into totals only (`insert` and its parts
+        # `.verify`, `.lookup`, `.coords`, `.fd`, `.listener`): a ring span
+        # each would wrap the ring within one sync. The seconds are summed
+        # here and reach the tracer once a consensus call
+        # (hand_over_inserts), not through its lock three times an event
         now = self.obs.clock.monotonic
         t_insert = now()
         # every event's signature is checked, over a digest of the body as
@@ -551,6 +564,7 @@ class Hashgraph:
 
         pos = self._pos_by_pubkey[creator]
         coords = (event.index(), event.hex())
+        t_looked_up = now()
         slot = self._init_event_coordinates(event, sp, op, pos, coords)
         self.store.set_event(event)
         t_fd = now()
@@ -558,6 +572,8 @@ class Hashgraph:
         t_fd_done = now()
         if self.insert_listener is not None:
             self.insert_listener(event, cells)
+            self._listened += 1
+            self._listener_s += now() - t_fd_done
 
         self.undetermined_events.append(coords[1])
         if event.is_loaded():
@@ -567,10 +583,35 @@ class Hashgraph:
         # now in the graph — the trace store looks them up by tx hash, so
         # no trace data touches the signed event bytes
         self.obs.traces.mark_event(event.transactions())
+        self._inserts += 1
+        self._verify_s += t_verified - t_insert
+        self._lookup_s += t_looked_up - t_verified
+        self._coords_s += t_fd - t_looked_up
+        self._fd_s += t_fd_done - t_fd
+        self._insert_s += now() - t_insert
+
+    def hand_over_inserts(self) -> None:
+        """Give the tracer the inserts summed since the last hand-over:
+        totals `insert`, `insert.verify`, `insert.lookup`, `insert.coords`,
+        `insert.fd`, each with the count of inserts, and `insert.listener`
+        with the count of those a listener was handed. `Core.run_consensus`
+        calls this before its entry checkpoint, so that a window of
+        checkpoints holds whole calls and the inserts between them;
+        process_decided_rounds does too, for a Hashgraph without a Core."""
+        n = self._inserts
+        if not n:
+            return
         tracer = self.obs.tracer
-        tracer.add("insert.verify", t_verified - t_insert)
-        tracer.add("insert.fd", t_fd_done - t_fd)
-        tracer.add("insert", now() - t_insert)
+        tracer.add("insert.verify", self._verify_s, n)
+        tracer.add("insert.lookup", self._lookup_s, n)
+        tracer.add("insert.coords", self._coords_s, n)
+        tracer.add("insert.fd", self._fd_s, n)
+        if self._listened:
+            tracer.add("insert.listener", self._listener_s, self._listened)
+        tracer.add("insert", self._insert_s, n)
+        self._inserts = self._listened = 0
+        self._insert_s = self._verify_s = self._lookup_s = 0.0
+        self._coords_s = self._fd_s = self._listener_s = 0.0
 
     def _set_wire_info(
         self, event: Event, sp: Optional[Event], op: Optional[Event],
@@ -916,6 +957,7 @@ class Hashgraph:
                 if self._key_hits:
                     tracer.add("insert.key_hit", 0.0, count=self._key_hits)
                     self._key_hits = 0
+                self.hand_over_inserts()
 
     def _process_decided_rounds(self) -> None:
         """The commit loop of process_decided_rounds.
@@ -1016,11 +1058,14 @@ class Hashgraph:
         """Build and store the frame of a round the store does not hold;
         `note` takes what the `commit.frame` span says of it."""
         derived_before = self._derivations
+        now = self.obs.clock.monotonic
+        t_frame = now()
         round_info = self.store.get_round(round_received)
         events = [self.store.get_event(eh) for eh in round_info.consensus_events()]
         from .event import by_lamport_key
 
         events.sort(key=by_lamport_key)
+        t_events = now()
 
         roots: Dict[str, Root] = {}
         created = 0
@@ -1054,6 +1099,13 @@ class Hashgraph:
 
         ordered_roots = [roots[p.pub_key_hex] for p in self.participants.to_peer_slice()]
 
+        # the two parts of `commit.frame`, once a frame (the rest of the
+        # span is set_frame): reading and sorting the round's events, and
+        # the roots with the other-parent walk
+        t_roots = now()
+        tracer = self.obs.tracer
+        tracer.add("commit.frame.events", t_events - t_frame, len(events))
+        tracer.add("commit.frame.roots", t_roots - t_events, created)
         res = Frame(round=round_received, roots=ordered_roots, events=events)
         self.store.set_frame(res)
         note["events"] = len(events)

@@ -426,6 +426,9 @@ class Core:
         any window of whole calls afterwards (`totals_between`)."""
         obs = self.hg.obs
         self._consensus_calls += 1
+        # the inserts since the last call, before the checkpoint that ends
+        # their window
+        self.hg.hand_over_inserts()
         obs.tracer.checkpoint()
         try:
             with obs.span("core.run_consensus",

@@ -37,6 +37,7 @@ from .devledger import (
     retrace_baseline,
     retrace_delta,
 )
+from . import gcpause
 from .flightrec import (
     DEFAULT_FLIGHT_CAPACITY,
     FlightRecord,
@@ -163,6 +164,9 @@ class Observability:
         # table, and staleness-asymmetry partition inference; dormant
         # until the node calls bind_local with its digest providers
         self.clusterview = ClusterObservatory(self)
+        # the cycle collector's pauses (totals `gc.young` / `gc.full`, the
+        # babble_gc_* counters); real SystemClock only
+        gcpause.watch(self)
 
     # Delegates so call sites read `obs.counter("...")`. The name flows
     # through a parameter here, which the obs-dynamic-name rule cannot

@@ -16,6 +16,12 @@ did not stand at a window's start can still take a windowed reading
 Timestamps come exclusively from the injected Clock seam — the tracer
 itself never touches wall time, and ids are sequence numbers, so it is
 byte-deterministic under the simulator's SimClock.
+
+One caller cannot take the tracer's lock: a `gc.callbacks` entry
+(obs/gcpause.py) runs on whatever thread's allocation set the collector
+off, and that thread may be inside this tracer. It hands its reading to
+`defer`, which only appends to a queue; the next locked operation books
+what is queued before its own work.
 """
 
 from __future__ import annotations
@@ -31,6 +37,8 @@ from ..common.clock import Clock, SYSTEM_CLOCK
 
 DEFAULT_SPAN_CAPACITY = 4096
 CHECKPOINT_CAPACITY = 4096
+# deferred readings a tracer nobody uses may hold before it drops new ones
+DEFERRED_CAPACITY = 4096
 ANNOTATION_PREFIX = "babble."
 
 KEPT_TRACERS = 4
@@ -97,6 +105,10 @@ class SpanTracer:
         self._open = threading.local()
         self._totals: Dict[str, List[float]] = {}  # guarded-by: _lock — name -> [count, seconds]
         self._checkpoints: deque = deque(maxlen=CHECKPOINT_CAPACITY)  # guarded-by: _lock
+        # unguarded-ok: deque append/popleft are atomic — readings handed
+        # over by `defer`, not yet in the ring or the totals
+        self._deferred: deque = deque()
+        self.deferred_dropped = 0  # unguarded-ok: a diagnostic; a lost increment is harmless
         _LIVE.add(self)
 
     def _stack(self) -> List[int]:
@@ -106,13 +118,18 @@ class SpanTracer:
         return stack
 
     def _store(self, sp: Span) -> None:
+        if self._deferred:
+            self._book_deferred()
         with self._lock:
-            if self._next >= self.capacity and \
-                    self._ring[self._next % self.capacity] is not None:
-                self.dropped += 1
-            self._ring[self._next % self.capacity] = sp
-            self._next += 1
-            self._add_locked(sp.name, sp.duration)
+            self._store_locked(sp)
+
+    def _store_locked(self, sp: Span) -> None:  # requires-lock: _lock
+        if self._next >= self.capacity and \
+                self._ring[self._next % self.capacity] is not None:
+            self.dropped += 1
+        self._ring[self._next % self.capacity] = sp
+        self._next += 1
+        self._add_locked(sp.name, sp.duration)
 
     def _add_locked(self, name: str, seconds: float, count: int = 1) -> None:  # requires-lock: _lock
         total = self._totals.get(name)
@@ -133,6 +150,42 @@ class SpanTracer:
         self._store(Span(name, start, duration, attrs,
                          threading.current_thread().name, next(self._ids),
                          stack[-1] if stack else None))
+
+    def defer(self, name: str, start: float, duration: float,
+              attrs: Optional[dict] = None, feeds=()) -> None:
+        """`record` for a caller that may be running inside this tracer on
+        its own thread (a `gc.callbacks` entry): takes no lock and calls
+        nothing that does. The reading's parent is the span open on this
+        thread now; it reaches the totals, and with `attrs` the ring, when
+        the tracer is next used, before that use's own work (so before a
+        checkpoint's copy). `feeds` are `(counter child, amount)` pairs
+        incremented then, as `span(histogram=...)` feeds a histogram. A
+        tracer that is never used again keeps DEFERRED_CAPACITY readings
+        and drops the rest."""
+        if len(self._deferred) >= DEFERRED_CAPACITY:
+            self.deferred_dropped += 1
+            return
+        stack = self._stack()
+        self._deferred.append(
+            (name, start, duration, attrs, threading.current_thread().name,
+             stack[-1] if stack else None, feeds))
+
+    def _book_deferred(self) -> None:
+        queue = self._deferred
+        while queue:
+            try:
+                name, start, duration, attrs, thread, parent, feeds = \
+                    queue.popleft()
+            except IndexError:  # another thread booked it
+                break
+            with self._lock:
+                if attrs is None:
+                    self._add_locked(name, duration)
+                else:
+                    self._store_locked(Span(name, start, duration, attrs,
+                                            thread, next(self._ids), parent))
+            for child, amount in feeds:
+                child.inc(amount)
 
     @contextmanager
     def span(self, name: str, histogram=None, ledger=None, **attrs):
@@ -176,12 +229,16 @@ class SpanTracer:
         totals and write no ring entry: for work done once per event,
         where a span each would wrap the ring within one sync, and for a
         count the program kept in a plain integer through a call."""
+        if self._deferred:
+            self._book_deferred()
         with self._lock:
             self._add_locked(name, seconds, count)
 
     def totals(self) -> Dict[str, Tuple[int, float]]:
         """Cumulative (count, seconds) per span name since the tracer was
         made: every span, every `record`, every `add`."""
+        if self._deferred:
+            self._book_deferred()
         with self._lock:
             return self._copy_locked()
 
@@ -189,6 +246,8 @@ class SpanTracer:
         """Keep a timestamped copy of the totals (the newest
         CHECKPOINT_CAPACITY are held). `Core.run_consensus` calls this on
         entry and on return."""
+        if self._deferred:
+            self._book_deferred()
         now = self.clock.monotonic()
         with self._lock:
             if not self._checkpoints:
@@ -214,6 +273,8 @@ class SpanTracer:
 
     def spans(self) -> List[Span]:
         """Retained spans, oldest first."""
+        if self._deferred:
+            self._book_deferred()
         with self._lock:
             if self._next <= self.capacity:
                 return [s for s in self._ring[: self._next] if s is not None]

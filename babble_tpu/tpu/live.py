@@ -1125,6 +1125,12 @@ def _fetch(hg, eng: LiveDeviceEngine, snap: dict, wait, discipline: str):
         lag_calls=eng.calls - snap["call"],
     ) as sp:
         packed = wait()
+    # the span's two attributes as counts a window can be read over: the
+    # fetches that were pipelined, and the calls they lagged their dispatch
+    tracer = hg.obs.tracer
+    if discipline == "pipelined":
+        tracer.add("fetch.pipelined", 0.0)
+    tracer.add("fetch.lag", 0.0, sp.attrs["lag_calls"])
     eng.consensus_calls += 1
     return packed, sp
 
@@ -1267,6 +1273,8 @@ def _write_back(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
     from ..common import StoreErr, StoreErrType, is_store_err
     from ..hashgraph import RoundInfo
 
+    obs, now = hg.obs, hg.obs.clock.monotonic
+    dispatch = snap["dispatch"]
     count, lo, base = snap["count"], snap["lo"], snap["base"]
     if base != eng.round_base:
         # rebases are ordered strictly between integrations; a mismatch
@@ -1274,124 +1282,134 @@ def _write_back(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
         raise GridUnsupported(
             f"integration base {base} != engine base {eng.round_base}"
         )
-    (rounds_w, lamport_w, witness_w, received_w, wtable, fame_decided,
-     famous, stale, fame_lag, last_round_rel, reopened) = _unpack_results(
-        packed, eng.e_win, eng.r_cap, eng.n)
     hashes = snap["hashes"]
     new_rows = snap["new_rows"]
-    rounds_w = rounds_w[: count - lo]
-    lamport_w = lamport_w[: count - lo]
-    witness_w = witness_w[: count - lo]
-    received_w = received_w[: count - lo]
-    if bool(stale) or bool(fame_lag):
-        eng.detach()
-        hg._live_device_engine = None
-        raise GridUnsupported(
-            "device window/unroll exhausted; rebuilding via one-shot path"
-        )
 
     def at(row, arr):
         if row < lo:
             raise GridUnsupported("decision row below fetch window")
         return arr[row - lo]
 
-    # --- DivideRounds write-back for the new events -----------------------
-    # boundary gate: validate the whole batch before stamping (a wrong
-    # round poisons the write-once host round function; see
-    # engine.validate_round_writeback) — violations demote this engine
-    from .engine import validate_round_writeback
-
-    # host-known rounds are AUTHORITATIVE: never re-stamp them (a fresh
-    # attach write-back covers every staged row, including rows below the
-    # engine base whose device-side round is a sentinel)
-    def _fresh_rows():
-        for row in new_rows:
-            if hg.store.get_event(hashes[row]).round is None:
-                yield row
-
-    validate_round_writeback(
-        hg,
-        (
-            (
-                hashes[row],
-                (int(at(row, rounds_w)) + base, int(at(row, lamport_w))),
+    with obs.span("live.integrate.gate", dispatch=dispatch):
+        (rounds_w, lamport_w, witness_w, received_w, wtable, fame_decided,
+         famous, stale, fame_lag, last_round_rel, reopened) = _unpack_results(
+            packed, eng.e_win, eng.r_cap, eng.n)
+        rounds_w = rounds_w[: count - lo]
+        lamport_w = lamport_w[: count - lo]
+        witness_w = witness_w[: count - lo]
+        received_w = received_w[: count - lo]
+        if bool(stale) or bool(fame_lag):
+            eng.detach()
+            hg._live_device_engine = None
+            raise GridUnsupported(
+                "device window/unroll exhausted; rebuilding via one-shot path"
             )
-            for row in _fresh_rows()
-        ),
-    )
-    undetermined = set(hg.undetermined_events)
+
+        # --- DivideRounds write-back for the new events -------------------
+        # boundary gate: validate the whole batch before stamping (a wrong
+        # round poisons the write-once host round function; see
+        # engine.validate_round_writeback) — violations demote this engine
+        from .engine import validate_round_writeback
+
+        # host-known rounds are AUTHORITATIVE: never re-stamp them (a fresh
+        # attach write-back covers every staged row, including rows below
+        # the engine base whose device-side round is a sentinel)
+        def _fresh_rows():
+            for row in new_rows:
+                if hg.store.get_event(hashes[row]).round is None:
+                    yield row
+
+        validate_round_writeback(
+            hg,
+            (
+                (
+                    hashes[row],
+                    (int(at(row, rounds_w)) + base, int(at(row, lamport_w))),
+                )
+                for row in _fresh_rows()
+            ),
+        )
     round_infos: Dict[int, RoundInfo] = {}
     # decision provenance (obs/provenance.py): cells captured from the
-    # fetched host buffers / host store only — no extra device syncs
-    prov = hg.obs.provenance
+    # fetched host buffers / host store only — no extra device syncs.
+    # `prov_s`: the seconds of its dearest call, note_event (an n-long
+    # list rebuilt a row), summed here and handed over once (total
+    # `obs.provenance`, below)
+    prov = obs.provenance
     prov_cells = 0
-    for row in new_rows:
-        h = hashes[row]
-        ev = hg.store.get_event(h)
-        if ev.round is None:
-            rnum = int(at(row, rounds_w)) + base
-            ev.set_round(rnum)
-            ev.set_lamport_timestamp(int(at(row, lamport_w)))
-            hg.store.set_event(ev)
-        else:
-            rnum = ev.round
-        if h in undetermined:
-            if ev.lamport_timestamp is not None and ev.last_ancestors is not None:
-                prov_cells += prov.note_event(
-                    h, rnum, ev.lamport_timestamp, ev.last_ancestors,
-                )
-            if bool(at(row, witness_w)):
-                prov_cells += prov.note_witness(
-                    h, rnum, hg.peer_position(ev.creator()),
-                )
-            ri = round_infos.get(rnum)
-            if ri is None:
-                try:
-                    ri = hg.store.get_round(rnum)
-                except StoreErr as err:
-                    if not is_store_err(err, StoreErrType.KEY_NOT_FOUND):
-                        raise
-                    ri = RoundInfo()
-                round_infos[rnum] = ri
-            is_witness = bool(at(row, witness_w))
-            hg.queue_round(
-                rnum, ri, late_witness=is_witness and not ri.is_decided(h))
-            ri.add_event(h, is_witness)
+    prov_s = 0.0
+    with obs.span("live.integrate.rounds", dispatch=dispatch):
+        undetermined = set(hg.undetermined_events)
+        for row in new_rows:
+            h = hashes[row]
+            ev = hg.store.get_event(h)
+            if ev.round is None:
+                rnum = int(at(row, rounds_w)) + base
+                ev.set_round(rnum)
+                ev.set_lamport_timestamp(int(at(row, lamport_w)))
+                hg.store.set_event(ev)
+            else:
+                rnum = ev.round
+            if h in undetermined:
+                if ev.lamport_timestamp is not None and ev.last_ancestors is not None:
+                    t_note = now()
+                    prov_cells += prov.note_event(
+                        h, rnum, ev.lamport_timestamp, ev.last_ancestors,
+                    )
+                    prov_s += now() - t_note
+                if bool(at(row, witness_w)):
+                    prov_cells += prov.note_witness(
+                        h, rnum, hg.peer_position(ev.creator()),
+                    )
+                ri = round_infos.get(rnum)
+                if ri is None:
+                    try:
+                        ri = hg.store.get_round(rnum)
+                    except StoreErr as err:
+                        if not is_store_err(err, StoreErrType.KEY_NOT_FOUND):
+                            raise
+                        ri = RoundInfo()
+                    round_infos[rnum] = ri
+                is_witness = bool(at(row, witness_w))
+                hg.queue_round(
+                    rnum, ri, late_witness=is_witness and not ri.is_decided(h))
+                ri.add_event(h, is_witness)
 
     # --- DecideFame write-back (pending rounds only) ----------------------
     delegated = hg.reset_floor is not None
-    if delegated:
-        # post-reset delegation, same reasoning as engine.py: fame and
-        # reception decision TIMING must match the host call-for-call or
-        # block composition skews between backends. Falls through to the
-        # capacity management — the engine still windows (rebases) like
-        # any other.
-        for rnum, ri in round_infos.items():
-            hg.store.set_round(rnum, ri)
-        hg.decide_fame()
-        hg.decide_round_received()
-    for pr in ([] if delegated else hg.pending_rounds):
-        ri = round_infos.get(pr.index)
-        if ri is None:
-            ri = hg.store.get_round(pr.index)
-            round_infos[pr.index] = ri
-        sh = pr.index - base
-        if 0 <= sh < eng.r_cap:
-            for c in range(eng.n):
-                wrow = int(wtable[sh, c])
-                if wrow < 0:
-                    continue
-                if fame_decided[sh, c]:
-                    ri.set_fame(hashes[wrow], bool(famous[sh, c]))
-                    prov_cells += prov.note_fame(
-                        hashes[wrow], pr.index, bool(famous[sh, c]),
-                        engine="live",
-                    )
-        # recompute, not just promote (as decide_fame does): a late
-        # witness in a round that is decided and still queued must unset
-        # the flag, or process_decided_rounds could settle the round
-        # around an undefined fame
-        pr.decided = ri.witnesses_decided()
+    with obs.span("live.integrate.fame", dispatch=dispatch):
+        if delegated:
+            # post-reset delegation, same reasoning as engine.py: fame and
+            # reception decision TIMING must match the host call-for-call or
+            # block composition skews between backends. Falls through to the
+            # capacity management — the engine still windows (rebases) like
+            # any other.
+            for rnum, ri in round_infos.items():
+                hg.store.set_round(rnum, ri)
+            hg.decide_fame()
+            hg.decide_round_received()
+        for pr in ([] if delegated else hg.pending_rounds):
+            ri = round_infos.get(pr.index)
+            if ri is None:
+                ri = hg.store.get_round(pr.index)
+                round_infos[pr.index] = ri
+            sh = pr.index - base
+            if 0 <= sh < eng.r_cap:
+                for c in range(eng.n):
+                    wrow = int(wtable[sh, c])
+                    if wrow < 0:
+                        continue
+                    if fame_decided[sh, c]:
+                        ri.set_fame(hashes[wrow], bool(famous[sh, c]))
+                        prov_cells += prov.note_fame(
+                            hashes[wrow], pr.index, bool(famous[sh, c]),
+                            engine="live",
+                        )
+            # recompute, not just promote (as decide_fame does): a late
+            # witness in a round that is decided and still queued must unset
+            # the flag, or process_decided_rounds could settle the round
+            # around an undefined fame
+            pr.decided = ri.witnesses_decided()
 
     # --- DecideRoundReceived write-back (undetermined only) ---------------
     from .engine import admissible_receptions, stamp_receptions
@@ -1436,11 +1454,12 @@ def _write_back(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
             sp.attrs["proposed"] = len(proposed)
             admissible = admissible_receptions(hg, round_infos, proposed)
         if admissible:
-            prov_cells += stamp_receptions(hg, round_infos, proposed)
-            hg.undetermined_events = left
+            with obs.span("live.integrate.receptions", dispatch=dispatch):
+                prov_cells += stamp_receptions(hg, round_infos, proposed)
+                hg.undetermined_events = left
 
-            for rnum, ri in round_infos.items():
-                hg.store.set_round(rnum, ri)
+                for rnum, ri in round_infos.items():
+                    hg.store.set_round(rnum, ri)
         else:
             # the device "unblocked" a reception the host rule refuses
             # (frozen/missing rounds): persist the fame state and run the
@@ -1465,6 +1484,7 @@ def _write_back(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
         )
     if prov_cells:
         prov.mark("prov.capture", engine="live", cells=prov_cells)
+        obs.tracer.add("obs.provenance", prov_s, prov_cells)
     return last_round_rel
 
 

@@ -9,6 +9,13 @@ count what the program did (events inserted, blocks committed), one
 interval is booked once, a reception the host rule refuses is counted,
 frame building reads the rounds the engine stamped and derives none, and
 the admissibility gate looks each round up once an integration.
+
+The second level (PR 35): the four `live.integrate.*` spans are children
+of their dispatch's `live.integrate` and sum to no more than it with the
+gate's walk, the insert totals have `insert`'s count and sum to no more
+than it, inside a window of checkpoints too, the two `commit.frame.*`
+totals count a frame's events and roots, `obs.provenance` counts the cells
+of the `prov.capture` marks, and the fetch discipline is a pair of counts.
 """
 
 import pytest
@@ -258,6 +265,129 @@ def test_checkpoints_bracket_every_call(run):
     later = tracer.totals_between(roots[1].start - 1e-4, last.start + last.duration + 1.0)
     assert later["core.run_consensus"][0] == len(roots) - 1
     assert later["insert"][0] == EVENTS - 2 * SYNC
+
+
+INTEGRATE_CHILDREN = ("live.integrate.gate", "live.integrate.rounds",
+                      "live.integrate.fame", "live.integrate.receptions")
+INSERT_PARTS = ("insert.verify", "insert.lookup", "insert.coords",
+                "insert.fd", "insert.listener")
+
+
+@pytest.mark.parametrize("name", INTEGRATE_CHILDREN)
+def test_integrate_child_hangs_under_its_dispatch(run, name):
+    _, core, _, _, spans = run
+    by_id = {s.id: s for s in spans}
+    mine = named(spans, name)
+    # every write-back of these runs is admitted: one of each a dispatch
+    assert len(mine) == len(named(spans, "live.integrate")) > 0
+    assert core.hg.obs.tracer.totals()[name][0] == len(mine)
+    for sp in mine:
+        parent = by_id[sp.parent]
+        assert parent.name == "live.integrate"
+        assert sp.attrs["dispatch"] == parent.attrs["dispatch"]
+
+
+def test_integrate_children_sum_to_no_more_than_it(run):
+    _, _, _, _, spans = run
+    inside = {}
+    for sp in spans:
+        if sp.name in INTEGRATE_CHILDREN + ("live.admissible",
+                                           "live.host_repair"):
+            inside[sp.parent] = inside.get(sp.parent, 0.0) + sp.duration
+    whole = named(spans, "live.integrate")
+    assert set(inside) == {sp.id for sp in whole}
+    for sp in whole:
+        assert 0.0 < inside[sp.id] <= sp.duration + 1e-9
+
+
+@pytest.mark.parametrize("name", INSERT_PARTS)
+def test_insert_part_has_inserts_count(run, name):
+    _, core, _, _, spans = run
+    tracer = core.hg.obs.tracer
+    totals = tracer.totals()
+    # the listener is the live engine's, set when the first call attaches
+    # it: the first sync's events had nobody listening
+    want = EVENTS - SYNC if name == "insert.listener" else EVENTS
+    assert totals[name][0] == want and totals[name][1] > 0.0
+    # from the second call's entry: one call and its sync's inserts fewer,
+    # as `insert` itself (test_checkpoints_bracket_every_call)
+    roots = named(spans, "core.run_consensus")
+    last = roots[-1]
+    later = tracer.totals_between(roots[1].start - 1e-4,
+                                  last.start + last.duration + 1.0)
+    assert later[name][0] == later["insert"][0] == EVENTS - 2 * SYNC
+
+
+def test_insert_parts_sum_to_no_more_than_insert(run, cpu_run):
+    _, core, _, _, _ = run
+    for totals in (core.hg.obs.tracer.totals(),
+                   cpu_run[0].hg.obs.tracer.totals()):
+        parts = sum(totals.get(name, (0, 0.0))[1] for name in INSERT_PARTS)
+        assert 0.0 < parts <= totals["insert"][1]
+    # nobody listens to the host engine's inserts
+    assert "insert.listener" not in cpu_run[0].hg.obs.tracer.totals()
+    assert cpu_run[0].hg.obs.tracer.totals()["insert.lookup"][0] == EVENTS
+
+
+def test_inserts_reach_the_tracer_once_a_call():
+    """The sums are kept in the Hashgraph and handed over by the next
+    consensus call, before its entry checkpoint."""
+    peers, key, signed = signed_stream()
+    core = Core(0, key, peers, InmemStore(peers, 2000), commit_ch=Blocks(),
+                consensus_backend="cpu")
+    tracer = core.hg.obs.tracer
+    for ev in signed[:SYNC]:
+        core.insert_event(handed(ev), True)
+    assert "insert" not in tracer.totals() and core.hg._inserts == SYNC
+    core.run_consensus()
+    assert tracer.totals()["insert"][0] == SYNC and core.hg._inserts == 0
+    t0 = core.hg.obs.clock.monotonic()
+    for ev in signed[SYNC:3 * SYNC]:
+        core.insert_event(handed(ev), True)
+    core.run_consensus()
+    core.run_consensus()
+    window = tracer.totals_between(t0, core.hg.obs.clock.monotonic())
+    # the window opens at the second call's entry checkpoint, which the
+    # second sync's inserts precede
+    assert window["core.run_consensus"][0] == 2
+    assert window.get("insert", (0, 0.0))[0] == 0
+    assert tracer.totals()["insert"][0] == 3 * SYNC
+
+
+@pytest.mark.parametrize("name,attr", [("commit.frame.events", "events"),
+                                       ("commit.frame.roots", "roots_created")])
+def test_frame_parts_count_the_frames(run, cpu_run, name, attr):
+    for core in (run[1], cpu_run[0]):
+        tracer = core.hg.obs.tracer
+        totals = tracer.totals()
+        frames = named(tracer.spans(), "commit.frame")
+        assert totals[name][0] == sum(s.attrs[attr] for s in frames) > 0
+        assert 0.0 < totals[name][1]
+        assert totals["commit.frame.events"][1] + totals["commit.frame.roots"][1] \
+            <= totals["commit.frame"][1]
+
+
+def test_provenance_total_counts_the_captured_cells(run):
+    _, core, _, _, _ = run
+    marks = [m for m in core.hg.obs.provenance.to_json()["marks"]
+             if m["name"] == "prov.capture"]
+    count, seconds = core.hg.obs.tracer.totals()["obs.provenance"]
+    assert count == sum(m["fields"]["cells"] for m in marks) > 0
+    assert {m["fields"]["engine"] for m in marks} == {"live"}
+    assert 0.0 < seconds <= core.hg.obs.tracer.totals()["live.integrate.rounds"][1]
+
+
+def test_fetch_discipline_is_counted(run):
+    discipline, core, _, _, spans = run
+    totals = core.hg.obs.tracer.totals()
+    fetches = named(spans, "device.fetch")
+    pipelined = [s for s in fetches if s.attrs["discipline"] == "pipelined"]
+    assert totals.get("fetch.pipelined", (0, 0.0))[0] == len(pipelined)
+    assert totals["fetch.lag"] == (sum(s.attrs["lag_calls"] for s in fetches), 0.0)
+    if discipline == "pipelined":
+        assert len(pipelined) >= len(fetches) - 1 and totals["fetch.lag"][0] > 0
+    else:
+        assert not pipelined and totals["fetch.lag"][0] == 0
 
 
 def test_refused_reception_is_counted_and_repaired(monkeypatch, cpu_blocks):

@@ -37,6 +37,8 @@ class Run:
     def __init__(self):
         cluster = Cluster()
         tracer = cluster.nodes[0].obs.tracer
+        # a push's own event is followed by no consensus call
+        cluster.nodes[0].core.hg.hand_over_inserts()
         self.offered = cluster.offered
         self.spans = tracer.spans()
         self.totals = tracer.totals()
