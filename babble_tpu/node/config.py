@@ -36,9 +36,11 @@ class Config:
     # cannot express falls down the same ladder as the single-device path
     mesh_devices: int = 0
     # async device dispatch (tpu/live.py multi-slot pipeline and the
-    # queued-mesh rung in tpu/dispatch.py): up to this many dispatches may
-    # be in flight before the serve path blocks to integrate the oldest.
-    # 1 reproduces the old single-slot overlap; 0 disables queuing.
+    # queued-mesh rung in tpu/dispatch.py): the cap on dispatches in
+    # flight. The mesh rung fills it before the serve path blocks to
+    # integrate the oldest; the live engine's pipelined fetch keeps one in
+    # flight and goes deeper, up to this cap, only while the oldest fetch
+    # is really waited on. 1 is the single-slot overlap; 0 disables queuing.
     dispatch_queue_depth: int = 4
     # cross-round dispatch batching: hold gossip-staged rows for up to
     # this many Clock seconds (or until a size threshold) before

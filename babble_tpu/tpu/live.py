@@ -95,9 +95,10 @@ def derive_fd_updates(grid: DagGrid) -> List[List[int]]:
 ENGINE_DEFAULTS = dict(
     e_cap=1 << 16, r_cap=64, batch_cap=64, upd_cap=8192, e_win=8192,
     r_win=64,
-    # async dispatch queue (ISSUE 6): up to queue_depth dispatches in
-    # flight before the serve path blocks to integrate the oldest; 1
-    # reproduces the round-3 single-slot overlap. batch_deadline > 0
+    # async dispatch queue (ISSUE 6): at most queue_depth dispatches in
+    # flight under the pipelined discipline. It is the cap, not the lag:
+    # the engine keeps one in flight and goes deeper only while the oldest
+    # fetch is really waited on (_note_wait). batch_deadline > 0
     # holds gossip-staged rows for that many Clock seconds (or until
     # batch_cap rows accumulate) before dispatching, so the device sees
     # fewer, larger trains. Node configs override both via
@@ -195,11 +196,14 @@ class LiveDeviceEngine:
         # pipelined-fetch discipline: flips on when the measured blocking
         # fetch is consistently expensive (ASYNC_FETCH_MIN_S). inflight is
         # a bounded FIFO of
-        # (_AsyncFetch, snapshot, t_dispatch) tuples — up to queue_depth
-        # dispatches ride concurrently, integrated oldest-first on
-        # DETERMINISTIC conditions only (queue full, or no dispatch this
-        # call) so same-seed sim runs never diverge on thread timing.
+        # (_AsyncFetch, snapshot, t_dispatch) tuples — fetch_lag dispatches
+        # ride concurrently (one; up to queue_depth after waits read from
+        # the obs clock: _note_wait), integrated oldest-first on
+        # DETERMINISTIC conditions only (fetch_lag in flight, or no
+        # dispatch this call) so same-seed sim runs never diverge on
+        # thread timing.
         self.async_fetch = ENGINE_DEFAULTS.get("async_fetch") is True
+        self.fetch_lag = 1
         self.queue_depth = (
             d["queue_depth"] if queue_depth is None else queue_depth
         )
@@ -988,21 +992,26 @@ def run_consensus_live(hg, queue_depth: int = None,
       expensive (threshold ASYNC_FETCH_MIN_S over 3 consecutive calls —
       on a colocated chip that means the device compute itself is slow
       against the gossip interval), the fetch moves OFF the consensus
-      critical path: up to ``queue_depth`` dispatches ride concurrently,
-      each call integrating the OLDEST dispatch's results (already
-      resident host-side via a background reader thread) and launching a
-      new dispatch whose compute and transfer overlap the next gossip
-      intervals.
-      Decisions lag up to queue_depth syncs — pure timing, not content:
+      critical path: each call integrates the PREVIOUS call's dispatch
+      (its results already resident host-side via a background reader
+      thread) and launches a new dispatch whose compute and transfer
+      overlap the next gossip interval. Only while that fetch is still
+      waited on (the same threshold, 3 consecutive calls) does the engine
+      keep one more dispatch in flight, up to ``queue_depth`` of them,
+      and a queue that drains for lack of traffic starts again at one
+      (_note_wait, _run_pipelined).
+      Decisions lag one sync, and up to queue_depth where the device
+      needs them — pure timing, not content:
       rounds, fame, and receptions are DAG facts, so block bodies stay
       byte-identical (pinned by the strict joiner differentials), they
-      just seal a few calls later. The write-back validation gates run
+      just seal that many calls later. The write-back validation gates run
       unchanged at integration time against a dispatch-time snapshot of
       the row mapping (rebases build fresh containers, so snapshots are
       O(1) references), and integration order is FIFO so parents' rounds
       always land before children's. Integration TRIGGERS are
-      deterministic (queue occupancy and call sequence, never thread
-      completion state) so same-seed sim runs stay byte-identical.
+      deterministic (queue occupancy, call sequence and waits read from
+      the obs clock, as the flip is; never thread completion state) so
+      same-seed sim runs stay byte-identical.
     """
     eng: Optional[LiveDeviceEngine] = getattr(hg, "_live_device_engine", None)
     if eng is None:
@@ -1037,7 +1046,9 @@ def run_consensus_live(hg, queue_depth: int = None,
 # narrower state's longer one, wait 13 ms and more and pipeline as before
 # (PERF.md section 6, PR 32: every cell's waits over six runs). A fixed
 # number of milliseconds stays the wrong rule for this trade of the wait
-# against queue_depth - 1 syncs of latency: ROADMAP.md queue A item 7.
+# against one call of latency (the pipelined discipline starts at a lag
+# of one: _note_wait): ROADMAP.md queue A item 6. The same threshold and
+# the same three in a row deepen the pipelined lag.
 ASYNC_FETCH_MIN_S = 0.012
 
 
@@ -1154,27 +1165,49 @@ def _run_sync(hg, eng: LiveDeviceEngine, new_rows: List[int]) -> None:
     forced = ENGINE_DEFAULTS.get("async_fetch")
     if forced is False:
         return
-    if dt > ASYNC_FETCH_MIN_S:
-        eng._slow_fetches += 1
-    else:
-        eng._slow_fetches = 0
-    if forced is True or eng._slow_fetches >= 3:
+    if forced is True or _waited_thrice(eng, dt):
         eng.async_fetch = True
+        eng._slow_fetches = 0  # the pipelined lag counts its own waits
 
 
-def _integrate_oldest(hg, eng: LiveDeviceEngine) -> int:
+def _waited_thrice(eng: LiveDeviceEngine, dt: float) -> bool:
+    """The evidence both for the flip and for a deeper lag: the third
+    consecutive fetch that blocked over ASYNC_FETCH_MIN_S."""
+    eng._slow_fetches = eng._slow_fetches + 1 if dt > ASYNC_FETCH_MIN_S else 0
+    return eng._slow_fetches >= 3
+
+
+def _note_wait(hg, eng: LiveDeviceEngine, dt: float) -> None:
+    """The pipelined lag finds its own depth: a fetch the queue's
+    occupancy asked for (not a barrier's drain, which waits by design)
+    that blocked on three consecutive calls means the device is not done
+    with a sync's programs when the call `fetch_lag` later comes, so one
+    more dispatch stays in flight, up to the configured queue_depth. `dt`
+    is read from the obs clock, as the flip's is: under the sim's virtual
+    clock it is 0 and the lag stays one."""
+    if _waited_thrice(eng, dt) and eng.fetch_lag < eng.queue_depth:
+        eng.fetch_lag += 1
+        eng._slow_fetches = 0
+        hg.obs.tracer.add("fetch.deepen", 0.0)
+
+
+def _integrate_oldest(hg, eng: LiveDeviceEngine, paced: bool = False) -> int:
     """Pop + integrate the oldest in-flight dispatch (FIFO — parents'
     rounds land before children's). Blocks only if the background reader
     has not finished; the blocked fraction of the dispatch's in-flight
-    wall time feeds the overlap-utilization histogram."""
+    wall time feeds the overlap-utilization histogram and, where the
+    queue's occupancy asked for this integration (`paced`), the lag."""
     fetch, snap, t_disp = eng.inflight.pop(0)
     # normally already resident
     packed, fetched = _fetch(hg, eng, snap, fetch.result, "pipelined")
     dt = fetched.duration
     in_flight = max(fetched.start + dt - t_disp, 1e-9)
     eng._m_overlap.observe(max(0.0, min(1.0, 1.0 - dt / in_flight)))
+    if paced:
+        _note_wait(hg, eng, dt)
     hg.obs.flightrec.record(
         "live.integrate", blocked=dt, depth=len(eng.inflight),
+        lag=eng.fetch_lag,
     )
     return _integrate(hg, eng, packed, snap)
 
@@ -1208,18 +1241,21 @@ def flush_live_engine(hg) -> None:
 
 
 def _run_pipelined(hg, eng: LiveDeviceEngine) -> None:
-    """Multi-slot overlap: keep up to queue_depth dispatches in flight,
-    integrating the oldest when the queue is full (steady state:
-    integrate N-1, dispatch N) or when gossip staged nothing this call
-    (so the queue drains when traffic quiets). Both triggers are
-    functions of queue occupancy and the call sequence — never of
-    whether a background fetch happens to have finished — so the
-    integration schedule is deterministic under the sim's virtual clock.
+    """Multi-slot overlap: keep fetch_lag dispatches in flight (one, and
+    up to queue_depth while the oldest fetch is really waited on:
+    _note_wait), integrating the oldest when that many ride (steady
+    state: integrate N-1, dispatch N) or when gossip staged nothing this
+    call (so the queue drains when traffic quiets, and a drained queue
+    starts again at a lag of one: what the waits said of the device's
+    pace is stale by then, and nothing in flight is held back by it). The
+    triggers are functions of queue occupancy, the call sequence and
+    waits on the obs clock — never of whether a background fetch happens
+    to have finished — so the integration schedule is deterministic under
+    the sim's virtual clock.
     """
     clock = hg.obs.clock
-    depth = max(1, eng.queue_depth)
-    while len(eng.inflight) >= depth:
-        _settle_capacity(hg, eng, _integrate_oldest(hg, eng))
+    while len(eng.inflight) >= eng.fetch_lag:
+        _settle_capacity(hg, eng, _integrate_oldest(hg, eng, paced=True))
 
     # cross-round dispatch batching: hold gossip-staged rows (all of
     # them — a partial drain would strand events no snapshot models)
@@ -1246,6 +1282,8 @@ def _run_pipelined(hg, eng: LiveDeviceEngine) -> None:
             )
     if not dispatched and eng.inflight:
         _settle_capacity(hg, eng, _integrate_oldest(hg, eng))
+        if not eng.inflight:
+            eng.fetch_lag, eng._slow_fetches = 1, 0
     eng._m_qdepth.set(float(len(eng.inflight)))
 
     hg.process_decided_rounds()
@@ -1509,9 +1547,9 @@ def _manage_capacity(eng: LiveDeviceEngine, last_round_rel: int) -> None:
     rebase (fame decisions lagging, so the base cannot advance yet) is
     tolerated while hard room remains — it is retried on every
     subsequent sync; only an exhausted axis escalates to the caller's
-    fallback. Under the queued discipline last_round_rel is up to
-    queue_depth dispatches old; the soft margin (8 rounds) absorbs the
-    lag, and the caller (_settle_capacity) guarantees the in-flight
+    fallback. Under the queued discipline last_round_rel is fetch_lag
+    dispatches old (one, at most queue_depth); the soft margin (8 rounds)
+    absorbs the lag, and the caller (_settle_capacity) guarantees the in-flight
     queue is empty before this may rebase."""
     hard = (
         last_round_rel >= eng.r_cap - 3

@@ -141,10 +141,12 @@ def test_mixed_backend_cluster_byte_identical():
         shutdown_nodes(nodes)
 
 
-def test_pipelined_fetch_cluster_byte_identical(monkeypatch):
+@pytest.mark.parametrize("lag", ["one", "cap"])
+def test_pipelined_fetch_cluster_byte_identical(monkeypatch, lag):
     """VERDICT r3 #2: with the device->host result fetch forced OFF the
     consensus critical path (pipelined discipline — decisions integrate
-    one sync late), a mixed cpu/tpu cluster must still commit
+    one sync late, or as many as the queue's cap where every wait counts
+    as one too long), a mixed cpu/tpu cluster must still commit
     byte-identical blocks: reception/fame values are DAG facts, so the
     lag shifts only WHEN a block seals, never what goes into it. Also
     forces rebases (tiny round axis) so the rebase-between-integrations
@@ -153,6 +155,10 @@ def test_pipelined_fetch_cluster_byte_identical(monkeypatch):
 
     monkeypatch.setitem(live_mod.ENGINE_DEFAULTS, "async_fetch", True)
     monkeypatch.setitem(live_mod.ENGINE_DEFAULTS, "r_cap", 16)
+    # no wait deepens the lag / every wait does, three calls a step
+    monkeypatch.setattr(
+        live_mod, "ASYNC_FETCH_MIN_S", 1e9 if lag == "one" else -1.0,
+    )
 
     nodes, proxies, *_ = build_mixed_cluster(
         ["cpu", "tpu", "cpu", "tpu"], sync_limit=2000
@@ -162,12 +168,17 @@ def test_pipelined_fetch_cluster_byte_identical(monkeypatch):
         bombard_and_wait(nodes, proxies, target_block=12, timeout_s=300)
         check_gossip(nodes, upto=12)
         pipelined = 0
+        deepened = 0
         for node in (nodes[1], nodes[3]):
             assert node.core.device_consensus_runs > 0
             eng = getattr(node.core.hg, "_live_device_engine", None)
             if eng is not None and eng.async_fetch:
                 pipelined += 1
+                assert 1 <= eng.fetch_lag <= eng.queue_depth == 4
+            deepened += node.core.hg.obs.tracer.totals().get(
+                "fetch.deepen", (0, 0.0))[0]
         assert pipelined > 0, "no node ran the pipelined fetch discipline"
+        assert (deepened > 0) == (lag == "cap")
     finally:
         shutdown_nodes(nodes)
 

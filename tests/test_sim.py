@@ -524,3 +524,50 @@ def test_round_batched_dispatch_deterministic():
     assert sum(
         _rounds_per_dispatch_count(a, f"node{i}") for i in range(4)
     ) > 0, "batching never active — the determinism assertion is vacuous"
+
+
+# ----------------------------------------------------------------------
+# live rung, pipelined fetch: the lag is one call under the virtual clock
+# ----------------------------------------------------------------------
+
+@pytest.mark.parametrize("depth", [1, 4])
+def test_live_rung_pipelined_lag_is_one_and_deterministic(monkeypatch, depth):
+    """The live rung's pipelined fetch on the virtual clock: a wait reads
+    0 there, so whatever `dispatch_queue_depth` caps, every result is
+    integrated on the call after its dispatch (tpu/live.py _note_wait
+    deepens the lag on waits read from the obs clock, never on a reader
+    thread's state), and two same-seed runs replay the same schedule:
+    same digest, same flight records, same trace."""
+    from babble_tpu.tpu import live as live_mod
+
+    monkeypatch.setitem(live_mod.ENGINE_DEFAULTS, "async_fetch", True)
+    kwargs = dict(n=4, seed=9, plan=FaultPlan(name="clean"), backend="tpu",
+                  dispatch_queue_depth=depth)
+
+    def run_once():
+        cluster = SimCluster(**kwargs)
+        try:
+            res = cluster.run(until=None, target_block=2)
+            integrated = [
+                r.fields for sn in cluster.sns
+                for r in sn.node.obs.flightrec.records()
+                if r.name == "live.integrate"
+            ]
+            lags = {
+                s.attrs["lag_calls"] for sn in cluster.sns
+                for s in sn.node.obs.tracer.spans()
+                if s.name == "device.fetch"
+                and s.attrs["discipline"] == "pipelined"
+            }
+            return res, integrated, lags
+        finally:
+            cluster.shutdown()
+
+    a, integrated, lags = run_once()
+    b, _, _ = run_once()
+    assert integrated and lags == {1}
+    assert {(r["lag"], r["blocked"]) for r in integrated} == {(1, 0.0)}
+    assert a["digest"] == b["digest"]
+    assert a["flightrec_fingerprint"] == b["flightrec_fingerprint"]
+    assert a["trace_fingerprint"] == b["trace_fingerprint"]
+    assert a["events_run"] == b["events_run"]
