@@ -74,7 +74,11 @@ def build_parser() -> argparse.ArgumentParser:
                      help="Allow /debug/* (profiler, stack dumps) from "
                           "non-loopback clients")
     run.add_argument("--store", action="store_true",
-                     help="Use persistent on-disk store instead of in-mem")
+                     help="Keep the hashgraph in <datadir>/babble.db (SQLite, "
+                          "WAL, synchronous=FULL) instead of in memory: a "
+                          "sync is durable when it ends, a block before the "
+                          "application sees it, and a restart replays the "
+                          "file (docs/store.md)")
     run.add_argument("--cache-size", type=int, default=500,
                      help="Number of items in LRU caches")
     run.add_argument("--heartbeat", type=float, default=1.0,

@@ -86,6 +86,7 @@ class Hashgraph:
         # nil-guards; a Node passes its own bundle (sharing the injected
         # clock), direct construction gets a private system-clock one
         self.obs = obs if obs is not None else Observability()
+        store.tracer = self.obs.tracer
         self._pass_hist = self.obs.histogram(
             "babble_consensus_pass_duration_seconds",
             "Wall time of each consensus pipeline pass",
@@ -1027,6 +1028,9 @@ class Hashgraph:
                         self.check_block_immutable(block)
                         self.store.set_block(block)
                         if self.commit_callback is not None:
+                            # on disk before the application sees it: the
+                            # block, its frame and every event it orders
+                            self.store.flush()
                             self.commit_callback(block)
                         sp.attrs["index"] = block.index()
                         sp.attrs["txs"] = txs

@@ -10,6 +10,19 @@ SQLite (stdlib) replaces BadgerDB; the reference's key scheme
 (`topo_%09d`, `{participant}__event_%09d`, ... reference:
 src/hashgraph/badger_store.go:121-147) becomes indexed relational tables,
 which buys us ordered replay and participant-index lookups for free.
+
+What is durable when. Writes gather in one open transaction, which `flush`
+commits under `synchronous=FULL` (one fsync of the log): nothing is on disk
+statement by statement, everything written between two flushes is there
+together or not at all. The callers set the boundaries (`Store.flush`): a
+sync is durable when `Core.sync` / `Core.run_consensus` return and before
+the Core signs a self-event on top of it, and a block, its frame and every
+event it orders are durable before the commit callback sees the block. A
+process that dies between two flushes restarts (`load_or_create`, then
+`Core.bootstrap`) with whole earlier syncs and never a block without its
+events. `close` flushes. The log is a WAL, so that a reader on a connection
+of its own (a tool, a second process) never finds the file locked, whatever
+the writer has open; docs/store.md has the reading the choice rests on.
 """
 
 from __future__ import annotations
@@ -61,6 +74,23 @@ CREATE TABLE IF NOT EXISTS participants (
 );
 """
 
+JOURNAL_MODE = "WAL"
+# a sync's transaction is ~16 MB at 64 validators: a page cache that holds it
+# (KiB) writes it once, at the commit, and a log that may grow to four of
+# them (pages) is copied into the database every few syncs, not after each
+# (PERF.md section 6, PR 37, has the readings on the chip's host)
+PAGE_CACHE_KIB = 65536
+WAL_CHECKPOINT_PAGES = 16384
+
+# tracer totals (seconds; count), summed here and handed over at `flush`
+SET_EVENT = "store.set_event"  # rows written
+SET_ROUND = "store.set_round"  # rows written
+SET_BLOCK_FRAME = "store.set_block_frame"  # rows written, blocks and frames
+RELEASE_PATCH = "store.release_patch"  # rows patched
+FLUSH = "store.flush"  # transactions committed
+BYTES = "store.bytes"  # no seconds; bytes of row data handed to SQLite
+TOTALS = (SET_EVENT, SET_ROUND, SET_BLOCK_FRAME, RELEASE_PATCH, FLUSH, BYTES)
+
 
 class SQLiteStore(Store):
     def __init__(self, participants: Peers, cache_size: int, path: str, existing_db: bool = False):
@@ -72,7 +102,12 @@ class SQLiteStore(Store):
         # access is serialized by the node's core_lock, so sharing the
         # connection across the node's worker threads is safe
         self.db = sqlite3.connect(path, check_same_thread=False)
+        self.db.execute(f"PRAGMA journal_mode={JOURNAL_MODE}")
+        self.db.execute("PRAGMA synchronous=FULL")
+        self.db.execute(f"PRAGMA cache_size=-{PAGE_CACHE_KIB}")
+        self.db.execute(f"PRAGMA wal_autocheckpoint={WAL_CHECKPOINT_PAGES}")
         self.db.executescript(_SCHEMA)
+        self._sums = {total: [0.0, 0] for total in TOTALS}  # [seconds, count]
 
         if existing_db:
             # participants come from the db, roots re-read from disk
@@ -86,14 +121,14 @@ class SQLiteStore(Store):
                         pass
                 self.inmem._roots_by_self_parent = None
         else:
-            with self.db:
-                for p in participants.to_peer_slice():
-                    self.db.execute(
-                        "INSERT OR REPLACE INTO participants VALUES (?, ?)",
-                        (p.pub_key_hex, p.net_addr),
-                    )
-                for pk, root in self.inmem.roots_by_participant.items():
-                    self._db_set_root(pk, root)
+            for p in participants.to_peer_slice():
+                self.db.execute(
+                    "INSERT OR REPLACE INTO participants VALUES (?, ?)",
+                    (p.pub_key_hex, p.net_addr),
+                )
+            for pk, root in self.inmem.roots_by_participant.items():
+                self._db_set_root(pk, root)
+            self.db.commit()
 
         self._topo_counter = self._db_max_topo() + 1
 
@@ -104,6 +139,33 @@ class SQLiteStore(Store):
         if os.path.exists(path):
             return cls(participants, cache_size, path, existing_db=True)
         return cls(participants, cache_size, path, existing_db=False)
+
+    # -- the flush boundary and the totals ---------------------------------
+
+    def _now(self) -> float:
+        """The owning Hashgraph's clock; nothing is timed without one."""
+        tracer = self.tracer
+        return tracer.clock.monotonic() if tracer is not None else 0.0
+
+    def _note(self, total: str, since: float, count: int, nbytes: int = 0) -> None:
+        got = self._sums[total]
+        got[0] += self._now() - since
+        got[1] += count
+        self._sums[BYTES][1] += nbytes
+
+    def flush(self) -> None:
+        """Commit the open transaction, if one is open, and hand the sums
+        since the last flush to the tracer."""
+        if self.db.in_transaction:
+            t = self._now()
+            self.db.commit()
+            self._note(FLUSH, t, 1)
+        tracer = self.tracer
+        if tracer is not None:
+            for total, got in self._sums.items():
+                if got[1]:
+                    tracer.add(total, got[0], got[1])
+                    got[:] = 0.0, 0
 
     # -- db helpers --------------------------------------------------------
 
@@ -163,22 +225,21 @@ class SQLiteStore(Store):
             return event
 
     def set_event(self, event: Event) -> None:
+        t = self._now()
         peer = self.inmem.participants().by_pub_key[event.creator()]
         last_known = self.inmem.participant_events_cache.known().get(peer.id, -1)
         if event.index() > last_known:
             # advances the creator's sequence: register in the
             # participant rolling index
-            with self.db:
-                self.inmem.set_event(event)
-                self._db_put_event(event)
+            self.inmem.set_event(event)
         else:
             # an event already registered (possibly LRU-evicted meanwhile,
             # so the object may be a copy read from disk): put it in the
             # cache, which registers nothing again (that would hit a rolled
             # participant window), and write it through
-            with self.db:
-                self.inmem.event_cache.add(event.hex(), event)
-                self._db_put_event(event)
+            self.inmem.event_cache.add(event.hex(), event)
+        nbytes = self._db_put_event(event)
+        self._note(SET_EVENT, t, 1, nbytes)
 
     def keep_first_descendants(self, keys, cells_of) -> None:
         """Every one of them: the cached objects, and each event's row, so
@@ -187,35 +248,42 @@ class SQLiteStore(Store):
         table is the truth while it holds them, and a restart rebuilds
         it: `Hashgraph.bootstrap`): the one field patched in place, the
         whole block in one statement."""
+        t = self._now()
         self.inmem.keep_first_descendants(keys, cells_of)
-        with self.db:
-            self.db.executemany(
-                "UPDATE events SET data = "
-                "json_set(data, '$.Meta.FirstDescendants', json(?)) "
-                "WHERE hex = ?",
-                ((json.dumps(cells_of(k)), key)
-                 for k, key in enumerate(keys) if key),
-            )
+        rows = nbytes = 0
 
-    def _db_put_event(self, event: Event) -> None:
+        def patches():
+            nonlocal rows, nbytes
+            for k, key in enumerate(keys):
+                if key:
+                    cells = json.dumps(cells_of(k))
+                    rows += 1
+                    nbytes += len(cells)
+                    yield cells, key
+
+        self.db.executemany(
+            "UPDATE events SET data = "
+            "json_set(data, '$.Meta.FirstDescendants', json(?)) "
+            "WHERE hex = ?",
+            patches(),
+        )
+        self._note(RELEASE_PATCH, t, rows, nbytes)
+
+    def _db_put_event(self, event: Event) -> int:
         """The event's row, under the topological index it was first
-        written with."""
+        written with; returns the bytes of the row's data."""
         row = self.db.execute(
             "SELECT topo_index FROM events WHERE hex = ?", (event.hex(),)
         ).fetchone()
         topo = row[0] if row else self._topo_counter
         if row is None:
             self._topo_counter += 1
+        data = json.dumps(event.to_store_json())
         self.db.execute(
             "INSERT OR REPLACE INTO events VALUES (?, ?, ?, ?, ?)",
-            (
-                event.hex(),
-                topo,
-                event.creator(),
-                event.index(),
-                json.dumps(event.to_store_json()),
-            ),
+            (event.hex(), topo, event.creator(), event.index(), data),
         )
+        return len(data)
 
     def participant_events(self, participant: str, skip: int) -> List[str]:
         try:
@@ -270,12 +338,11 @@ class SQLiteStore(Store):
             return RoundInfo.from_json(json.loads(row[0]))
 
     def set_round(self, r: int, round_info: RoundInfo) -> None:
+        t = self._now()
         self.inmem.set_round(r, round_info)
-        with self.db:
-            self.db.execute(
-                "INSERT OR REPLACE INTO rounds VALUES (?, ?)",
-                (r, json.dumps(round_info.to_json())),
-            )
+        data = json.dumps(round_info.to_json())
+        self.db.execute("INSERT OR REPLACE INTO rounds VALUES (?, ?)", (r, data))
+        self._note(SET_ROUND, t, 1, len(data))
 
     def last_round(self) -> int:
         return self.inmem.last_round()
@@ -308,12 +375,12 @@ class SQLiteStore(Store):
             return Block.from_json(json.loads(row[0]))
 
     def set_block(self, block: Block) -> None:
+        t = self._now()
         self.inmem.set_block(block)
-        with self.db:
-            self.db.execute(
-                "INSERT OR REPLACE INTO blocks VALUES (?, ?)",
-                (block.index(), json.dumps(block.to_json())),
-            )
+        data = json.dumps(block.to_json())
+        self.db.execute(
+            "INSERT OR REPLACE INTO blocks VALUES (?, ?)", (block.index(), data))
+        self._note(SET_BLOCK_FRAME, t, 1, len(data))
 
     def last_block_index(self) -> int:
         return self.inmem.last_block_index()
@@ -328,20 +395,25 @@ class SQLiteStore(Store):
             return Frame.from_json(json.loads(row[0]))
 
     def set_frame(self, frame: Frame) -> None:
+        t = self._now()
         self.inmem.set_frame(frame)
-        with self.db:
-            self.db.execute(
-                "INSERT OR REPLACE INTO frames VALUES (?, ?)",
-                (frame.round, json.dumps(frame.to_json())),
-            )
+        data = json.dumps(frame.to_json())
+        self.db.execute(
+            "INSERT OR REPLACE INTO frames VALUES (?, ?)", (frame.round, data))
+        self._note(SET_BLOCK_FRAME, t, 1, len(data))
 
     def reset(self, roots: Dict[str, Root]) -> None:
         self.inmem.reset(roots)
-        with self.db:
-            for pk, root in roots.items():
-                self._db_set_root(pk, root)
+        for pk, root in roots.items():
+            self._db_set_root(pk, root)
 
     def close(self) -> None:
+        """Flush, then close; closing a closed store is nothing, as with
+        sqlite3's own `close`."""
+        try:
+            self.flush()
+        except sqlite3.ProgrammingError:
+            return  # the connection is closed already
         self.db.close()
 
     def need_bootstrap(self) -> bool:

@@ -19,6 +19,9 @@ class Store(ABC):
     # anew (read back from disk) is handed it, so that it answers as the
     # live object does
     coordinates = None
+    # the owning Hashgraph's span tracer: a store that times its writes
+    # hands the sums over at `flush` (totals `store.*`, docs/observability.md)
+    tracer = None
 
     @abstractmethod
     def cache_size(self) -> int: ...
@@ -108,6 +111,14 @@ class Store(ABC):
 
     @abstractmethod
     def reset(self, roots: Dict[str, Root]) -> None: ...
+
+    def flush(self) -> None:
+        """The durability boundary: when it returns, everything written
+        since the last boundary is committed, as one unit. The Core calls
+        it where a sync and a consensus call end and before it signs a
+        self-event, the Hashgraph before a block goes to the commit
+        callback. A store that keeps nothing across a restart has nothing
+        to do."""
 
     @abstractmethod
     def close(self) -> None: ...

@@ -164,10 +164,14 @@ class Core:
 
     def bootstrap(self) -> None:
         self.hg.bootstrap()
+        self.hg.store.flush()
 
     # -- event insertion ---------------------------------------------------
 
     def sign_and_insert_self_event(self, event: Event) -> None:
+        # a self-event vouches for its other-parent and everything under
+        # it: that is durable before the signature exists
+        self.hg.store.flush()
         event.sign(self.key)
         self.insert_event(event, True)
 
@@ -256,6 +260,7 @@ class Core:
                 obs.tracer.record("sync.insert", sp.start + decode_s, insert_s)
                 obs.tracer.add("sync.events", 0.0, inserted)
             self.add_self_event(other_head)
+            self.hg.store.flush()
 
     def _insert_wire_events(
         self, unknown_events: List[WireEvent], spent: list
@@ -435,6 +440,9 @@ class Core:
                           call=self._consensus_calls) as sp:
                 try:
                     self._run_ladder()
+                    # the sync is durable when the call returns: the point
+                    # at which a node answers its peer
+                    self.hg.store.flush()
                 finally:
                     sp.attrs["rung"] = self.ladder_rung()
         finally:
@@ -734,6 +742,7 @@ class Core:
             from ..tpu.live import flush_live_engine
 
             flush_live_engine(self.hg)
+        self.hg.store.flush()
 
     def add_transactions(
         self, txs: List[bytes], admitted_at: Optional[List[float]] = None

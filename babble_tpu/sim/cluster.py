@@ -454,11 +454,14 @@ class SimCluster:
         sn.gen += 1  # orphan every callback the dead process scheduled
         sn.exchange_inflight = False
         self.net.set_alive(sn.addr, False)
-        # close the store so a sqlite file can be reopened cleanly;
-        # NOT node.shutdown(): that joins threads we never started and
-        # a real crash doesn't run shutdown hooks anyway
+        # drop the store so a sqlite file can be reopened; NOT
+        # node.shutdown(): that joins threads we never started and a real
+        # crash doesn't run shutdown hooks anyway. Nor `store.close()`,
+        # which flushes: what a sqlite store wrote since its last flush
+        # dies with the process (the connection closes without a commit)
+        store = sn.node.core.hg.store
         try:
-            sn.node.core.hg.store.close()
+            getattr(store, "db", store).close()
         except Exception:  # noqa: BLE001 — a dirty close IS the crash
             pass
 

@@ -1,0 +1,304 @@
+"""The durable deployment (`babble run --store`) at small sizes, JAX on the
+CPU: a seeded gossip stream handed in 100-event syncs to an observer
+`Core(consensus_backend="tpu")` on a `SQLiteStore` in a directory on disk.
+
+What is held (the letters are the issue's, PR 37):
+
+(a) blocks and every event's round, lamport timestamp and round received
+    equal an in-memory Core's and the plain reference's over the file
+    (`benchmark/reference/durable.py`), and the rows on disk carry them;
+(b) at every block hand-over a second, read-only connection reads the
+    block, its frame and every event it orders;
+(c) a validator stopped at a sync boundary (the connection dropped, no
+    flush) and started again from the file holds the same known events and
+    re-derives the same blocks, byte for byte;
+(d) the connection dropped inside a sync, in the inserts or at a block
+    hand-over: whole earlier syncs are there, no block without its events,
+    and the remaining events handed over again end in the same blocks;
+(e) the `store.*` totals are there and non-zero, and a run of k syncs that
+    commit b blocks makes at most k + b + 1 transactions;
+(f) a Core on `InmemStore` records no `store.*` total.
+"""
+
+import json
+from types import SimpleNamespace
+
+import pytest
+
+from babble_tpu.hashgraph import InmemStore, SQLiteStore
+from babble_tpu.node import Core
+from benchmark import traffic as gen
+from benchmark.entries import replay
+from benchmark.reference import durable
+
+SYNC = 100
+CACHE = 50000
+CASES = {"v16": (16, 3000), "v64": (64, 4000)}  # validators, events
+TOPOLOGY_SEED, SEED, ZIPF_A = 1000000007, 7, 1.1
+STORE_TOTALS = ("store.set_event", "store.set_round", "store.set_block_frame",
+                "store.flush", "store.bytes")
+
+
+class Handover(replay.CommitStamps):
+    """The application's end of `commit_ch`: every block's body, and at the
+    hand-over what a reader on a connection of its own finds missing."""
+
+    def __init__(self, stream, path=None):
+        super().__init__()
+        self.stream, self.path = stream, path
+        self.bodies = []
+        self.unreadable = []  # (block index, what was missing)
+        # set where a test stops the validator at a block's hand-over
+        self.store = self.stop_at = None
+
+    def put(self, block) -> None:
+        super().put(block)
+        self.bodies.append(block.body.marshal())
+        if self.path is not None:
+            self.unreadable += [(block.index(), what)
+                                for what in missing_on_disk(self.path, self.stream, block)]
+        if self.stop_at == block.index():
+            self.store.db.close()  # the process dies here: no flush
+            raise Stopped()
+
+
+class Stopped(BaseException):
+    """The process dies: no `except Exception` of the ladder may hold it."""
+
+
+def missing_on_disk(path, stream, block) -> list:
+    """What of `block`, its frame and the events it orders a read-only
+    connection does not find in the file at `path`."""
+    missing = []
+    db = durable.connect(path)
+    try:
+        row = db.execute("SELECT data FROM blocks WHERE idx = ?",
+                         (block.index(),)).fetchone()
+        if row is None or json.loads(row[0])["Body"] != block.body.to_canonical():
+            missing.append("block")
+        if db.execute("SELECT 1 FROM frames WHERE idx = ?",
+                      (block.round_received(),)).fetchone() is None:
+            missing.append("frame")
+        for tx in block.transactions():
+            key = stream.signed[gen.payload_event(tx)].hex()
+            if db.execute("SELECT 1 FROM events WHERE hex = ?",
+                          (key,)).fetchone() is None:
+                missing.append(key)
+    finally:
+        db.close()
+    return missing
+
+
+def new_core(stream, store, commit):
+    return Core(0, stream.key, stream.peers, store, commit_ch=commit,
+                consensus_backend="tpu")
+
+
+def feed(core, stream, lo, hi) -> int:
+    """Hand over events [lo, hi) in SYNC-event syncs; the syncs made."""
+    syncs = 0
+    for a in range(lo, hi, SYNC):
+        for signed in stream.signed[a:min(a + SYNC, hi)]:
+            core.insert_event(stream.copy(signed), True)
+        core.run_consensus()
+        syncs += 1
+    return syncs
+
+
+def stamps_of(core, stream, upto) -> list:
+    def stamp(v):
+        return -1 if v is None else int(v)
+
+    out = []
+    for signed in stream.signed[:upto]:
+        ev = core.hg.store.get_event(signed.hex())
+        out.append((stamp(ev.round), stamp(ev.lamport_timestamp),
+                    stamp(ev.round_received)))
+    return out
+
+
+_STREAMS, _IN_MEMORY = {}, {}
+
+
+def stream_of(case):
+    if case not in _STREAMS:
+        n, events = CASES[case]
+        _STREAMS[case] = replay.Stream(n, events, SEED, ZIPF_A, 1, TOPOLOGY_SEED)
+    return _STREAMS[case]
+
+
+def in_memory(case):
+    """The whole stream through a Core on `InmemStore`: (core, blocks)."""
+    if case not in _IN_MEMORY:
+        stream = stream_of(case)
+        blocks = Handover(stream)
+        core = new_core(stream, InmemStore(stream.peers, CACHE), blocks)
+        feed(core, stream, 0, CASES[case][1])
+        core.flush_device_dispatch()
+        _IN_MEMORY[case] = core, blocks
+    return _IN_MEMORY[case]
+
+
+@pytest.fixture(scope="module", params=list(CASES))
+def ran(request, tmp_path_factory):
+    """One whole run on disk and one in memory, per case."""
+    case = request.param
+    stream = stream_of(case)
+    events = CASES[case][1]
+    path = str(tmp_path_factory.mktemp(case) / "store" / "babble.db")
+    disk_blocks = Handover(stream, path)
+    disk = new_core(stream, SQLiteStore(stream.peers, CACHE, path), disk_blocks)
+    syncs = feed(disk, stream, 0, events)
+    disk.flush_device_dispatch()
+    mem, mem_blocks = in_memory(case)
+    assert disk.ladder_rung() == mem.ladder_rung() == "live"
+    return SimpleNamespace(case=case, stream=stream, events=events, path=path,
+                           syncs=syncs, disk=disk, disk_blocks=disk_blocks,
+                           mem=mem, mem_blocks=mem_blocks)
+
+
+def test_orders_as_in_memory_and_as_the_reference(ran):
+    """(a)"""
+    assert ran.disk_blocks.bodies and ran.disk_blocks.bodies == ran.mem_blocks.bodies
+    got = stamps_of(ran.disk, ran.stream, ran.events)
+    assert got == stamps_of(ran.mem, ran.stream, ran.events)
+    stored = durable.read(ran.path)
+    assert stored.hexes == [ev.hex() for ev in ran.stream.signed]
+    assert stored.topo == list(range(ran.events))
+    assert stored.stamps.tolist() == [list(s) for s in got]
+    want = durable.order_stored(stored)
+    observed = (stored.stamps, [(b.index(), b.round_received(), b.transactions())
+                                for _, b in ran.disk_blocks.blocks])
+    assert replay.mismatches(observed, want) == {
+        "events_mismatched": 0, "blocks_mismatched": 0}
+    assert stored.blocks == observed[1]
+
+
+def test_a_block_is_on_disk_before_it_is_delivered(ran):
+    """(b)"""
+    assert ran.disk_blocks.blocks
+    assert ran.disk_blocks.unreadable == []
+
+
+def test_store_totals_and_transactions(ran):
+    """(e)"""
+    totals = ran.disk.hg.obs.tracer.totals()
+    for name in STORE_TOTALS:
+        count, seconds = totals[name]
+        assert count > 0, name
+        assert (seconds > 0) == (name != "store.bytes"), name
+    blocks = len(ran.disk_blocks.blocks)
+    assert totals["store.flush"][0] <= ran.syncs + blocks + 1
+    assert totals["store.set_event"][0] >= ran.events
+    assert totals["store.set_block_frame"][0] >= 2 * blocks
+    # rows of ~a hundred bytes a validator: the two coordinate vectors
+    assert totals["store.bytes"][0] > ran.events * 100 * CASES[ran.case][0]
+    # between two flushes nothing is handed over: the sums wait in the store
+    ran.disk.hg.store.set_round(0, ran.disk.hg.store.get_round(0))
+    assert ran.disk.hg.obs.tracer.totals()["store.set_round"] == totals["store.set_round"]
+    ran.disk.hg.store.flush()
+    assert (ran.disk.hg.obs.tracer.totals()["store.set_round"][0]
+            == totals["store.set_round"][0] + 1)
+
+
+def test_an_in_memory_core_records_no_store_total(ran):
+    """(f)"""
+    assert [k for k in ran.mem.hg.obs.tracer.totals() if k.startswith("store.")] == []
+
+
+def test_the_file_is_opened_durable(ran):
+    db = ran.disk.hg.store.db
+    assert db.execute("PRAGMA synchronous").fetchone()[0] == 2  # FULL
+    assert db.execute("PRAGMA journal_mode").fetchone()[0] == "wal"
+    assert not db.in_transaction  # the barrier flushed
+
+
+def test_release_patch_is_counted(tmp_path):
+    """The rows the coordinate table lets go (a small cache: the newest
+    hundred and the undetermined stay) are patched in the sync's own
+    transaction, and `store.release_patch` counts them."""
+    stream = stream_of("v16")
+    store = SQLiteStore(stream.peers, 100, str(tmp_path / "small.db"))
+    core = Core(0, stream.key, stream.peers, store)
+    syncs = feed(core, stream, 0, 2200)
+    totals = core.hg.obs.tracer.totals()
+    rows, seconds = totals["store.release_patch"]
+    assert rows == core.hg._coords.base > 0 and seconds > 0
+    # nobody is handed the blocks: a sync is one transaction, the patch in it
+    assert core.get_last_block_index() > 0
+    assert totals["store.flush"][0] == syncs
+    store.close()
+
+
+def restart(stream, path):
+    """A new process on the directory: the store, a Core, the bootstrap."""
+    blocks = Handover(stream, path)
+    store = SQLiteStore.load_or_create(stream.peers, CACHE, path)
+    assert store.need_bootstrap()
+    core = new_core(stream, store, blocks)
+    core.bootstrap()
+    return core, blocks
+
+
+def test_restart_at_a_sync_boundary(tmp_path):
+    """(c): stopped when `run_consensus` returned, before any barrier."""
+    stream, events = stream_of("v16"), 2000
+    path = str(tmp_path / "babble.db")
+    blocks = Handover(stream, path)
+    core = new_core(stream, SQLiteStore(stream.peers, CACHE, path), blocks)
+    feed(core, stream, 0, events)
+    known = core.known_events()
+    assert blocks.bodies
+    core.hg.store.db.close()  # the process dies: nothing flushes after it
+
+    again, found = restart(stream, path)
+    assert again.known_events() == known
+    assert [e.hex() for e in again.hg.store.db_topological_events()] == [
+        ev.hex() for ev in stream.signed[:events]]
+    # everything it had committed, byte for byte (and what its in-flight
+    # dispatches had not brought back yet)
+    assert found.bodies[:len(blocks.bodies)] == blocks.bodies
+    assert found.unreadable == []
+    again.hg.store.close()
+
+
+@pytest.mark.parametrize("where", ["inserts", "block"])
+def test_restart_inside_a_sync(tmp_path, where):
+    """(d)"""
+    stream, events = stream_of("v16"), CASES["v16"][1]
+    path = str(tmp_path / "babble.db")
+    whole = 1500  # events in whole syncs before the one that is cut
+    blocks = Handover(stream, path)
+    store = SQLiteStore(stream.peers, CACHE, path)
+    core = new_core(stream, store, blocks)
+    feed(core, stream, 0, whole)
+    if where == "inserts":
+        for signed in stream.signed[whole:whole + SYNC // 2]:
+            core.insert_event(stream.copy(signed), True)
+        store.db.close()
+    else:
+        blocks.store, blocks.stop_at = store, len(blocks.bodies) + 1
+        with pytest.raises(Stopped):
+            feed(core, stream, whole, events)
+    delivered = list(blocks.bodies)
+
+    stored = durable.read(path)
+    held = len(stored.hexes)
+    assert held >= whole and held % SYNC == 0  # whole syncs, the earlier ones all
+    assert stored.hexes == [ev.hex() for ev in stream.signed[:held]]
+    if where == "block":
+        # the block that was being handed over is there with its sync
+        assert len(stored.blocks) == len(delivered) and held > whole
+    last = max((gen.payload_event(tx) for _, _, txs in stored.blocks for tx in txs),
+               default=-1)
+    assert last < held  # no block without its events
+    assert set(rr for _, rr, _ in stored.blocks) <= set(stored.frames)
+
+    again, found = restart(stream, path)
+    assert found.bodies[:len(stored.blocks)] == delivered[:len(stored.blocks)]
+    feed(again, stream, held, events)
+    again.flush_device_dispatch()
+    assert again.ladder_rung() == "live"
+    assert found.bodies == in_memory("v16")[1].bodies
+    assert found.unreadable == []
+    again.hg.store.close()
