@@ -23,6 +23,13 @@ process that dies between two flushes restarts (`load_or_create`, then
 events. `close` flushes. The log is a WAL, so that a reader on a connection
 of its own (a tool, a second process) never finds the file locked, whatever
 the writer has open; docs/store.md has the reading the choice rests on.
+
+An event's row is written once, when the store first sees it. What changes
+after that goes elsewhere: its round, lamport and reception stamps (and the
+hashgraph's topological index, which a reset or a replay may renumber) to a
+narrow `stamps` row keyed by the row's `topo_index`, its final first
+descendants into the row's own field when the coordinate table lets them
+go. A read-back joins the two.
 """
 
 from __future__ import annotations
@@ -52,6 +59,13 @@ CREATE TABLE IF NOT EXISTS events (
 );
 CREATE UNIQUE INDEX IF NOT EXISTS events_topo ON events(topo_index);
 CREATE UNIQUE INDEX IF NOT EXISTS events_creator_idx ON events(creator, idx);
+CREATE TABLE IF NOT EXISTS stamps (
+    topo_index INTEGER PRIMARY KEY,
+    topo INTEGER,
+    round INTEGER,
+    lamport INTEGER,
+    round_received INTEGER
+);
 CREATE TABLE IF NOT EXISTS rounds (
     idx INTEGER PRIMARY KEY,
     data TEXT NOT NULL
@@ -83,13 +97,20 @@ PAGE_CACHE_KIB = 65536
 WAL_CHECKPOINT_PAGES = 16384
 
 # tracer totals (seconds; count), summed here and handed over at `flush`
-SET_EVENT = "store.set_event"  # rows written
+SET_EVENT = "store.set_event"  # rows written, whole and stamps
+STAMP = "store.stamp"  # no seconds; stamps rows written in place of a whole row
 SET_ROUND = "store.set_round"  # rows written
 SET_BLOCK_FRAME = "store.set_block_frame"  # rows written, blocks and frames
 RELEASE_PATCH = "store.release_patch"  # rows patched
 FLUSH = "store.flush"  # transactions committed
 BYTES = "store.bytes"  # no seconds; bytes of row data handed to SQLite
-TOTALS = (SET_EVENT, SET_ROUND, SET_BLOCK_FRAME, RELEASE_PATCH, FLUSH, BYTES)
+TOTALS = (SET_EVENT, STAMP, SET_ROUND, SET_BLOCK_FRAME, RELEASE_PATCH, FLUSH, BYTES)
+
+# an event seen before: its stamps under the row's topo_index, none written
+# where it has no row yet
+_PUT_STAMPS = ("INSERT OR REPLACE INTO stamps "
+               "SELECT topo_index, ?, ?, ?, ? FROM events WHERE hex = ?")
+STAMP_BYTES = 4 * 8  # store.bytes of a stamps row: four integers bound
 
 
 class SQLiteStore(Store):
@@ -215,10 +236,16 @@ class SQLiteStore(Store):
         try:
             return self.inmem.get_event(key)
         except StoreErr:
-            row = self.db.execute("SELECT data FROM events WHERE hex = ?", (key,)).fetchone()
+            row = self.db.execute(
+                "SELECT e.data, s.topo_index, s.topo, s.round, s.lamport, "
+                "s.round_received FROM events e LEFT JOIN stamps s "
+                "ON s.topo_index = e.topo_index WHERE e.hex = ?", (key,)).fetchone()
             if row is None:
                 raise StoreErr("SQLite.Events", StoreErrType.KEY_NOT_FOUND, key)
             event = Event.from_store_json(json.loads(row[0]))
+            if row[1] is not None:  # its stamps row, written after the row
+                (event.topological_index, event.round,
+                 event.lamport_timestamp, event.round_received) = row[2:]
             # its cells are the table's while the table holds its row, and
             # the row's own after that
             event.coordinates = self.coordinates
@@ -238,16 +265,25 @@ class SQLiteStore(Store):
             # cache, which registers nothing again (that would hit a rolled
             # participant window), and write it through
             self.inmem.event_cache.add(event.hex(), event)
-        nbytes = self._db_put_event(event)
+        if self.db.execute(_PUT_STAMPS, self._stamps(event) + (event.hex(),)).rowcount:
+            self._sums[STAMP][1] += 1
+            nbytes = STAMP_BYTES
+        else:
+            nbytes = self._db_put_event(event)
         self._note(SET_EVENT, t, 1, nbytes)
+
+    @staticmethod
+    def _stamps(event: Event) -> tuple:
+        return (event.topological_index, event.round, event.lamport_timestamp,
+                event.round_received)
 
     def keep_first_descendants(self, keys, cells_of) -> None:
         """Every one of them: the cached objects, and each event's row, so
         that an event read back after the graph released its cells still
-        has them all. The one rewrite of a row for its cells (the graph's
-        table is the truth while it holds them, and a restart rebuilds
-        it: `Hashgraph.bootstrap`): the one field patched in place, the
-        whole block in one statement."""
+        has them all. The one write of a row's cells (the graph's table is
+        the truth while it holds them, and a restart rebuilds it:
+        `Hashgraph.bootstrap`): the one field patched in place, the whole
+        block in one statement."""
         t = self._now()
         self.inmem.keep_first_descendants(keys, cells_of)
         rows = nbytes = 0
@@ -270,19 +306,28 @@ class SQLiteStore(Store):
         self._note(RELEASE_PATCH, t, rows, nbytes)
 
     def _db_put_event(self, event: Event) -> int:
-        """The event's row, under the topological index it was first
-        written with; returns the bytes of the row's data."""
-        row = self.db.execute(
-            "SELECT topo_index FROM events WHERE hex = ?", (event.hex(),)
-        ).fetchone()
-        topo = row[0] if row else self._topo_counter
-        if row is None:
-            self._topo_counter += 1
-        data = json.dumps(event.to_store_json())
+        """The event's one row, under the next topological index: body,
+        signature, wire info, `Topo` and `LastAncestors`, and no first
+        descendants (the graph's table holds them until the release patch
+        writes them); its stamps row beside it if a stamp is set already (an
+        event adopted from a fast-sync section). Returns the bytes written."""
+        topo = self._topo_counter
+        self._topo_counter += 1
+        d = event.to_json()
+        d["Meta"] = {"Topo": event.topological_index, "Round": None,
+                     "Lamport": None, "RoundReceived": None,
+                     "LastAncestors": event.last_ancestors,
+                     "FirstDescendants": None}
+        data = json.dumps(d)
         self.db.execute(
             "INSERT OR REPLACE INTO events VALUES (?, ?, ?, ?, ?)",
             (event.hex(), topo, event.creator(), event.index(), data),
         )
+        stamps = self._stamps(event)
+        if any(v is not None for v in stamps[1:]):
+            self.db.execute("INSERT OR REPLACE INTO stamps VALUES (?, ?, ?, ?, ?)",
+                            (topo,) + stamps)
+            return len(data) + STAMP_BYTES
         return len(data)
 
     def participant_events(self, participant: str, skip: int) -> List[str]:

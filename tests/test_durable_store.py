@@ -6,7 +6,8 @@ What is held (the letters are the issue's, PR 37):
 
 (a) blocks and every event's round, lamport timestamp and round received
     equal an in-memory Core's and the plain reference's over the file
-    (`benchmark/reference/durable.py`), and the rows on disk carry them;
+    (`benchmark/reference/durable.py`), and the file carries them in its
+    `stamps` table, joined to the rows on `topo_index`;
 (b) at every block hand-over a second, read-only connection reads the
     block, its frame and every event it orders;
 (c) a validator stopped at a sync boundary (the connection dropped, no
@@ -17,15 +18,24 @@ What is held (the letters are the issue's, PR 37):
     and the remaining events handed over again end in the same blocks;
 (e) the `store.*` totals are there and non-zero, and a run of k syncs that
     commit b blocks makes at most k + b + 1 transactions;
-(f) a Core on `InmemStore` records no `store.*` total.
+(f) a Core on `InmemStore` records no `store.*` total;
+(g) an event's row is written once (PR 38): k inserted events are k rows
+    written whole, every later `set_event` is a stamps row (`store.stamp`),
+    and an event read back from disk after its eviction carries the live
+    object's stamps and last ancestors, and its first descendants through
+    the table, then from its row once released.
 """
 
+import dataclasses
 import json
 from types import SimpleNamespace
 
+import numpy as np
 import pytest
 
+from babble_tpu.common import LRU
 from babble_tpu.hashgraph import InmemStore, SQLiteStore
+from babble_tpu.hashgraph.coordinates import MAX_INT32
 from babble_tpu.node import Core
 from benchmark import traffic as gen
 from benchmark.entries import replay
@@ -35,8 +45,9 @@ SYNC = 100
 CACHE = 50000
 CASES = {"v16": (16, 3000), "v64": (64, 4000)}  # validators, events
 TOPOLOGY_SEED, SEED, ZIPF_A = 1000000007, 7, 1.1
-STORE_TOTALS = ("store.set_event", "store.set_round", "store.set_block_frame",
-                "store.flush", "store.bytes")
+STORE_TOTALS = ("store.set_event", "store.stamp", "store.set_round",
+                "store.set_block_frame", "store.flush", "store.bytes")
+COUNTS_ONLY = ("store.stamp", "store.bytes")  # totals without seconds
 
 
 class Handover(replay.CommitStamps):
@@ -105,16 +116,31 @@ def feed(core, stream, lo, hi) -> int:
     return syncs
 
 
-def stamps_of(core, stream, upto) -> list:
-    def stamp(v):
-        return -1 if v is None else int(v)
+def stamp(v) -> int:
+    return -1 if v is None else int(v)
 
+
+def stamps_of(core, stream, upto) -> list:
     out = []
     for signed in stream.signed[:upto]:
         ev = core.hg.store.get_event(signed.hex())
         out.append((stamp(ev.round), stamp(ev.lamport_timestamp),
                     stamp(ev.round_received)))
     return out
+
+
+def stamps_on_disk(path) -> np.ndarray:
+    """(E, 3) round, lamport, round received of the file's events in
+    `topo_index` order, from the `stamps` table (-1 where unset)."""
+    db = durable.connect(path)
+    try:
+        rows = db.execute(
+            "SELECT s.round, s.lamport, s.round_received FROM events e "
+            "LEFT JOIN stamps s ON s.topo_index = e.topo_index "
+            "ORDER BY e.topo_index").fetchall()
+    finally:
+        db.close()
+    return np.array([[stamp(v) for v in row] for row in rows], np.int64)
 
 
 _STREAMS, _IN_MEMORY = {}, {}
@@ -165,6 +191,7 @@ def test_orders_as_in_memory_and_as_the_reference(ran):
     stored = durable.read(ran.path)
     assert stored.hexes == [ev.hex() for ev in ran.stream.signed]
     assert stored.topo == list(range(ran.events))
+    stored = dataclasses.replace(stored, stamps=stamps_on_disk(ran.path))
     assert stored.stamps.tolist() == [list(s) for s in got]
     want = durable.order_stored(stored)
     observed = (stored.stamps, [(b.index(), b.round_received(), b.transactions())
@@ -186,7 +213,7 @@ def test_store_totals_and_transactions(ran):
     for name in STORE_TOTALS:
         count, seconds = totals[name]
         assert count > 0, name
-        assert (seconds > 0) == (name != "store.bytes"), name
+        assert (seconds > 0) == (name not in COUNTS_ONLY), name
     blocks = len(ran.disk_blocks.blocks)
     assert totals["store.flush"][0] <= ran.syncs + blocks + 1
     assert totals["store.set_event"][0] >= ran.events
@@ -201,6 +228,24 @@ def test_store_totals_and_transactions(ran):
             == totals["store.set_round"][0] + 1)
 
 
+def test_a_row_is_written_once(ran):
+    """(g) k inserted events, k rows written whole: a REPLACE of a row
+    would give it a new rowid past the k-th, and nothing else did; every
+    other `set_event` wrote a stamps row, and `store.stamp` counts them."""
+    db = durable.connect(ran.path)
+    try:
+        assert db.execute("SELECT MAX(rowid), COUNT(*) FROM events").fetchone() == (
+            ran.events, ran.events)
+        stamp_rows = db.execute("SELECT COUNT(*) FROM stamps").fetchone()[0]
+    finally:
+        db.close()
+    totals = ran.disk.hg.obs.tracer.totals()
+    rows, stamps = totals["store.set_event"][0], totals["store.stamp"][0]
+    assert rows - stamps == ran.events
+    # a round stamp and a reception stamp for most events, one row each
+    assert 0 < stamp_rows <= ran.events < stamps <= 3 * ran.events
+
+
 def test_an_in_memory_core_records_no_store_total(ran):
     """(f)"""
     assert [k for k in ran.mem.hg.obs.tracer.totals() if k.startswith("store.")] == []
@@ -213,21 +258,64 @@ def test_the_file_is_opened_durable(ran):
     assert not db.in_transaction  # the barrier flushed
 
 
-def test_release_patch_is_counted(tmp_path):
-    """The rows the coordinate table lets go (a small cache: the newest
-    hundred and the undetermined stay) are patched in the sync's own
-    transaction, and `store.release_patch` counts them."""
+@pytest.fixture(scope="module")
+def small_cache(tmp_path_factory):
+    """The v16 stream on a store whose cache holds 100 events: the newest
+    hundred and the undetermined stay, the rest is read back from disk."""
     stream = stream_of("v16")
-    store = SQLiteStore(stream.peers, 100, str(tmp_path / "small.db"))
-    core = Core(0, stream.key, stream.peers, store)
-    syncs = feed(core, stream, 0, 2200)
+    store = SQLiteStore(stream.peers, 100,
+                        str(tmp_path_factory.mktemp("small") / "small.db"))
+    core = new_core(stream, store, None)
+    syncs = feed(core, stream, 0, CASES["v16"][1])
+    core.flush_device_dispatch()
+    yield SimpleNamespace(stream=stream, store=store, core=core, syncs=syncs)
+    store.close()
+
+
+def test_release_patch_is_counted(small_cache):
+    """The rows the coordinate table lets go are patched in the sync's own
+    transaction, and `store.release_patch` counts them."""
+    core = small_cache.core
     totals = core.hg.obs.tracer.totals()
     rows, seconds = totals["store.release_patch"]
     assert rows == core.hg._coords.base > 0 and seconds > 0
-    # nobody is handed the blocks: a sync is one transaction, the patch in it
+    # nobody is handed the blocks: a sync is one transaction, the patch in
+    # it (the barrier after the last found nothing open)
     assert core.get_last_block_index() > 0
-    assert totals["store.flush"][0] == syncs
-    store.close()
+    assert totals["store.flush"][0] == small_cache.syncs
+
+
+def test_an_evicted_event_reads_back_whole(small_cache):
+    """(g) Every event, evicted and read back from disk, carries the stamps
+    and last ancestors of the in-memory Core's object, and answers its
+    first descendants through the table while the table holds its row and
+    from the row's own cells after the release."""
+    store, stream = small_cache.store, small_cache.stream
+    mem = in_memory("v16")[0].hg.store
+    table = small_cache.core.hg._coords
+    store.inmem.event_cache = LRU(100)
+    live = released = received = 0
+    for signed in stream.signed:
+        back = store.get_event(signed.hex())
+        want = mem.get_event(signed.hex())
+        assert back is not want
+        assert ((back.round, back.lamport_timestamp, back.round_received)
+                == (want.round, want.lamport_timestamp, want.round_received))
+        assert back.topological_index == want.topological_index
+        assert back.last_ancestors == want.last_ancestors
+        received += back.round_received is not None
+        if table.slot_of(back) >= 0:
+            live += 1
+            assert back.first_descendants == want.first_descendants
+        else:
+            released += 1
+            data = store.db.execute("SELECT data FROM events WHERE hex = ?",
+                                    (signed.hex(),)).fetchone()[0]
+            cells = json.loads(data)["Meta"]["FirstDescendants"]
+            assert back.first_descendants == [tuple(c) for c in cells]
+            for cell, full in zip(back.first_descendants, want.first_descendants):
+                assert cell in (full, (MAX_INT32, ""))
+    assert live > 0 and released == table.base > 0 and received > 0
 
 
 def restart(stream, path):

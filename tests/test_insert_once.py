@@ -788,9 +788,10 @@ def on_disk_cells(store, key):
 def test_sqlite_store_reads_back_through_the_table(stream8, tmp_path):
     """A cache of 60 under 900 events: most ancestors an insert writes to
     were evicted. No insert rewrites an ancestor's row (a row is written
-    when its event is stored or stamped, and once more when the table
-    releases it); an evicted event read back answers through the table
-    while the table holds its row, and from its row's final cells after."""
+    whole once, when its event is stored; its stamps go to a row of their
+    own, and its cells once more when the table releases it); an evicted
+    event read back answers through the table while the table holds its
+    row, and from its row's final cells after."""
     peers = stream8.peers
     events = stream8.signed[:900]
     disk = on_disk(SQLiteStore, peers, 60, tmp_path / "new.db")
@@ -808,8 +809,8 @@ def test_sqlite_store_reads_back_through_the_table(stream8, tmp_path):
             ref.run_consensus()
     assert disk_fed == fed
     assert bodies(hg) == bodies(ref) and len(bodies(hg)) > 3
-    # a row an insert, and one for each of an event's three stamps at most
-    assert len(events) <= len(writes) <= 3 * len(events)
+    # one whole row an insert, in insertion order, and never another
+    assert writes == [e.hex() for e in events]
     assert [r[0] for r in db_rows(disk)] == [e.hex() for e in events]
     table = hg._coords
     assert 0 < table.base and len(disk.inmem.event_cache) <= 60
@@ -840,8 +841,9 @@ def test_sqlite_store_reads_back_through_the_table(stream8, tmp_path):
 def test_a_stored_event_written_again_keeps_its_row(stream8, tmp_path):
     """`set_event` of an event the store has registered (a stamp written
     back, by the object it handed out or by a copy read from disk):
-    InmemStore keeps the object it has, SQLiteStore refreshes cache and row
-    under the row's topological index, and neither registers it again."""
+    InmemStore keeps the object it has, SQLiteStore refreshes the cache and
+    the row's stamps under the row's topological index, leaves the row as
+    it was, and neither registers it again."""
     peers = stream8.peers
     mem = InmemStore(peers, 100)
     hg = Hashgraph(peers, mem)
@@ -859,8 +861,8 @@ def test_a_stored_event_written_again_keeps_its_row(stream8, tmp_path):
     for signed in stream8.signed[:20]:
         hd.insert_event(stream8.copy(signed), True)
     h = stream8.signed[3].hex()
-    rows = len(db_rows(disk))
-    topo = dict((r[0], r[1]) for r in db_rows(disk))[h]
+    rows = db_rows(disk)
+    topo = dict((r[0], r[1]) for r in rows)[h]
     disk.inmem.event_cache = LRU(100)
     ev = disk.get_event(h)  # a copy read from disk
     ev.set_round(7)
@@ -869,12 +871,14 @@ def test_a_stored_event_written_again_keeps_its_row(stream8, tmp_path):
     disk.inmem.event_cache = LRU(100)
     back = disk.get_event(h)
     assert back.round == 7
-    # the copy's cells, persisted with it, were the table's
-    assert on_disk_cells(disk, h) == back.first_descendants
+    assert disk.db.execute("SELECT round FROM stamps WHERE topo_index = ?",
+                           (topo,)).fetchone() == (7,)
+    # the row carries no cells: the table answers while it holds them
+    data = dict((r[0], r[4]) for r in rows)[h]
+    assert json.loads(data)["Meta"]["FirstDescendants"] is None
     assert back.coordinates is hd._coords
     assert back.first_descendants == hd._coords.cells(3)
-    assert len(db_rows(disk)) == rows
-    assert dict((r[0], r[1]) for r in db_rows(disk))[h] == topo
+    assert db_rows(disk) == rows
     assert disk.known_events() == known
     disk.close()
 
@@ -918,6 +922,39 @@ def test_sqlite_event_from_before_a_reset_keeps_its_own_cells(tmp_path):
             [c[0] for c in final[signed.hex()]]]
         strangers += 1
     assert strangers > 400 and lookalikes > 200
+    disk.close()
+
+
+def test_sqlite_event_stored_again_after_a_reset_reads_back_renumbered(tmp_path):
+    """The frame a reset inserts can hold events whose rows are on disk
+    already: their rows stay as written, and their stamps row carries the
+    topological index the new table gave them, so that each one, evicted
+    and read back, answers through the new table with the live stamps."""
+    stream = make_stream(4, "honest")
+    peers = stream.peers
+    block, frame = anchor_of(stream, 600)
+    disk = on_disk(SQLiteStore, peers, 50000, tmp_path / "r.db")
+    hg = Hashgraph(peers, disk)
+    for signed in stream.signed[:600]:
+        hg.insert_event(stream.copy(signed), True)
+    rows = db_rows(disk)
+    hg.reset(Block.from_json(block.to_json()), Frame.from_json(frame.to_json()))
+    # every frame event was stored before: no row was added or moved (the
+    # release patch wrote the old table's cells into them)
+    assert [r[:4] for r in db_rows(disk)] == [r[:4] for r in rows]
+    live = {ev.hex(): disk.get_event(ev.hex()) for ev in frame.events}
+    on_row = {r[0]: json.loads(r[4])["Meta"]["Topo"] for r in rows}
+    disk.inmem.event_cache = LRU(50000)
+    table = hg._coords
+    renumbered = 0
+    for key, ev in live.items():
+        back = disk.get_event(key)
+        assert back is not ev and table.slot_of(back) >= 0
+        renumbered += back.topological_index != on_row[key]
+        assert back.first_descendants == ev.first_descendants
+        assert ((back.round, back.lamport_timestamp, back.round_received)
+                == (ev.round, ev.lamport_timestamp, ev.round_received))
+    assert len(live) > 4 and renumbered == len(live)
     disk.close()
 
 
