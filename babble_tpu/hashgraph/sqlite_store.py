@@ -30,6 +30,14 @@ hashgraph's topological index, which a reset or a replay may renumber) to a
 narrow `stamps` row keyed by the row's `topo_index`, its final first
 descendants into the row's own field when the coordinate table lets them
 go. A read-back joins the two.
+
+The row keeps an event's last ancestors as chain c's index alone, not the
+`[index, hash]` pair the object holds (~5 bytes a chain, not ~74): the hash
+is the `hex` of chain c's row at that index (`events_creator_idx`), and a
+read-back finds it there again. Where the table may not hold it, at or below
+the root a reset left on that chain (a fast-sync section's donor names
+events this file never saw), the cell stays a pair; a file written before
+this form, whose every cell is a pair, reads back as it did.
 """
 
 from __future__ import annotations
@@ -104,7 +112,9 @@ SET_BLOCK_FRAME = "store.set_block_frame"  # rows written, blocks and frames
 RELEASE_PATCH = "store.release_patch"  # rows patched
 FLUSH = "store.flush"  # transactions committed
 BYTES = "store.bytes"  # no seconds; bytes of row data handed to SQLite
-TOTALS = (SET_EVENT, STAMP, SET_ROUND, SET_BLOCK_FRAME, RELEASE_PATCH, FLUSH, BYTES)
+READ_BACK = "store.read_back"  # events `get_event` read back from disk
+TOTALS = (SET_EVENT, STAMP, SET_ROUND, SET_BLOCK_FRAME, RELEASE_PATCH, FLUSH,
+          BYTES, READ_BACK)
 
 # an event seen before: its stamps under the row's topo_index, none written
 # where it has no row yet
@@ -152,6 +162,7 @@ class SQLiteStore(Store):
             self.db.commit()
 
         self._topo_counter = self._db_max_topo() + 1
+        self._set_chain_roots()
 
     # -- factory -----------------------------------------------------------
 
@@ -236,20 +247,66 @@ class SQLiteStore(Store):
         try:
             return self.inmem.get_event(key)
         except StoreErr:
+            t = self._now()
             row = self.db.execute(
                 "SELECT e.data, s.topo_index, s.topo, s.round, s.lamport, "
                 "s.round_received FROM events e LEFT JOIN stamps s "
                 "ON s.topo_index = e.topo_index WHERE e.hex = ?", (key,)).fetchone()
             if row is None:
                 raise StoreErr("SQLite.Events", StoreErrType.KEY_NOT_FOUND, key)
-            event = Event.from_store_json(json.loads(row[0]))
+            d = json.loads(row[0])
+            # the row's cells are resolved here, not by from_store_json,
+            # which takes every cell for a pair
+            meta = d["Meta"]
+            cells, meta["LastAncestors"] = meta["LastAncestors"], None
+            event = Event.from_store_json(d)
+            if cells is not None:
+                event.last_ancestors = self._last_ancestors(cells)
             if row[1] is not None:  # its stamps row, written after the row
                 (event.topological_index, event.round,
                  event.lamport_timestamp, event.round_received) = row[2:]
             # its cells are the table's while the table holds its row, and
             # the row's own after that
             event.coordinates = self.coordinates
+            self._note(READ_BACK, t, 1)
             return event
+
+    def _last_ancestors(self, cells: list) -> List[Tuple[int, str]]:
+        """The `(index, hash)` pairs a row's last-ancestor cells stand for:
+        a pair as written, -1 as `(-1, "")`, chain c's index as the hash its
+        chain holds there, from the participant index (a root's included)
+        and else from the table, all of a read-back's misses in one
+        statement. A cell that neither holds is KEY_NOT_FOUND."""
+        creators = self.inmem.participants().to_pub_key_slice()
+        out: list = []
+        missing = []
+        for c, cell in enumerate(cells):
+            if isinstance(cell, list):
+                out.append(tuple(cell))
+            elif cell < 0:
+                out.append((-1, ""))
+            else:
+                try:
+                    out.append((cell, self.inmem.participant_event(creators[c], cell)))
+                except StoreErr:
+                    out.append(None)
+                    missing.append(c)
+        if missing:
+            # the cells drive the join, so each is one search of
+            # events_creator_idx (an IN over row values scans the table)
+            pairs = ", ".join(["(?, ?)"] * len(missing))
+            found = {(creator, index): key for creator, index, key in self.db.execute(
+                f"WITH cell(creator, idx) AS (VALUES {pairs}) "
+                "SELECT e.creator, e.idx, e.hex FROM cell CROSS JOIN events e "
+                "ON e.creator = cell.creator AND e.idx = cell.idx",
+                [v for c in missing for v in (creators[c], cells[c])])}
+            for c in missing:
+                key = found.get((creators[c], cells[c]))
+                if key is None:
+                    raise StoreErr("SQLite.Events", StoreErrType.KEY_NOT_FOUND,
+                                   f"{creators[c]}:{cells[c]}")
+                out[c] = (cells[c], key)
+        return out
 
     def set_event(self, event: Event) -> None:
         t = self._now()
@@ -307,17 +364,21 @@ class SQLiteStore(Store):
 
     def _db_put_event(self, event: Event) -> int:
         """The event's one row, under the next topological index: body,
-        signature, wire info, `Topo` and `LastAncestors`, and no first
-        descendants (the graph's table holds them until the release patch
-        writes them); its stamps row beside it if a stamp is set already (an
-        event adopted from a fast-sync section). Returns the bytes written."""
+        signature, wire info, `Topo` and `LastAncestors` (an index a chain,
+        a pair at or below the chain's root), and no first descendants (the
+        graph's table holds them until the release patch writes them); its
+        stamps row beside it if a stamp is set already (an event adopted
+        from a fast-sync section). Returns the bytes written."""
         topo = self._topo_counter
         self._topo_counter += 1
         d = event.to_json()
+        cells = event.last_ancestors
+        if cells is not None:
+            cells = [k if k > floor or k < 0 else [k, h]
+                     for (k, h), floor in zip(cells, self._chain_roots)]
         d["Meta"] = {"Topo": event.topological_index, "Round": None,
                      "Lamport": None, "RoundReceived": None,
-                     "LastAncestors": event.last_ancestors,
-                     "FirstDescendants": None}
+                     "LastAncestors": cells, "FirstDescendants": None}
         data = json.dumps(d)
         self.db.execute(
             "INSERT OR REPLACE INTO events VALUES (?, ?, ?, ?, ?)",
@@ -451,6 +512,15 @@ class SQLiteStore(Store):
         self.inmem.reset(roots)
         for pk, root in roots.items():
             self._db_set_root(pk, root)
+        self._set_chain_roots()
+
+    def _set_chain_roots(self) -> None:
+        """Each chain's root index. Above it, every index an event names as
+        a last ancestor is a row of this file: the chain's events there are
+        the frame's and those stored after it. At or below it, maybe not."""
+        roots = self.inmem.roots_by_participant
+        self._chain_roots = [roots[pk].self_parent.index
+                             for pk in self.inmem.participants().to_pub_key_slice()]
 
     def close(self) -> None:
         """Flush, then close; closing a closed store is nothing, as with

@@ -33,8 +33,8 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
-from babble_tpu.common import LRU
-from babble_tpu.hashgraph import InmemStore, SQLiteStore
+from babble_tpu.common import LRU, StoreErr, StoreErrType
+from babble_tpu.hashgraph import Block, Frame, Hashgraph, InmemStore, SQLiteStore
 from babble_tpu.hashgraph.coordinates import MAX_INT32
 from babble_tpu.node import Core
 from benchmark import traffic as gen
@@ -47,6 +47,7 @@ CASES = {"v16": (16, 3000), "v64": (64, 4000)}  # validators, events
 TOPOLOGY_SEED, SEED, ZIPF_A = 1000000007, 7, 1.1
 STORE_TOTALS = ("store.set_event", "store.stamp", "store.set_round",
                 "store.set_block_frame", "store.flush", "store.bytes")
+ROW_BYTES = 1500  # an event's row at 64 validators, at most
 COUNTS_ONLY = ("store.stamp", "store.bytes")  # totals without seconds
 
 
@@ -218,8 +219,9 @@ def test_store_totals_and_transactions(ran):
     assert totals["store.flush"][0] <= ran.syncs + blocks + 1
     assert totals["store.set_event"][0] >= ran.events
     assert totals["store.set_block_frame"][0] >= 2 * blocks
-    # rows of ~a hundred bytes a validator: the two coordinate vectors
-    assert totals["store.bytes"][0] > ran.events * 100 * CASES[ran.case][0]
+    # rows of tens of bytes a validator: an index a chain in the event's
+    # row, the round rows' entries
+    assert totals["store.bytes"][0] > ran.events * 20 * CASES[ran.case][0]
     # between two flushes nothing is handed over: the sums wait in the store
     ran.disk.hg.store.set_round(0, ran.disk.hg.store.get_round(0))
     assert ran.disk.hg.obs.tracer.totals()["store.set_round"] == totals["store.set_round"]
@@ -390,3 +392,171 @@ def test_restart_inside_a_sync(tmp_path, where):
     assert found.bodies == in_memory("v16")[1].bodies
     assert found.unreadable == []
     again.hg.store.close()
+
+
+def test_a_row_keeps_its_last_ancestors_as_indices(ran):
+    """(h) One index a chain and no hash: a row under ROW_BYTES at 64
+    validators, where the `[index, hash]` pairs made it ~5.4 KB."""
+    db = durable.connect(ran.path)
+    try:
+        rows = [data for data, in db.execute("SELECT data FROM events")]
+    finally:
+        db.close()
+    assert len(rows) == ran.events
+    for data in rows:
+        cells = json.loads(data)["Meta"]["LastAncestors"]
+        assert len(cells) == CASES[ran.case][0]
+        assert all(type(cell) is int for cell in cells)
+        assert len(data) < ROW_BYTES
+
+
+class PairRows(SQLiteStore):
+    """An event's row in the old form: every last ancestor a pair."""
+
+    def _db_put_event(self, event):
+        topo = self._topo_counter
+        self._topo_counter += 1
+        d = event.to_json()
+        d["Meta"] = {"Topo": event.topological_index, "Round": None,
+                     "Lamport": None, "RoundReceived": None,
+                     "LastAncestors": event.last_ancestors,
+                     "FirstDescendants": None}
+        data = json.dumps(d)
+        self.db.execute("INSERT OR REPLACE INTO events VALUES (?, ?, ?, ?, ?)",
+                        (event.hex(), topo, event.creator(), event.index(), data))
+        return len(data)
+
+
+def insert(hg, stream, lo, hi) -> None:
+    """Events [lo, hi) into a host-engine graph, consensus every SYNC; an
+    event the graph refuses (one it has, one below its frame) is passed."""
+    for i, signed in enumerate(stream.signed[lo:hi], lo):
+        try:
+            hg.insert_event(stream.copy(signed), True)
+        except (ValueError, StoreErr):
+            pass
+        if i % SYNC == SYNC - 1:
+            hg.run_consensus()
+
+
+def cells_on_disk(store) -> list:
+    return [json.loads(data)["Meta"]["LastAncestors"]
+            for data, in store.db.execute("SELECT data FROM events ORDER BY topo_index")]
+
+
+def read_back(store, want) -> int:
+    """Empty the cache, read every event of `want` ({hash: last ancestors})
+    back from disk and hold its last ancestors; returns `store.read_back`'s
+    count over the reads."""
+    store.flush()
+    before = store.tracer.totals().get("store.read_back", (0, 0.0))[0]
+    store.inmem.event_cache = LRU(store.cache_size())
+    for key, ancestors in want.items():
+        assert store.get_event(key).last_ancestors == ancestors
+    store.flush()
+    return store.tracer.totals()["store.read_back"][0] - before
+
+
+def live_ancestors(store) -> dict:
+    return {key: list(store.get_event(key).last_ancestors)
+            for key in store.inmem.event_cache.keys()}
+
+
+def anchor(stream, upto, back):
+    """A donor's graph over the first `upto` events: (donor, block, frame)
+    of the block `back` before its newest."""
+    donor = Hashgraph(stream.peers, InmemStore(stream.peers, CACHE))
+    insert(donor, stream, 0, upto)
+    block = donor.store.get_block(donor.store.last_block_index() - back)
+    return donor, block, donor.get_frame(block.round_received())
+
+
+def old_form(stream, path):
+    """A file in the old form: every cell a pair, read back as written."""
+    store = PairRows(stream.peers, CACHE, path)
+    insert(Hashgraph(stream.peers, store), stream, 0, 1000)
+    assert all(type(c) is list for cells in cells_on_disk(store) for c in cells)
+    return store, live_ancestors(store)
+
+
+def after_a_reset(stream, path):
+    """Rows from before a reset read their hashes back from the table (the
+    participant index starts again at the frame); rows written after it
+    carry a pair where a cell lies at or below its chain's root (the
+    frame's events found their self-parents' rows on disk), an index
+    elsewhere."""
+    store = SQLiteStore(stream.peers, CACHE, path)
+    hg = Hashgraph(stream.peers, store)
+    insert(hg, stream, 0, 1000)
+    before = live_ancestors(store)
+    _, block, frame = anchor(stream, 1600, 0)
+    hg.reset(Block.from_json(block.to_json()), Frame.from_json(frame.to_json()))
+    insert(hg, stream, 1000, 2000)
+    after = [c for cells in cells_on_disk(store)[1000:] for c in cells]
+    assert sum(type(c) is list for c in after) > 0
+    assert sum(type(c) is int for c in after) > len(after) // 2
+    # a row holds what it was written with: the frame's events stored
+    # before the reset keep their first last ancestors on disk
+    return store, {**live_ancestors(store), **before}
+
+
+def after_a_section(stream, path):
+    """A joiner's empty file after a fast-sync section, whose donor names
+    last ancestors below the frame that the file never holds: those cells
+    stay pairs (as indices they would be KEY_NOT_FOUND)."""
+    from babble_tpu.hashgraph.section import Section
+
+    donor, block, frame = anchor(stream, 1600, 2)
+    shipped = Section.from_json(json.loads(json.dumps(
+        donor.get_section(frame.round).to_json())))
+    store = SQLiteStore(stream.peers, CACHE, path)
+    hg = Hashgraph(stream.peers, store)
+    hg.reset(Block.from_json(block.to_json()), Frame.from_json(frame.to_json()))
+    hg.apply_section(shipped)
+    insert(hg, stream, 1600, 2000)
+    held = set(store.db.execute("SELECT creator, idx FROM events"))
+    creators = stream.peers.to_pub_key_slice()
+    assert any(type(cell) is list and (creators[c], cell[0]) not in held
+               for cells in cells_on_disk(store) for c, cell in enumerate(cells))
+    return store, live_ancestors(store)
+
+
+def after_a_restart(stream, path):
+    """A slim file, started again, re-derives the blocks it had committed
+    and reads back as the live objects of the process that wrote it."""
+    blocks = Handover(stream, path)
+    core = new_core(stream, SQLiteStore(stream.peers, CACHE, path), blocks)
+    feed(core, stream, 0, 1000)
+    live = live_ancestors(core.hg.store)
+    core.hg.store.db.close()  # the process dies: nothing flushes after it
+    again, found = restart(stream, path)
+    assert blocks.bodies and found.bodies[:len(blocks.bodies)] == blocks.bodies
+    return again.hg.store, live
+
+
+@pytest.mark.parametrize("written", [old_form, after_a_reset, after_a_section,
+                                     after_a_restart],
+                         ids=["pairs", "reset", "section", "restart"])
+def test_an_evicted_event_reads_back_its_last_ancestors(tmp_path, written):
+    """(h) Every event of the file, its cache emptied, reads back with the
+    last ancestors it was written with, and `store.read_back` counts each."""
+    store, want = written(stream_of("v16"), str(tmp_path / "babble.db"))
+    assert len(want) > 500
+    assert read_back(store, want) == len(want)
+    store.close()
+
+
+def test_an_index_no_row_holds_is_not_found(tmp_path):
+    """(h) A read-back guesses no hash: a cell whose index neither the
+    participant index nor the table holds is KEY_NOT_FOUND."""
+    stream = stream_of("v16")
+    store = SQLiteStore(stream.peers, CACHE, str(tmp_path / "babble.db"))
+    insert(Hashgraph(stream.peers, store), stream, 0, 300)
+    key = stream.signed[299].hex()
+    store.db.execute("UPDATE events SET data = json_set(data, "
+                     "'$.Meta.LastAncestors[0]', 1000000) WHERE hex = ?", (key,))
+    store.inmem.event_cache = LRU(CACHE)
+    with pytest.raises(StoreErr) as err:
+        store.get_event(key)
+    assert err.value.err_type == StoreErrType.KEY_NOT_FOUND
+    store.close()
