@@ -33,18 +33,28 @@ go. A read-back joins the two.
 
 The row keeps an event's last ancestors as chain c's index alone, not the
 `[index, hash]` pair the object holds (~5 bytes a chain, not ~74): the hash
-is the `hex` of chain c's row at that index (`events_creator_idx`), and a
+is the `hex` of chain c's row at that index (`events_chain_idx`), and a
 read-back finds it there again. Where the table may not hold it, at or below
 the root a reset left on that chain (a fast-sync section's donor names
 events this file never saw), the cell stays a pair; a file written before
 this form, whose every cell is a pair, reads back as it did.
+
+Every key the `events` table is searched or ordered by is a small integer,
+so that a sync's new rows touch few index pages: a row is stored under its
+`topo_index` (the rowid: rows are appended in that order), found by its hash
+through `hkey` (the hash's first 8 bytes; `hex` beside it settles a prefix
+shared by two hashes) and by its creator's chain through `chain` (the
+creator's `Peer.id`). No index holds `hex` or `creator`. A file written with
+the text keys is rebuilt in this layout when it is opened.
 """
 
 from __future__ import annotations
 
 import json
+import mmap
 import os
 import sqlite3
+import struct
 from typing import Dict, List, Tuple
 
 from ..common import StoreErr, StoreErrType, is_store_err
@@ -57,16 +67,18 @@ from .root import Root
 from .round_info import RoundInfo
 from .store import Store
 
-_SCHEMA = """
-CREATE TABLE IF NOT EXISTS events (
-    hex TEXT PRIMARY KEY,
-    topo_index INTEGER NOT NULL,
+_EVENTS = """CREATE TABLE IF NOT EXISTS events (
+    topo_index INTEGER PRIMARY KEY,
+    hex TEXT NOT NULL,
     creator TEXT NOT NULL,
     idx INTEGER NOT NULL,
-    data TEXT NOT NULL
-);
-CREATE UNIQUE INDEX IF NOT EXISTS events_topo ON events(topo_index);
-CREATE UNIQUE INDEX IF NOT EXISTS events_creator_idx ON events(creator, idx);
+    data TEXT NOT NULL,
+    hkey INTEGER NOT NULL,
+    chain INTEGER NOT NULL
+)"""
+_SCHEMA = _EVENTS + """;
+CREATE INDEX IF NOT EXISTS events_hkey ON events(hkey);
+CREATE UNIQUE INDEX IF NOT EXISTS events_chain_idx ON events(chain, idx);
 CREATE TABLE IF NOT EXISTS stamps (
     topo_index INTEGER PRIMARY KEY,
     topo INTEGER,
@@ -113,14 +125,33 @@ RELEASE_PATCH = "store.release_patch"  # rows patched
 FLUSH = "store.flush"  # transactions committed
 BYTES = "store.bytes"  # no seconds; bytes of row data handed to SQLite
 READ_BACK = "store.read_back"  # events `get_event` read back from disk
+PAGES = "store.pages"  # no seconds; log frames (pages) the commits appended
 TOTALS = (SET_EVENT, STAMP, SET_ROUND, SET_BLOCK_FRAME, RELEASE_PATCH, FLUSH,
-          BYTES, READ_BACK)
+          BYTES, READ_BACK, PAGES)
 
 # an event seen before: its stamps under the row's topo_index, none written
 # where it has no row yet
 _PUT_STAMPS = ("INSERT OR REPLACE INTO stamps "
-               "SELECT topo_index, ?, ?, ?, ? FROM events WHERE hex = ?")
+               "SELECT topo_index, ?, ?, ?, ? FROM events "
+               "WHERE hkey = ? AND hex = ?")
 STAMP_BYTES = 4 * 8  # store.bytes of a stamps row: four integers bound
+# the WAL index's header (the `-shm` file): `mxFrame`, the frames in the
+# log, is a native u32 at byte 16 (sqlite.org/walformat.html)
+_WAL_INDEX_HEADER, _MX_FRAME = 48, 16
+
+
+def _hkey(key: str) -> int:
+    """The key of `events_hkey`: the hash's first 8 bytes as a signed
+    64-bit integer (0 for a key that is no hash, which no row holds)."""
+    try:
+        return int.from_bytes(bytes.fromhex(key[2:18]), "big", signed=True)
+    except ValueError:
+        return 0
+
+
+def _chain(creator: str) -> int:
+    """The key of `events_chain_idx`: the creator's `Peer.id`."""
+    return Peer(pub_key_hex=creator).id
 
 
 class SQLiteStore(Store):
@@ -133,11 +164,13 @@ class SQLiteStore(Store):
         # access is serialized by the node's core_lock, so sharing the
         # connection across the node's worker threads is safe
         self.db = sqlite3.connect(path, check_same_thread=False)
-        self.db.execute(f"PRAGMA journal_mode={JOURNAL_MODE}")
+        journal = self.db.execute(f"PRAGMA journal_mode={JOURNAL_MODE}").fetchone()[0]
         self.db.execute("PRAGMA synchronous=FULL")
         self.db.execute(f"PRAGMA cache_size=-{PAGE_CACHE_KIB}")
         self.db.execute(f"PRAGMA wal_autocheckpoint={WAL_CHECKPOINT_PAGES}")
+        self._rebuild_events()
         self.db.executescript(_SCHEMA)
+        self._wal_index = self._map_wal_index() if journal == "wal" else None
         self._sums = {total: [0.0, 0] for total in TOTALS}  # [seconds, count]
 
         if existing_db:
@@ -172,6 +205,40 @@ class SQLiteStore(Store):
             return cls(participants, cache_size, path, existing_db=True)
         return cls(participants, cache_size, path, existing_db=False)
 
+    def _rebuild_events(self) -> None:
+        """A file written with the text keys (its `events` has no `hkey`):
+        the table rebuilt in this layout in one transaction, every row
+        under the `topo_index` it had (its stamps row still joins), and the
+        old table dropped with its indexes."""
+        columns = [row[1] for row in self.db.execute("PRAGMA table_info(events)")]
+        if not columns or "hkey" in columns:
+            return
+        self.db.create_function("hkey", 1, _hkey, deterministic=True)
+        self.db.create_function("chain", 1, _chain, deterministic=True)
+        self.db.execute("BEGIN")
+        self.db.execute("ALTER TABLE events RENAME TO text_keyed_events")
+        self.db.execute(_EVENTS)
+        self.db.execute(
+            "INSERT INTO events SELECT topo_index, hex, creator, idx, data, "
+            "hkey(hex), chain(creator) FROM text_keyed_events ORDER BY topo_index")
+        self.db.execute("DROP TABLE text_keyed_events")
+        self.db.commit()
+
+    def _map_wal_index(self):
+        """The WAL index's header mapped read-only, for `store.pages` (a map
+        shares the pages SQLite writes through its own, where a read of the
+        file may not see them yet): (file, map), both kept open while the
+        connection is, since closing a descriptor of the file drops every
+        lock the process holds on it, SQLite's too."""
+        shm = open(self._path + "-shm", "rb", buffering=0)
+        return shm, mmap.mmap(shm.fileno(), _WAL_INDEX_HEADER, access=mmap.ACCESS_READ)
+
+    def _log_frames(self) -> int:
+        """Frames in the log (`mxFrame`); 0 where the log is no WAL."""
+        if self._wal_index is None:
+            return 0
+        return struct.unpack_from("=I", self._wal_index[1], _MX_FRAME)[0]
+
     # -- the flush boundary and the totals ---------------------------------
 
     def _now(self) -> float:
@@ -190,8 +257,13 @@ class SQLiteStore(Store):
         since the last flush to the tracer."""
         if self.db.in_transaction:
             t = self._now()
+            before = self._log_frames()
             self.db.commit()
             self._note(FLUSH, t, 1)
+            # the frames this commit appended; a log that restarted at it
+            # holds this commit's frames alone
+            after = self._log_frames()
+            self._sums[PAGES][1] += after - before if after >= before else after
         tracer = self.tracer
         if tracer is not None:
             for total, got in self._sums.items():
@@ -251,7 +323,8 @@ class SQLiteStore(Store):
             row = self.db.execute(
                 "SELECT e.data, s.topo_index, s.topo, s.round, s.lamport, "
                 "s.round_received FROM events e LEFT JOIN stamps s "
-                "ON s.topo_index = e.topo_index WHERE e.hex = ?", (key,)).fetchone()
+                "ON s.topo_index = e.topo_index WHERE e.hkey = ? AND e.hex = ?",
+                (_hkey(key), key)).fetchone()
             if row is None:
                 raise StoreErr("SQLite.Events", StoreErrType.KEY_NOT_FOUND, key)
             d = json.loads(row[0])
@@ -277,7 +350,8 @@ class SQLiteStore(Store):
         chain holds there, from the participant index (a root's included)
         and else from the table, all of a read-back's misses in one
         statement. A cell that neither holds is KEY_NOT_FOUND."""
-        creators = self.inmem.participants().to_pub_key_slice()
+        peers = self.inmem.participants().to_peer_slice()
+        creators = [p.pub_key_hex for p in peers]
         out: list = []
         missing = []
         for c, cell in enumerate(cells):
@@ -293,15 +367,15 @@ class SQLiteStore(Store):
                     missing.append(c)
         if missing:
             # the cells drive the join, so each is one search of
-            # events_creator_idx (an IN over row values scans the table)
+            # events_chain_idx (an IN over row values scans the table)
             pairs = ", ".join(["(?, ?)"] * len(missing))
-            found = {(creator, index): key for creator, index, key in self.db.execute(
-                f"WITH cell(creator, idx) AS (VALUES {pairs}) "
-                "SELECT e.creator, e.idx, e.hex FROM cell CROSS JOIN events e "
-                "ON e.creator = cell.creator AND e.idx = cell.idx",
-                [v for c in missing for v in (creators[c], cells[c])])}
+            found = {(chain, index): key for chain, index, key in self.db.execute(
+                f"WITH cell(chain, idx) AS (VALUES {pairs}) "
+                "SELECT e.chain, e.idx, e.hex FROM cell CROSS JOIN events e "
+                "ON e.chain = cell.chain AND e.idx = cell.idx",
+                [v for c in missing for v in (peers[c].id, cells[c])])}
             for c in missing:
-                key = found.get((creators[c], cells[c]))
+                key = found.get((peers[c].id, cells[c]))
                 if key is None:
                     raise StoreErr("SQLite.Events", StoreErrType.KEY_NOT_FOUND,
                                    f"{creators[c]}:{cells[c]}")
@@ -310,6 +384,7 @@ class SQLiteStore(Store):
 
     def set_event(self, event: Event) -> None:
         t = self._now()
+        key = event.hex()
         peer = self.inmem.participants().by_pub_key[event.creator()]
         last_known = self.inmem.participant_events_cache.known().get(peer.id, -1)
         if event.index() > last_known:
@@ -321,8 +396,8 @@ class SQLiteStore(Store):
             # so the object may be a copy read from disk): put it in the
             # cache, which registers nothing again (that would hit a rolled
             # participant window), and write it through
-            self.inmem.event_cache.add(event.hex(), event)
-        if self.db.execute(_PUT_STAMPS, self._stamps(event) + (event.hex(),)).rowcount:
+            self.inmem.event_cache.add(key, event)
+        if self.db.execute(_PUT_STAMPS, self._stamps(event) + (_hkey(key), key)).rowcount:
             self._sums[STAMP][1] += 1
             nbytes = STAMP_BYTES
         else:
@@ -352,23 +427,24 @@ class SQLiteStore(Store):
                     cells = json.dumps(cells_of(k))
                     rows += 1
                     nbytes += len(cells)
-                    yield cells, key
+                    yield cells, _hkey(key), key
 
         self.db.executemany(
             "UPDATE events SET data = "
             "json_set(data, '$.Meta.FirstDescendants', json(?)) "
-            "WHERE hex = ?",
+            "WHERE hkey = ? AND hex = ?",
             patches(),
         )
         self._note(RELEASE_PATCH, t, rows, nbytes)
 
     def _db_put_event(self, event: Event) -> int:
-        """The event's one row, under the next topological index: body,
-        signature, wire info, `Topo` and `LastAncestors` (an index a chain,
-        a pair at or below the chain's root), and no first descendants (the
-        graph's table holds them until the release patch writes them); its
-        stamps row beside it if a stamp is set already (an event adopted
-        from a fast-sync section). Returns the bytes written."""
+        """The event's one row, under the next topological index and its two
+        integer keys: body, signature, wire info, `Topo` and `LastAncestors`
+        (an index a chain, a pair at or below the chain's root), and no
+        first descendants (the graph's table holds them until the release
+        patch writes them); its stamps row beside it if a stamp is set
+        already (an event adopted from a fast-sync section). Returns the
+        bytes written."""
         topo = self._topo_counter
         self._topo_counter += 1
         d = event.to_json()
@@ -380,9 +456,11 @@ class SQLiteStore(Store):
                      "Lamport": None, "RoundReceived": None,
                      "LastAncestors": cells, "FirstDescendants": None}
         data = json.dumps(d)
+        key, creator = event.hex(), event.creator()
         self.db.execute(
-            "INSERT OR REPLACE INTO events VALUES (?, ?, ?, ?, ?)",
-            (event.hex(), topo, event.creator(), event.index(), data),
+            "INSERT INTO events VALUES (?, ?, ?, ?, ?, ?, ?)",
+            (topo, key, creator, event.index(), data, _hkey(key),
+             self.inmem.participants().by_pub_key[creator].id),
         )
         stamps = self._stamps(event)
         if any(v is not None for v in stamps[1:]):
@@ -396,8 +474,8 @@ class SQLiteStore(Store):
             return self.inmem.participant_events(participant, skip)
         except StoreErr:
             rows = self.db.execute(
-                "SELECT hex FROM events WHERE creator = ? AND idx > ? ORDER BY idx",
-                (participant, skip),
+                "SELECT hex FROM events WHERE chain = ? AND idx > ? ORDER BY idx",
+                (_chain(participant), skip),
             ).fetchall()
             return [r[0] for r in rows]
 
@@ -406,8 +484,8 @@ class SQLiteStore(Store):
             return self.inmem.participant_event(participant, index)
         except StoreErr:
             row = self.db.execute(
-                "SELECT hex FROM events WHERE creator = ? AND idx = ?",
-                (participant, index),
+                "SELECT hex FROM events WHERE chain = ? AND idx = ?",
+                (_chain(participant), index),
             ).fetchone()
             if row is None:
                 raise StoreErr("SQLite.Events", StoreErrType.KEY_NOT_FOUND, str(index))
@@ -528,8 +606,13 @@ class SQLiteStore(Store):
         try:
             self.flush()
         except sqlite3.ProgrammingError:
-            return  # the connection is closed already
-        self.db.close()
+            pass  # the connection is closed already
+        else:
+            self.db.close()
+        if self._wal_index is not None:  # after the connection: see _map_wal_index
+            for handle in reversed(self._wal_index):
+                handle.close()
+            self._wal_index = None
 
     def need_bootstrap(self) -> bool:
         return self._need_bootstrap

@@ -23,18 +23,27 @@ What is held (the letters are the issue's, PR 37):
     written whole, every later `set_event` is a stamps row (`store.stamp`),
     and an event read back from disk after its eviction carries the live
     object's stamps and last ancestors, and its first descendants through
-    the table, then from its row once released.
+    the table, then from its row once released;
+(h) the row keeps its last ancestors as indices, one a chain;
+(i) the events table's keys are integers: a row under its `topo_index`,
+    found through `hkey` and `(chain, idx)`, no index on `hex` or `creator`;
+    a shared hash prefix never reads a wrong row; a file written with the
+    text keys is rebuilt on open and reads back whole; `store.pages` is
+    the log's growth, and a 500-event sync at 64 validators writes at most
+    70% of the pages the text keys write.
 """
 
 import dataclasses
 import json
+import os
+import sqlite3
 from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
 from babble_tpu.common import LRU, StoreErr, StoreErrType
-from babble_tpu.hashgraph import Block, Frame, Hashgraph, InmemStore, SQLiteStore
+from babble_tpu.hashgraph import Block, Frame, Hashgraph, InmemStore, SQLiteStore, sqlite_store
 from babble_tpu.hashgraph.coordinates import MAX_INT32
 from babble_tpu.node import Core
 from benchmark import traffic as gen
@@ -231,13 +240,14 @@ def test_store_totals_and_transactions(ran):
 
 
 def test_a_row_is_written_once(ran):
-    """(g) k inserted events, k rows written whole: a REPLACE of a row
-    would give it a new rowid past the k-th, and nothing else did; every
-    other `set_event` wrote a stamps row, and `store.stamp` counts them."""
+    """(g) k inserted events, k rows written whole under `topo_index` 0 to
+    k - 1: a REPLACE of a row would store it again past the k-th, and
+    nothing else did; every other `set_event` wrote a stamps row, and
+    `store.stamp` counts them."""
     db = durable.connect(ran.path)
     try:
-        assert db.execute("SELECT MAX(rowid), COUNT(*) FROM events").fetchone() == (
-            ran.events, ran.events)
+        top, rows = db.execute("SELECT MAX(topo_index), COUNT(*) FROM events").fetchone()
+        assert top + 1 == rows == ran.events
         stamp_rows = db.execute("SELECT COUNT(*) FROM stamps").fetchone()[0]
     finally:
         db.close()
@@ -422,8 +432,10 @@ class PairRows(SQLiteStore):
                      "LastAncestors": event.last_ancestors,
                      "FirstDescendants": None}
         data = json.dumps(d)
-        self.db.execute("INSERT OR REPLACE INTO events VALUES (?, ?, ?, ?, ?)",
-                        (event.hex(), topo, event.creator(), event.index(), data))
+        self.db.execute("INSERT INTO events VALUES (?, ?, ?, ?, ?, ?, ?)",
+                        (topo, event.hex(), event.creator(), event.index(), data,
+                         sqlite_store._hkey(event.hex()),
+                         self.participants().by_pub_key[event.creator()].id))
         return len(data)
 
 
@@ -559,4 +571,225 @@ def test_an_index_no_row_holds_is_not_found(tmp_path):
     with pytest.raises(StoreErr) as err:
         store.get_event(key)
     assert err.value.err_type == StoreErrType.KEY_NOT_FOUND
+    store.close()
+
+
+# (i) the short keys: every key the events table is searched by an integer
+
+TEXT_KEYS = """CREATE TABLE IF NOT EXISTS events (
+    hex TEXT PRIMARY KEY,
+    topo_index INTEGER NOT NULL,
+    creator TEXT NOT NULL,
+    idx INTEGER NOT NULL,
+    data TEXT NOT NULL
+);
+CREATE UNIQUE INDEX IF NOT EXISTS events_topo ON events(topo_index);
+CREATE UNIQUE INDEX IF NOT EXISTS events_creator_idx ON events(creator, idx);
+"""  # the events table as it was written before the short keys
+FRAME_BYTES = 4096 + 24  # a WAL frame: a 4 KB page and its header
+
+
+def event_indexes(db) -> dict:
+    """{index name: its columns} of the events table."""
+    return {name: [col for _, _, col in db.execute(f"PRAGMA index_info({name})")]
+            for _, name, *_ in db.execute("PRAGMA index_list(events)")}
+
+
+def test_the_event_table_is_keyed_by_integers(ran):
+    """(i) A row under its `topo_index` (the rowid), searched through
+    `hkey` and `(chain, idx)`; no index holds `hex` or `creator`."""
+    db = durable.connect(ran.path)
+    try:
+        columns = {name: (kind, pk) for _, name, kind, _, _, pk
+                   in db.execute("PRAGMA table_info(events)")}
+        indexes = event_indexes(db)
+    finally:
+        db.close()
+    assert columns["topo_index"] == ("INTEGER", 1)
+    assert columns["hkey"][0] == columns["chain"][0] == "INTEGER"
+    assert {"hex", "creator", "idx", "data"} <= set(columns)
+    assert indexes == {"events_hkey": ["hkey"], "events_chain_idx": ["chain", "idx"]}
+
+
+def shared_prefix(key: str) -> int:
+    return 7
+
+
+@pytest.mark.parametrize("hkey", [sqlite_store._hkey, shared_prefix],
+                         ids=["hash", "shared"])
+def test_an_event_is_found_by_its_hash_whatever_its_prefix(tmp_path, monkeypatch, hkey):
+    """(i) `hkey` finds the rows that share a hash's prefix and `hex` picks
+    the one: with every row under one `hkey`, every event, evicted, reads
+    back as itself with its stamps, the stamps rows land on their own rows,
+    and a hash no row holds is KEY_NOT_FOUND."""
+    monkeypatch.setattr(sqlite_store, "_hkey", hkey)
+    stream = stream_of("v16")
+    store = SQLiteStore(stream.peers, CACHE, str(tmp_path / "babble.db"))
+    insert(Hashgraph(stream.peers, store), stream, 0, 1000)
+    want = {key: store.get_event(key) for key in store.inmem.event_cache.keys()}
+    assert {k for k, in store.db.execute("SELECT DISTINCT hkey FROM events")} == (
+        {7} if hkey is shared_prefix else {sqlite_store._hkey(k) for k in want})
+    store.flush()
+    store.inmem.event_cache = LRU(CACHE)
+    for key, live in want.items():
+        back = store.get_event(key)
+        assert back.hex() == key
+        assert ((back.topological_index, back.round, back.lamport_timestamp,
+                 back.round_received, back.last_ancestors)
+                == (live.topological_index, live.round, live.lamport_timestamp,
+                    live.round_received, live.last_ancestors))
+    with pytest.raises(StoreErr):
+        store.get_event("0x" + "00" * 32)
+    store.close()
+
+
+def to_text_keys(path) -> None:
+    """The file's events table rewritten in the layout it had before the
+    short keys, the same rows under the same `topo_index`."""
+    db = sqlite3.connect(path)
+    db.execute("BEGIN")
+    db.execute("ALTER TABLE events RENAME TO keyed")
+    for statement in TEXT_KEYS.split(";")[:-1]:
+        db.execute(statement)
+    db.execute("INSERT INTO events SELECT hex, topo_index, creator, idx, data FROM keyed")
+    db.execute("DROP TABLE keyed")
+    db.commit()
+    db.close()
+
+
+def test_a_file_with_text_keys_is_rebuilt_on_open(tmp_path):
+    """(i) A file written before the short keys opens in the new layout:
+    every event (its row, its stamps, its last ancestors), every block and
+    frame reads back as written, and the restart re-derives the blocks."""
+    stream, events = stream_of("v16"), 2000
+    path = str(tmp_path / "babble.db")
+    blocks = Handover(stream, path)
+    core = new_core(stream, SQLiteStore(stream.peers, CACHE, path), blocks)
+    feed(core, stream, 0, events)
+    core.flush_device_dispatch()
+    live = {key: core.hg.store.get_event(key) for key in core.hg.store.inmem.event_cache.keys()}
+    core.hg.store.close()
+    written, stamps = durable.read(path), stamps_on_disk(path)
+    to_text_keys(path)
+    db = durable.connect(path)
+    assert "events_creator_idx" in event_indexes(db)
+    db.close()
+
+    store = SQLiteStore.load_or_create(stream.peers, CACHE, path)
+    assert event_indexes(store.db) == {"events_hkey": ["hkey"],
+                                       "events_chain_idx": ["chain", "idx"]}
+    assert store.db.execute("SELECT name FROM sqlite_master WHERE tbl_name = 'keyed' "
+                            "OR name = 'events_creator_idx'").fetchall() == []
+    rebuilt = durable.read(path)
+    for field in dataclasses.fields(durable.Stored):
+        got, want = getattr(rebuilt, field.name), getattr(written, field.name)
+        assert (np.array_equal(got, want) if isinstance(want, np.ndarray)
+                else got == want), field.name
+    assert (stamps_on_disk(path) == stamps).all()
+    assert len(live) == events
+    for key, want in live.items():
+        back = store.get_event(key)
+        assert ((back.topological_index, back.round, back.lamport_timestamp,
+                 back.round_received, back.last_ancestors)
+                == (want.topological_index, want.round, want.lamport_timestamp,
+                    want.round_received, want.last_ancestors))
+    for index in range(len(blocks.bodies)):
+        assert store.get_block(index).body.marshal() == blocks.bodies[index]
+    store.close()
+
+    again, found = restart(stream, path)
+    assert again.known_events() == core.known_events()
+    assert found.bodies[:len(blocks.bodies)] == blocks.bodies
+    assert found.unreadable == []
+    again.hg.store.close()
+
+
+class Recorder:
+    """A store's connection that keeps the statements it is handed, with
+    their arguments, and a `None` at each commit."""
+
+    def __init__(self, db):
+        self.db, self.log = db, []
+
+    def __getattr__(self, name):
+        return getattr(self.db, name)
+
+    def execute(self, sql, args=()):
+        self.log.append((sql, args, False))
+        return self.db.execute(sql, args)
+
+    def executemany(self, sql, rows):
+        rows = list(rows)
+        self.log.append((sql, rows, True))
+        return self.db.executemany(sql, rows)
+
+    def commit(self):
+        self.log.append(None)
+        self.db.commit()
+
+
+# the store's statements on the events table as the text keys had them
+TEXT_KEYED = {
+    sqlite_store._PUT_STAMPS: (
+        "INSERT OR REPLACE INTO stamps SELECT topo_index, ?, ?, ?, ? "
+        "FROM events WHERE hex = ?", lambda a: a[:4] + a[5:]),
+    "INSERT INTO events VALUES (?, ?, ?, ?, ?, ?, ?)": (
+        "INSERT OR REPLACE INTO events VALUES (?, ?, ?, ?, ?)",
+        lambda a: (a[1], a[0]) + a[2:5]),
+}
+
+
+def log_frames(path) -> int:
+    return (os.path.getsize(path + "-wal") - 32) // FRAME_BYTES
+
+
+def text_keyed_frames(log, path) -> list:
+    """The frames each commit of `log` appends to a file of the text keys,
+    the log's statements on the events table put as they were then."""
+    schema = sqlite_store._SCHEMA
+    db = sqlite3.connect(path)
+    db.execute("PRAGMA journal_mode=WAL")
+    db.execute(f"PRAGMA cache_size=-{sqlite_store.PAGE_CACHE_KIB}")
+    db.execute("PRAGMA wal_autocheckpoint=0")
+    db.executescript(TEXT_KEYS + schema[schema.index("CREATE TABLE IF NOT EXISTS stamps"):])
+    frames, out = log_frames(path), []
+    for entry in log:
+        if entry is None:
+            db.commit()
+            out.append(log_frames(path) - frames)
+            frames += out[-1]
+            continue
+        sql, args, many = entry
+        if sql.startswith(("SELECT", "WITH")):
+            continue  # a read writes nothing
+        if " events " in sql:
+            sql, put = TEXT_KEYED[sql]
+            args = [put(a) for a in args] if many else put(args)
+        (db.executemany if many else db.execute)(sql, args)
+    db.close()
+    return out
+
+
+def test_store_pages_counts_the_log_and_short_keys_write_fewer(tmp_path):
+    """(i) `store.pages` is what each flush appended to the log (its growth
+    with the checkpoint off), and at 64 validators a 500-event sync writes at
+    most 70% of the pages that the same statements write under text keys."""
+    stream, sync = replay.Stream(64, 5000, SEED, ZIPF_A, 1, TOPOLOGY_SEED), 500
+    path = str(tmp_path / "babble.db")
+    store = SQLiteStore(stream.peers, CACHE, path)
+    store.db.execute("PRAGMA wal_autocheckpoint=0")
+    store.db = Recorder(store.db)
+    hg = Hashgraph(stream.peers, store)
+    pages, grown = [], []
+    for lo in range(0, len(stream.signed), sync):
+        before, frames = hg.obs.tracer.totals().get("store.pages", (0, 0.0))[0], log_frames(path)
+        for signed in stream.signed[lo:lo + sync]:
+            hg.insert_event(stream.copy(signed), True)
+        hg.run_consensus()
+        store.flush()
+        pages.append(hg.obs.tracer.totals()["store.pages"][0] - before)
+        grown.append(log_frames(path) - frames)
+    assert pages == grown and min(pages) > 0
+    old = text_keyed_frames(store.db.log, str(tmp_path / "text.db"))
+    assert pages[-1] <= 0.7 * old[-1]
     store.close()

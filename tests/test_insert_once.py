@@ -34,6 +34,7 @@ from babble_tpu.hashgraph import (
     Block, Event, Frame, Hashgraph, InmemStore, SQLiteStore,
 )
 from babble_tpu.hashgraph import event as event_mod
+from babble_tpu.hashgraph import sqlite_store
 from babble_tpu.hashgraph.hashgraph import MAX_INT32
 from benchmark.entries import replay, replay_adversarial
 
@@ -74,9 +75,10 @@ class PlainSQLiteStore(SQLiteStore):
             if row is None:
                 self._topo_counter += 1
             self.db.execute(
-                "INSERT OR REPLACE INTO events VALUES (?, ?, ?, ?, ?)",
-                (event.hex(), topo, event.creator(), event.index(),
-                 json.dumps(event.to_store_json())),
+                "INSERT OR REPLACE INTO events VALUES (?, ?, ?, ?, ?, ?, ?)",
+                (topo, event.hex(), event.creator(), event.index(),
+                 json.dumps(event.to_store_json()), sqlite_store._hkey(event.hex()),
+                 peer.id),
             )
 
 
