@@ -1382,11 +1382,6 @@ class Hashgraph:
             "hashgraph.reset", block=block.index(),
             round=block.round_received(),
         )
-        # any incremental device state is invalid after a reset
-        eng = getattr(self, "_live_device_engine", None)
-        if eng is not None:
-            eng.detach()
-            self._live_device_engine = None
         self.last_consensus_round = None
         self.first_consensus_round = None
         self.anchor_block = None

@@ -5,7 +5,7 @@ from __future__ import annotations
 
 import logging
 import queue
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 from ..crypto import pub_key_bytes
 from ..hashgraph import (
@@ -19,6 +19,52 @@ from ..hashgraph import (
     WireEvent,
 )
 from ..peers import Peers
+
+
+class _Backoff:
+    """Retry slots for a failing rung: after a failure at call c the next
+    try is at c + step, the step doubling with each failure up to `cap`
+    and back to 1 once the rung serves."""
+
+    def __init__(self, cap: int):
+        self.cap, self.step, self.at = cap, 1, 0
+
+    def fail(self, call: int) -> int:
+        self.step = min(self.step * 2, self.cap)
+        self.at = call + self.step
+        return self.step
+
+
+class _Rung(NamedTuple):
+    """A device rung whose engine stays attached across calls. The engine
+    has serve(), flush() and detach(); building it is attaching it."""
+
+    name: str  # ladder_rung(), the logs and the ladder.* records
+    factory: Callable[[Core], object]
+
+
+def _live_engine(core: Core):
+    from ..tpu.live import LiveDeviceEngine
+
+    return LiveDeviceEngine(
+        core.hg, queue_depth=core.dispatch_queue_depth,
+        batch_deadline=core.dispatch_batch_deadline,
+        batch_cap=core.dispatch_batch_rows,
+    )
+
+
+def _mesh_queue(core: Core):
+    from ..tpu.dispatch import MeshDispatchQueue
+
+    return MeshDispatchQueue(
+        core.hg, core._get_mesh(), queue_depth=core.dispatch_queue_depth,
+        batch_deadline=core.dispatch_batch_deadline,
+        batch_rows=core.dispatch_batch_rows,
+    )
+
+
+_LIVE = _Rung("live", _live_engine)
+_MESH_QUEUED = _Rung("mesh_queued", _mesh_queue)
 
 
 class Core:
@@ -124,8 +170,12 @@ class Core:
         self.device_attach_failures = 0
         self._attach_errors_logged: set = set()
         self._consensus_calls = 0
-        self._live_retry_at = 0  # next _consensus_calls value to retry at
-        self._live_backoff = 1
+        # the engine of the device rung that stays attached across calls
+        # (tpu/live.py LiveDeviceEngine, tpu/dispatch.py MeshDispatchQueue;
+        # _device_rung says which), or None. Core alone attaches, serves,
+        # flushes and drops it; the hashgraph does not know it
+        self.device_engine = None
+        self._rung_retry = _Backoff(64)
         # set when the hashgraph state stops being grid-expressible (e.g. a
         # rolled store window). NOT a one-way door (VERDICT r4 #3): the
         # one-shot path is retried with bounded exponential backoff — a
@@ -133,8 +183,7 @@ class Core:
         # needing a fast-forward (which also clears it, by compacting the
         # state back into grid range). Heals are counted for /stats.
         self._device_down = False
-        self._device_retry_at = 0
-        self._device_backoff = 1
+        self._down_retry = _Backoff(256)
         self.device_heals = 0
 
     # -- identity ----------------------------------------------------------
@@ -358,6 +407,13 @@ class Core:
     def apply_fast_forward(self, block: Block, frame: Frame, section=None) -> None:
         """Apply a validated fast-forward (reset + section replay +
         consensus continuation). Args must come from prepare_fast_forward."""
+        # the attached rung's device state and in-flight dispatches were
+        # built against the store the reset replaces: drop it (a demotion,
+        # visible in /stats), and re-attach (the frontier assembly handles
+        # post-reset states) after one one-shot call lets the reset settle
+        if self.device_engine is not None:
+            self.live_demotions += 1
+        self._drop_device_engine()
         self.hg.reset(block, frame)
         if section is not None:
             self.hg.apply_section(section, block.index())
@@ -367,20 +423,8 @@ class Core:
         )
         self.set_head_and_seq()
         self._device_down = False  # reset compacted the state back into range
-        self._device_backoff = 1
-        self._device_retry_at = 0
-        # the live engine's device state is desynced from the reset store:
-        # drop it (a demotion, visible in /stats), and re-attach (the
-        # frontier assembly handles post-reset states) after one one-shot
-        # call lets the reset settle
-        if getattr(self.hg, "_live_device_engine", None) is not None:
-            self.live_demotions += 1
-        self._drop_live_engine()
-        # in-flight mesh dispatches were staged against pre-reset state;
-        # their snapshots alias containers the reset invalidated — discard
-        # (nothing from them was stamped, the next serve restages)
-        self._drop_mesh_queue()
-        self._live_retry_at = self._consensus_calls + 2
+        self._down_retry = _Backoff(256)
+        self._rung_retry.at = self._consensus_calls + 2
         self.run_consensus()
 
     def fast_forward(
@@ -451,182 +495,110 @@ class Core:
     def _run_ladder(self) -> None:
         """The device path covers passes 1-3 (grid extraction + fused XLA
         pipeline) and falls back to the host engine on any state the dense
-        grid cannot express (reference boundary: src/node/core.go:335-377)."""
-        if self.consensus_backend == "tpu":
-            from ..tpu.engine import run_consensus_device
-            from ..tpu.grid import GridUnsupported
+        grid cannot express (reference boundary: src/node/core.go:335-377).
+        Its rungs, top down: the attached one (_serve_rung), the one-shot
+        device call, the host engine."""
+        if self.consensus_backend != "tpu" or (
+            self._device_down and self._consensus_calls < self._down_retry.at
+        ):
+            # host backend, or down but healing: CPU serves until the next
+            # retry slot
+            self.hg.run_consensus()
+            return
+        from ..tpu.engine import run_consensus_device
+        from ..tpu.grid import GridUnsupported
 
-            if self._device_down and self._consensus_calls < self._device_retry_at:
-                # down, but healing: CPU serves until the next retry slot
-                self.hg.run_consensus()
-                return
-            if self.mesh_devices > 1:
-                # mesh ladder (--mesh-devices): queued async dispatch ->
-                # sync one-shot mesh -> CPU. The queued rung (ISSUE 6)
-                # overlaps the sharded pipeline with gossip through a
-                # bounded dispatch queue; it shares the live engine's
-                # demote/heal machinery (bounded backoff, counted
-                # demotions/re-attaches) because it is the mesh analogue
-                # of that rung. The sync one-shot path remains for
-                # post-reset states (host-delegated decision timing) and
-                # as the recompute safety net after a queue demotion.
-                if (
-                    self.dispatch_queue_depth > 0
-                    and self._consensus_calls >= self._live_retry_at
-                ):
-                    from ..tpu.dispatch import run_consensus_mesh_queued
+        rung = self._device_rung()
+        if (
+            rung is not None
+            and self._consensus_calls >= self._rung_retry.at
+            and self._serve_rung(rung)
+        ):
+            return
+        mesh = self.mesh_devices > 1
+        try:
+            run_consensus_device(self.hg, mesh=self._get_mesh() if mesh else None)
+        except GridUnsupported as e:
+            # unsupported states (rolled windows) tend to persist until a
+            # reset compacts them — back off instead of failing every
+            # tick, but keep retrying: windows can also roll back into
+            # range as consensus advances
+            self._mark_device_down(
+                "mesh consensus" if mesh else "device consensus", e)
+            self.hg.run_consensus()
+            return
+        self.device_consensus_runs += 1
+        self._note_device_up()
 
-                    attached = (
-                        getattr(self.hg, "_mesh_dispatch_queue", None)
-                        is not None
-                    )
-                    try:
-                        run_consensus_mesh_queued(
-                            self.hg, self._get_mesh(),
-                            queue_depth=self.dispatch_queue_depth,
-                            batch_deadline=self.dispatch_batch_deadline,
-                            batch_rows=self.dispatch_batch_rows,
-                        )
-                        self.device_consensus_runs += 1
-                        self._note_device_up()
-                        if not attached and self.live_demotions > 0:
-                            self.live_reattaches += 1
-                            self.hg.obs.flightrec.record(
-                                "ladder.reattach", rung="mesh_queued",
-                                demotions=self.live_demotions,
-                            )
-                            self.logger.info(
-                                "queued mesh dispatch re-attached "
-                                "(demotions=%d)", self.live_demotions,
-                            )
-                        self._live_backoff = 1
-                        return
-                    except Exception as e:  # noqa: BLE001 — in-flight
-                        # results are discarded wholesale (nothing was
-                        # stamped from them), so the one-shot restage
-                        # below recomputes everything from the store
-                        if attached:
-                            self.live_demotions += 1
-                        else:
-                            self._note_attach_failure("queued mesh", e)
-                        self._live_backoff = min(self._live_backoff * 2, 64)
-                        self._live_retry_at = (
-                            self._consensus_calls + self._live_backoff
-                        )
-                        self._drop_mesh_queue()
-                        if attached:
-                            self.hg.obs.flightrec.record(
-                                "ladder.demote", rung="mesh_queued",
-                                error=type(e).__name__,
-                                backoff=self._live_backoff,
-                            )
-                            # 3 demotions in 10s = a flapping backend:
-                            # dump the ring while the evidence is fresh
-                            self.hg.obs.flightrec.note_flap("demotion")
-                        if attached:
-                            log = (
-                                self.logger.info
-                                if isinstance(e, GridUnsupported)
-                                else self.logger.warning
-                            )
-                        else:
-                            log = self.logger.debug
-                        log(
-                            "queued mesh dispatch unavailable (%s); "
-                            "one-shot mesh path, retry in %d calls",
-                            e, self._live_backoff,
-                        )
-                try:
-                    run_consensus_device(self.hg, mesh=self._get_mesh())
-                    self.device_consensus_runs += 1
-                    self._note_device_up()
-                    return
-                except GridUnsupported as e:
-                    self._mark_device_down("mesh consensus", e)
-                    self.hg.run_consensus()
-                    return
-            if self._consensus_calls >= self._live_retry_at:
-                from ..tpu.live import run_consensus_live
+    def _device_rung(self) -> Optional[_Rung]:
+        """The rung that stays attached across calls: the queued mesh
+        dispatch with --mesh-devices > 1 (none without a dispatch queue),
+        else the live engine. Read from the knobs on each call, which an
+        embedding caller may set after construction."""
+        if self.mesh_devices > 1:
+            return _MESH_QUEUED if self.dispatch_queue_depth > 0 else None
+        return _LIVE
 
-                attached = (
-                    getattr(self.hg, "_live_device_engine", None) is not None
+    def _serve_rung(self, rung: _Rung) -> bool:
+        """One call on the attached rung, attaching it first if it is not:
+        True if it served the call. On any failure the engine is dropped
+        and the attach is retried with bounded backoff."""
+        from ..tpu.grid import GridUnsupported
+
+        attached = self.device_engine is not None
+        try:
+            if not attached:
+                self.device_engine = rung.factory(self)
+                if rung is _LIVE:
+                    # mirrored for the benchmark's entries, which read it
+                    # off the hashgraph; cleared in _drop_device_engine
+                    self.hg._live_device_engine = self.device_engine
+            self.device_engine.serve()
+        except Exception as e:  # noqa: BLE001 — any failure leaves the
+            # engine desynced from the store: drop it (the one-shot path
+            # recomputes from the store) and retry the attach with bounded
+            # backoff, since the frontier assembly can rebuild from any
+            # settled state. Only the fall of an ATTACHED engine is a
+            # demotion; a failed re-attach just extends the backoff.
+            backoff = self._rung_retry.fail(self._consensus_calls)
+            self._drop_device_engine()
+            if attached:
+                self.live_demotions += 1
+                self.hg.obs.flightrec.record(
+                    "ladder.demote", rung=rung.name, error=type(e).__name__,
+                    backoff=backoff,
                 )
-                try:
-                    run_consensus_live(
-                        self.hg,
-                        queue_depth=self.dispatch_queue_depth,
-                        batch_deadline=self.dispatch_batch_deadline,
-                        batch_cap=self.dispatch_batch_rows,
-                    )
-                    self.device_consensus_runs += 1
-                    self._note_device_up()
-                    if not attached and self.live_demotions > 0:
-                        self.live_reattaches += 1
-                        self.hg.obs.flightrec.record(
-                            "ladder.reattach", rung="live",
-                            demotions=self.live_demotions,
-                        )
-                        self.logger.info(
-                            "incremental device engine re-attached "
-                            "(demotions=%d)", self.live_demotions,
-                        )
-                    self._live_backoff = 1
-                    return
-                except Exception as e:  # noqa: BLE001 — any failure leaves
-                    # the engine's device state desynced from its host
-                    # bookkeeping: drop it entirely (the one-shot path
-                    # recomputes from the store, so nothing is lost) and
-                    # retry the attach with bounded backoff — the frontier
-                    # assembly can rebuild from any settled state, so
-                    # demotion is a pause, not a sentence. Only a fall of
-                    # an ATTACHED engine is a demotion; a failed re-attach
-                    # attempt just extends the backoff (else the counter
-                    # grows without bound on permanently-unsupported
-                    # states and stops meaning "engine dropped").
-                    if attached:
-                        self.live_demotions += 1
-                        self.hg.obs.flightrec.record(
-                            "ladder.demote", rung="live",
-                            error=type(e).__name__,
-                            backoff=min(self._live_backoff * 2, 64),
-                        )
-                        self.hg.obs.flightrec.note_flap("demotion")
-                    else:
-                        self._note_attach_failure("live", e)
-                    self._live_backoff = min(self._live_backoff * 2, 64)
-                    self._live_retry_at = (
-                        self._consensus_calls + self._live_backoff
-                    )
-                    self._drop_live_engine()
-                    # one log per TRANSITION (a demotion of an attached
-                    # engine): repeated failed re-attach attempts while
-                    # already demoted stay at debug so a permanently
-                    # unsupported state doesn't log every backoff window
-                    if attached:
-                        log = (
-                            self.logger.info
-                            if isinstance(e, GridUnsupported)
-                            else self.logger.warning
-                        )
-                    else:
-                        log = self.logger.debug
-                    log(
-                        "incremental device engine unavailable (%s); "
-                        "one-shot device path, retry in %d calls",
-                        e, self._live_backoff,
-                    )
-            try:
-                run_consensus_device(self.hg)
-                self.device_consensus_runs += 1
-                self._note_device_up()
-                return
-            except GridUnsupported as e:
-                # unsupported states (rolled windows) tend to persist until
-                # a reset compacts them — back off instead of failing every
-                # tick, but keep retrying: windows can also roll back into
-                # range as consensus advances
-                self._mark_device_down("device consensus", e)
-        self.hg.run_consensus()
+                # 3 demotions in 10s = a flapping backend: dump the ring
+                # while the evidence is fresh
+                self.hg.obs.flightrec.note_flap("demotion")
+                log = (
+                    self.logger.info
+                    if isinstance(e, GridUnsupported)
+                    else self.logger.warning
+                )
+            else:
+                self._note_attach_failure(rung.name, e)
+                # one log per transition: failed re-attaches stay at debug
+                log = self.logger.debug
+            log(
+                "%s rung unavailable (%s); one-shot path, retry in %d calls",
+                rung.name, e, backoff,
+            )
+            return False
+        self.device_consensus_runs += 1
+        self._note_device_up()
+        if not attached and self.live_demotions > 0:
+            self.live_reattaches += 1
+            self.hg.obs.flightrec.record(
+                "ladder.reattach", rung=rung.name,
+                demotions=self.live_demotions,
+            )
+            self.logger.info(
+                "%s rung re-attached (demotions=%d)", rung.name,
+                self.live_demotions,
+            )
+        self._rung_retry.step = 1
+        return True
 
     def _note_attach_failure(self, rung: str, e: Exception) -> None:
         """A rung whose FIRST attach failed. GridUnsupported is routine (a
@@ -652,17 +624,16 @@ class Core:
         first = not self._device_down
         self._device_down = True
         self.device_consensus_fallbacks += 1
-        self._device_backoff = min(self._device_backoff * 2, 256)
-        self._device_retry_at = self._consensus_calls + self._device_backoff
+        backoff = self._down_retry.fail(self._consensus_calls)
         if first:
             self.hg.obs.flightrec.record(
                 "ladder.device_down", what=what, error=type(e).__name__,
-                backoff=self._device_backoff,
+                backoff=backoff,
             )
         log = self.logger.info if first else self.logger.debug
         log(
             "%s unsupported (%s); using CPU, retry in %d calls",
-            what, e, self._device_backoff,
+            what, e, backoff,
         )
 
     def _note_device_up(self) -> None:
@@ -677,7 +648,7 @@ class Core:
                 "device backend healed after %d fallbacks "
                 "(heals=%d)", self.device_consensus_fallbacks, self.device_heals,
             )
-        self._device_backoff = 1
+        self._down_retry.step = 1
 
     def _get_mesh(self):
         """The node's device mesh, built once. One axis ("shard", over
@@ -719,29 +690,25 @@ class Core:
                 )
         return self._mesh
 
-    def _drop_live_engine(self) -> None:
-        eng = getattr(self.hg, "_live_device_engine", None)
-        if eng is not None:
-            eng.detach()
-            self.hg._live_device_engine = None
-
-    def _drop_mesh_queue(self) -> None:
-        q = getattr(self.hg, "_mesh_dispatch_queue", None)
-        if q is not None:
-            q.detach()  # in-flight results are never stamped
-            self.hg._mesh_dispatch_queue = None
+    def _drop_device_engine(self) -> None:
+        """Detach the attached rung: its in-flight results are never
+        stamped, and the next attach rebuilds from the store."""
+        if self.device_engine is not None:
+            self.device_engine.detach()
+            self.device_engine = None
+            self.hg._live_device_engine = None  # the benchmark's mirror
 
     def flush_device_dispatch(self) -> None:
         """Blocking barrier for drivers/benches/shutdown: integrate every
-        in-flight device dispatch (queued-mesh and live-engine queues) so
-        the store reflects all staged work before assertions or exit."""
-        q = getattr(self.hg, "_mesh_dispatch_queue", None)
-        if q is not None:
-            q.flush()
-        if getattr(self.hg, "_live_device_engine", None) is not None:
-            from ..tpu.live import flush_live_engine
-
-            flush_live_engine(self.hg)
+        in-flight device dispatch so the store reflects all staged work
+        before assertions or exit. An engine whose flush raises (a latch)
+        is dropped, but no call fell, so it is no demotion."""
+        if self.device_engine is not None:
+            try:
+                self.device_engine.flush()
+            except Exception:
+                self._drop_device_engine()
+                raise
         self.hg.store.flush()
 
     def add_transactions(
@@ -830,10 +797,8 @@ class Core:
             return "cpu"
         if self._device_down:
             return "cpu_fallback"
-        if getattr(self.hg, "_live_device_engine", None) is not None:
-            return "live"
-        if getattr(self.hg, "_mesh_dispatch_queue", None) is not None:
-            return "mesh_queued"
+        if self.device_engine is not None:
+            return self._device_rung().name
         if self.mesh_devices and int(self.mesh_devices) > 1:
             return "mesh"
         return "one_shot"

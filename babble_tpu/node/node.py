@@ -1424,8 +1424,9 @@ class Node(NodeStateMachine):
         adapter: durations now come from the registry histograms
         (babble_device_dispatch/fetch_seconds); structural counters
         (dispatches, rebases, pipelining) stay on the engine."""
-        eng = getattr(self.core.hg, "_live_device_engine", None)
-        if eng is None or eng.consensus_calls == 0:
+        eng = self.core.device_engine
+        if (eng is None or self.core.ladder_rung() != "live"
+                or eng.consensus_calls == 0):
             return {}
         fetch_calls, fetch_sum = self._m_fetch.stats()
         _, dispatch_sum = self._m_dispatch.stats()

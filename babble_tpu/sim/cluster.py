@@ -932,10 +932,10 @@ class SimCluster:
                 # a mesh node may have a dispatch worker mid-execution;
                 # an orphaned daemon thread inside JAX at interpreter
                 # exit aborts the process, so wait it out first
-                q = getattr(sn.node.core.hg, "_mesh_dispatch_queue", None)
-                if q is not None:
+                core = sn.node.core
+                if core.ladder_rung() == "mesh_queued":
                     try:
-                        q.quiesce()
+                        core.device_engine.quiesce()
                     except Exception:  # noqa: BLE001
                         pass
                 try:

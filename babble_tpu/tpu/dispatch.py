@@ -391,20 +391,3 @@ class MeshDispatchQueue:
             "dispatch.integrate", blocked=dt, depth=len(self.inflight),
             integrations=self.integrations, rounds=new_rounds,
         )
-
-
-def run_consensus_mesh_queued(hg, mesh, queue_depth: int = 4,
-                              batch_deadline: float = 0.0,
-                              batch_rows: int = MESH_BATCH_ROWS) -> None:
-    """Queued-mesh rung entry point: get-or-create the hashgraph's
-    dispatch queue and serve one consensus call through it. The queue
-    hangs off the hashgraph like the live engine does, so Core's
-    demotion machinery (_drop_live_engine) can discard both."""
-    q: Optional[MeshDispatchQueue] = getattr(hg, "_mesh_dispatch_queue", None)
-    if q is None:
-        q = MeshDispatchQueue(
-            hg, mesh, queue_depth=queue_depth, batch_deadline=batch_deadline,
-            batch_rows=batch_rows,
-        )
-        hg._mesh_dispatch_queue = q
-    q.serve()

@@ -11,7 +11,7 @@ state.
 Wiring: the Hashgraph's insert path reports each inserted event plus the
 first-descendant cells its insert wrote (hashgraph.insert_listener): the
 hashes of the ancestors written, since a cell's column and value are the
-event's own creator position and index. run_consensus_live drains that
+event's own creator position and index. LiveDeviceEngine.serve drains that
 queue into fixed-shape append batches, a batch's cells going to the three
 update arrays in bulk (`_build_batch`, `incremental._pack_upd`),
 advances the device state, and writes new rounds/fame/received back into
@@ -160,7 +160,7 @@ class LiveDeviceEngine:
         # the pipelining heuristic reads them per-engine.
         self.dispatches = 0
         self.consensus_calls = 0
-        # run_consensus_live calls and dispatches so far: the `dispatch`
+        # serve calls and dispatches so far: the `dispatch`
         # attribute ties one dispatch's launch, fetch and integration,
         # which the pipelined discipline spreads over several calls
         self.calls = 0
@@ -249,6 +249,72 @@ class LiveDeviceEngine:
         if getattr(self.hg, "insert_listener", None) is self._on_insert:
             self.hg.insert_listener = None
         self.inflight = []  # results of a dropped engine are never stamped
+
+    # -- serving -----------------------------------------------------------
+
+    def serve(self) -> None:
+        """One consensus call: advance the persistent state by the events
+        inserted since the last call, then write decisions back and run the
+        host passes (mirrors engine.run_consensus_device's write-back,
+        restricted to new/undetermined work). The first call, the one that
+        attached the engine, writes back the bootstrap's rows as well.
+
+        Two fetch disciplines (a slow fetch must not serialize gossip under
+        the core lock):
+
+        - synchronous (default): dispatch, fetch, integrate, all in this call.
+          The blocking fetch waits for the device to finish the programs just
+          launched, then copies one packed vector.
+        - pipelined (self-activating): when the measured blocking fetch is
+          expensive (threshold ASYNC_FETCH_MIN_S over 3 consecutive calls —
+          on a colocated chip that means the device compute itself is slow
+          against the gossip interval), the fetch moves OFF the consensus
+          critical path: each call integrates the PREVIOUS call's dispatch
+          (its results already resident host-side via a background reader
+          thread) and launches a new dispatch whose compute and transfer
+          overlap the next gossip interval. Only while that fetch is still
+          waited on (the same threshold, 3 consecutive calls) does the engine
+          keep one more dispatch in flight, up to ``queue_depth`` of them,
+          and a queue that drains for lack of traffic starts again at one
+          (_note_wait, _run_pipelined).
+          Decisions lag one sync, and up to queue_depth where the device
+          needs them — pure timing, not content:
+          rounds, fame, and receptions are DAG facts, so block bodies stay
+          byte-identical (pinned by the strict joiner differentials), they
+          just seal that many calls later. The write-back validation gates run
+          unchanged at integration time against a dispatch-time snapshot of
+          the row mapping (rebases build fresh containers, so snapshots are
+          O(1) references), and integration order is FIFO so parents' rounds
+          always land before children's. Integration TRIGGERS are
+          deterministic (queue occupancy, call sequence and waits read from
+          the obs clock, as the flip is; never thread completion state) so
+          same-seed sim runs stay byte-identical.
+        """
+        self.calls += 1
+        if self.calls == 1:
+            # the bootstrap replayed the whole pre-existing DAG on device;
+            # its rows still need the host write-back — the attach call is
+            # always synchronous so the node leaves it with a fully written
+            # store
+            new_rows = list(range(len(self.hashes)))
+            new_rows.extend(self.advance())
+            _run_sync(self.hg, self, new_rows)
+        elif self.async_fetch:
+            _run_pipelined(self.hg, self)
+        else:
+            _run_sync(self.hg, self, self.advance())
+
+    def flush(self) -> None:
+        """Blocking barrier: integrate every in-flight dispatch
+        (Core.flush_device_dispatch, before asserting on store state)."""
+        if not self.inflight:
+            return
+        last_round_rel = 0
+        while self.inflight:
+            last_round_rel = _integrate_oldest(self.hg, self)
+        _manage_capacity(self, last_round_rel)
+        self.hg.process_decided_rounds()
+        self.hg.process_sig_pool()
 
     # -- construction ------------------------------------------------------
 
@@ -461,7 +527,7 @@ class LiveDeviceEngine:
         decided round received can only be received at a round >= the
         first undecided one, so nothing below the base can influence any
         future decision. Rounds are stored base-relative on device;
-        run_consensus_live translates at the write-back boundary.
+        the write-back translates them back.
 
         Everything is assembled host-side from the store (coordinates are
         host-maintained and write-once) — one device upload, no replay.
@@ -974,67 +1040,6 @@ def _unpack_results(packed, e_win: int, r_cap: int, n: int):
             int(flags[2]), reopened)
 
 
-def run_consensus_live(hg, queue_depth: int = None,
-                       batch_deadline: float = None,
-                       batch_cap: int = None) -> None:
-    """Incremental device consensus for a live node: advance the persistent
-    state by the events inserted since the last call, then write decisions
-    back and run the host passes (mirrors engine.run_consensus_device's
-    write-back, restricted to new/undetermined work).
-
-    Two fetch disciplines (a slow fetch must not serialize gossip under
-    the core lock):
-
-    - synchronous (default): dispatch, fetch, integrate, all in this call.
-      The blocking fetch waits for the device to finish the programs just
-      launched, then copies one packed vector.
-    - pipelined (self-activating): when the measured blocking fetch is
-      expensive (threshold ASYNC_FETCH_MIN_S over 3 consecutive calls —
-      on a colocated chip that means the device compute itself is slow
-      against the gossip interval), the fetch moves OFF the consensus
-      critical path: each call integrates the PREVIOUS call's dispatch
-      (its results already resident host-side via a background reader
-      thread) and launches a new dispatch whose compute and transfer
-      overlap the next gossip interval. Only while that fetch is still
-      waited on (the same threshold, 3 consecutive calls) does the engine
-      keep one more dispatch in flight, up to ``queue_depth`` of them,
-      and a queue that drains for lack of traffic starts again at one
-      (_note_wait, _run_pipelined).
-      Decisions lag one sync, and up to queue_depth where the device
-      needs them — pure timing, not content:
-      rounds, fame, and receptions are DAG facts, so block bodies stay
-      byte-identical (pinned by the strict joiner differentials), they
-      just seal that many calls later. The write-back validation gates run
-      unchanged at integration time against a dispatch-time snapshot of
-      the row mapping (rebases build fresh containers, so snapshots are
-      O(1) references), and integration order is FIFO so parents' rounds
-      always land before children's. Integration TRIGGERS are
-      deterministic (queue occupancy, call sequence and waits read from
-      the obs clock, as the flip is; never thread completion state) so
-      same-seed sim runs stay byte-identical.
-    """
-    eng: Optional[LiveDeviceEngine] = getattr(hg, "_live_device_engine", None)
-    if eng is None:
-        eng = LiveDeviceEngine(
-            hg, queue_depth=queue_depth, batch_deadline=batch_deadline,
-            batch_cap=batch_cap,
-        )
-        hg._live_device_engine = eng
-        eng.calls = 1
-        # the bootstrap replayed the whole pre-existing DAG on device; its
-        # rows still need the host write-back — the attach call is always
-        # synchronous so the node leaves it with a fully written store
-        new_rows = list(range(len(eng.hashes)))
-        new_rows.extend(eng.advance())
-        _run_sync(hg, eng, new_rows)
-        return
-    eng.calls += 1
-    if eng.async_fetch:
-        _run_pipelined(hg, eng)
-    else:
-        _run_sync(hg, eng, eng.advance())
-
-
 # blocking-fetch cost that flips an engine to the pipelined discipline
 # (3 consecutive calls over the threshold); ENGINE_DEFAULTS["async_fetch"]
 # forces True/False for tests. The wait is a sync's device time less what
@@ -1225,21 +1230,6 @@ def _settle_capacity(hg, eng: LiveDeviceEngine, last_round_rel: int) -> None:
     _manage_capacity(eng, last_round_rel)
 
 
-def flush_live_engine(hg) -> None:
-    """Blocking barrier: integrate every in-flight live-engine dispatch
-    (drivers/benches call this via Core.flush_device_dispatch before
-    asserting on store state)."""
-    eng: Optional[LiveDeviceEngine] = getattr(hg, "_live_device_engine", None)
-    if eng is None or not eng.inflight:
-        return
-    last_round_rel = 0
-    while eng.inflight:
-        last_round_rel = _integrate_oldest(hg, eng)
-    _manage_capacity(eng, last_round_rel)
-    hg.process_decided_rounds()
-    hg.process_sig_pool()
-
-
 def _run_pipelined(hg, eng: LiveDeviceEngine) -> None:
     """Multi-slot overlap: keep fetch_lag dispatches in flight (one, and
     up to queue_depth while the oldest fetch is really waited on:
@@ -1337,8 +1327,7 @@ def _write_back(hg, eng: LiveDeviceEngine, packed, snap: dict) -> int:
         witness_w = witness_w[: count - lo]
         received_w = received_w[: count - lo]
         if bool(stale) or bool(fame_lag):
-            eng.detach()
-            hg._live_device_engine = None
+            # Core drops the engine on the raise
             raise GridUnsupported(
                 "device window/unroll exhausted; rebuilding via one-shot path"
             )

@@ -307,7 +307,7 @@ def check_device_core(core, what: str) -> Dict[str, object]:
           f"{what}: {core.device_attach_failures} failed attaches")
     check(led["kernel_retraces"] == 0,
           f"{what}: {led['kernel_retraces']} retraces")
-    eng = core.hg._live_device_engine
+    eng = core.device_engine
     return {
         "rung": core.ladder_rung(),
         "device_consensus_runs": core.device_consensus_runs,

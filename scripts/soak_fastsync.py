@@ -251,11 +251,11 @@ def scenario_reattach():
         # the engine must re-attach with traffic flowing
         deadline = time.monotonic() + 300
         while time.monotonic() < deadline:
-            if getattr(node.core.hg, "_live_device_engine", None) is not None:
+            if node.core.ladder_rung() == "live":
                 break
             target += 1
             watched_wait(nodes, nodes, proxies, target, 240, "reattach-poll")
-        if getattr(node.core.hg, "_live_device_engine", None) is None:
+        if node.core.ladder_rung() != "live":
             raise Stall("reattach: live engine never re-attached")
     finally:
         shutdown_nodes(nodes)

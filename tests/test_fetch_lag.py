@@ -111,8 +111,8 @@ def test_a_result_is_integrated_on_the_call_after_its_dispatch(
     depths = []
     core, blocks, totals = drive(
         stream, cap=4,
-        after_call=lambda c: depths.append(len(c.hg._live_device_engine.inflight)))
-    eng = core.hg._live_device_engine
+        after_call=lambda c: depths.append(len(c.device_engine.inflight)))
+    eng = core.device_engine
     assert totals["fetch.pipelined"][0] == totals["fetch.lag"][0] > 10
     assert set(lags(core)[:-1]) == {1}  # the flush's own fetch lags no call
     assert "fetch.deepen" not in core.hg.obs.tracer.totals()
@@ -128,9 +128,9 @@ def test_waits_on_consecutive_calls_deepen_the_lag_to_the_cap(
     core, blocks, totals = drive(
         stream, cap=cap, waits=lambda i: SLOW,
         after_call=lambda c: seen.append(
-            (c.hg._live_device_engine.fetch_lag,
-             len(c.hg._live_device_engine.inflight))))
-    eng = core.hg._live_device_engine
+            (c.device_engine.fetch_lag,
+             len(c.device_engine.inflight))))
+    eng = core.device_engine
     assert eng.fetch_lag == cap
     assert totals["fetch.deepen"] == (cap - 1, 0.0)
     steps = [b[0] - a[0] for a, b in zip(seen, seen[1:])]
@@ -150,7 +150,7 @@ def test_a_fast_fetch_between_slow_ones_deepens_nothing(
         pipelined, stream, cpu_blocks):
     core, blocks, totals = drive(
         stream, cap=4, waits=lambda i: 0.0 if i % 3 == 2 else SLOW)
-    assert core.hg._live_device_engine.fetch_lag == 1
+    assert core.device_engine.fetch_lag == 1
     assert "fetch.deepen" not in totals
     assert totals["fetch.pipelined"][0] == totals["fetch.lag"][0]
     assert blocks == cpu_blocks
@@ -160,7 +160,7 @@ def test_a_fast_fetch_between_slow_ones_deepens_nothing(
 def test_a_cap_of_one_or_none_keeps_a_lag_of_one(
         pipelined, stream, cpu_blocks, cap):
     core, blocks, totals = drive(stream, cap=cap, waits=lambda i: SLOW)
-    eng = core.hg._live_device_engine
+    eng = core.device_engine
     assert eng.queue_depth == cap and eng.fetch_lag == 1
     assert "fetch.deepen" not in totals
     assert set(lags(core)[:-1]) == {1}
@@ -175,7 +175,7 @@ def test_a_queue_drained_for_lack_of_traffic_starts_again_at_one(
     after_idle = []
 
     def idle_once(core):
-        eng = core.hg._live_device_engine
+        eng = core.device_engine
         if eng.fetch_lag == 3 and len(eng.inflight) == 3 and not after_idle:
             for _ in range(3):
                 core.run_consensus()
@@ -187,7 +187,7 @@ def test_a_queue_drained_for_lack_of_traffic_starts_again_at_one(
     # because it dispatched nothing
     assert after_idle == [(3, 1), (1, 0), (1, 0)]
     # and the waits that follow deepen it again
-    assert core.hg._live_device_engine.fetch_lag == 3
+    assert core.device_engine.fetch_lag == 3
     assert totals["fetch.deepen"][0] == 4
 
 
@@ -204,7 +204,7 @@ def test_rebase_barrier_and_flush_leave_nothing_in_flight(
 
     monkeypatch.setattr(live_mod.LiveDeviceEngine, "rebase", rebase)
     core, blocks, totals = drive(stream, cap=4, waits=lambda i: waits)
-    eng = core.hg._live_device_engine
+    eng = core.device_engine
     assert eng.rebases > 0 and set(in_flight_at_rebase) == {0}
     # a barrier's drain waits by design: it is no evidence, and keeps the lag
     assert eng.fetch_lag == (4 if waits else 1)
@@ -217,7 +217,7 @@ def test_the_synchronous_discipline_has_no_lag(monkeypatch, stream, cpu_blocks):
     core, blocks, totals = drive(stream, cap=4, waits=lambda i: SLOW)
     assert totals["fetch.lag"][0] == 0 and "fetch.pipelined" not in totals
     assert "fetch.deepen" not in totals and not integrations(core)
-    assert core.hg._live_device_engine.fetch_lag == 1
+    assert core.device_engine.fetch_lag == 1
     assert blocks == cpu_blocks
 
 
@@ -239,7 +239,7 @@ def test_a_flip_starts_the_lag_at_one(monkeypatch, stream, cpu_blocks):
 
     def watch(core):
         clock_of["clock"] = core.hg.obs.clock
-        eng = core.hg._live_device_engine
+        eng = core.device_engine
         if eng.async_fetch and not flipped_at:
             flipped_at.append((eng.calls, eng.fetch_lag, eng._slow_fetches))
 

@@ -459,7 +459,7 @@ def test_rebase_has_a_span(monkeypatch, cpu_blocks):
     spans = core.hg.obs.tracer.spans()
     by_id = {s.id: s for s in spans}
     rebases = [s for s in named(spans, "live.rebase") if s.attrs]
-    eng = core.hg._live_device_engine
+    eng = core.device_engine
     assert len(rebases) == eng.rebases > 0
     assert core.hg.obs.counter("babble_device_rebases_total").value() == eng.rebases
     assert [s.attrs["base"] for s in rebases] == sorted(s.attrs["base"] for s in rebases)

@@ -508,7 +508,7 @@ def test_stage_cells_total_is_what_was_handed_less_the_pruned(monkeypatch, run):
     staged = [s.attrs["fd_updates"] for s in spans if s.name == "live.stage"]
     assert sum(staged) <= seen.handed  # the ring may have wrapped
     if run is not plain_run:
-        eng = core.hg._live_device_engine
+        eng = core.device_engine
         assert eng.rebases > 2 and eng.round_base > 0
         assert seen.dropped > 0 and seen.parent_lamports > 0
         # each side of the packer's selection drops the pruned cells itself

@@ -196,7 +196,7 @@ def test_a_batch_is_cut_at_the_update_cap(monkeypatch, n, events, span, cap,
     # the cuts at the staging are counted apart from the level table's and
     # handed to the tracer once a dispatch
     cut = tpu.hg.obs.tracer.totals()["stage.cut"][0]
-    assert cut == tpu.hg._live_device_engine.update_cuts > 0
+    assert cut == tpu.device_engine.update_cuts > 0
 
 
 def test_width_128_agrees_with_the_reference(monkeypatch):
@@ -230,7 +230,7 @@ def test_width_128_agrees_with_the_reference(monkeypatch):
     assert "live.host_repair" not in totals
     # the programs the cell launches, and the staging's cuts counted: at this
     # width a 32-row batch carries ~4,000 cells and now and then over 8,192
-    eng = tpu.hg._live_device_engine
+    eng = tpu.device_engine
     assert totals["live.launch.step"][0] >= 60  # the attach is not in it
     assert totals["live.launch.train"][0] >= 28
     assert totals["stage.cut"][0] == eng.update_cuts
