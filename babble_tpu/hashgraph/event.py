@@ -157,11 +157,16 @@ class Event:
         if pub is None:
             pub = crypto.pub_key_from_bytes(self.body.creator)
         r, s = crypto.decode_signature(self.signature)
+        return crypto.verify(pub, self.fresh_hash(), r, s)
+
+    def fresh_hash(self) -> bytes:
+        """The digest of the body as it stands now, left as the event's
+        cached hash: what `verify` checks the signature over."""
         digest = self.body.hash()
         if digest != self._hash:
             self._hash = digest
             self._hex = ""
-        return crypto.verify(pub, digest, r, s)
+        return digest
 
     # -- consensus metadata ------------------------------------------------
 

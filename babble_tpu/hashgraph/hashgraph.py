@@ -32,6 +32,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from .. import crypto
+from ..crypto.batch_verify import PoolVerifier
 from ..common import StoreErr, StoreErrType, is_store_err
 from ..peers import Peers
 from .block import Block, BlockSignature, new_block_from_frame
@@ -543,6 +544,13 @@ class Hashgraph:
             self._key_hits += 1
         if not event.verify(self._creator_key(event)):
             raise ValueError("Invalid Event signature")
+        self._insert_verified(event, set_wire_info, t_insert)
+
+    def _insert_verified(self, event: Event, set_wire_info: bool,
+                         t_insert: float) -> None:
+        """`insert_event` from where the signature has been checked, since
+        `t_insert` on the hashgraph's clock."""
+        now = self.obs.clock.monotonic
         t_verified = now()
 
         # the creator's root and the stored parents, fetched once for the
@@ -1875,10 +1883,30 @@ class Hashgraph:
         topo = getattr(self.store, "db_topological_events", None)
         return [] if topo is None else topo()
 
-    def insert_stored(self, events: List[Event]) -> None:
-        """Insert `stored_events` again, each signature checked."""
-        for e in events:
-            self.insert_event(e, True)
+    def stored_event_count(self) -> int:
+        """How many events `stored_events` will read, known before it
+        reads them; 0 for a store that keeps nothing on disk."""
+        count = getattr(self.store, "db_event_count", None)
+        return 0 if count is None else count()
+
+    def insert_stored(self, events: List[Event],
+                      pool: Optional[PoolVerifier] = None) -> None:
+        """Insert `stored_events` again, each signature checked before its
+        insert. With a `pool` (`Core.bootstrap` starts one for a long list)
+        the checks run in its workers ahead of the inserts; an event the
+        pool did not pass is checked here at its turn, so a forged one is
+        refused in the same place, with every event before it inserted and
+        none after it."""
+        if pool is None:
+            for e in events:
+                self.insert_event(e, True)
+            return
+        now = self.obs.clock.monotonic
+        for e, verdict in pool.checked(events, self._pos_by_pubkey):
+            if verdict:
+                self._insert_verified(e, True, now())
+            else:
+                self.insert_event(e, True)
 
     # ------------------------------------------------------------------
     # wire (reference: src/hashgraph/hashgraph.go:1414-1479)

@@ -295,6 +295,11 @@ class SQLiteStore(Store):
             raise StoreErr("SQLite.Roots", StoreErrType.KEY_NOT_FOUND, participant)
         return Root.from_canonical(json.loads(row[0]))
 
+    def db_event_count(self) -> int:
+        """The rows `db_topological_events` reads, from the primary key's
+        last entry: the rows are numbered from 0 in insertion order."""
+        return self._db_max_topo() + 1
+
     def db_topological_events(self) -> List[Event]:
         """All events in insertion order, for Bootstrap replay. Consensus
         metadata is deliberately stripped (from_json, not from_store_json):

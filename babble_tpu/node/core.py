@@ -8,6 +8,7 @@ import queue
 from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 from ..crypto import pub_key_bytes
+from ..crypto.batch_verify import PoolVerifier
 from ..hashgraph import (
     Block,
     BlockSignature,
@@ -220,7 +221,10 @@ class Core:
         store needs it; reference: src/node/core.go Bootstrap): read the
         stored events in topological order (`bootstrap.read`), insert them
         again with their signatures checked (`bootstrap.insert`; a row
-        already on disk gets its stamps row, not a second row), and settle
+        already on disk gets its stamps row, not a second row; for a long
+        store the checks run in a pool of worker processes started before
+        the read and joined before the consensus call, total
+        `bootstrap.verify`, crypto/batch_verify.py), and settle
         them with one `run_consensus`, so that the ladder's engine re-derives
         the blocks through the normal commit path, where
         `check_block_immutable` holds each one to the stored body. On the
@@ -236,13 +240,22 @@ class Core:
         try:
             with obs.span("core.bootstrap") as sp:
                 obs.tracer.checkpoint()  # as in run_consensus
-                with obs.span("bootstrap.read"):
-                    events = self.hg.stored_events()
-                with obs.span("bootstrap.insert"):
-                    self.hg.insert_stored(events)
+                # the signature checks' workers start while the store is read
+                pool = PoolVerifier.start(self.hg.stored_event_count(),
+                                          obs.clock.monotonic)
+                try:
+                    with obs.span("bootstrap.read"):
+                        events = self.hg.stored_events()
+                    with obs.span("bootstrap.insert"):
+                        self.hg.insert_stored(events, pool)
+                finally:
+                    if pool is not None:
+                        pool.close()
                 self.run_consensus()
                 blocks = store.last_block_index() - blocks
                 obs.tracer.add("bootstrap.events", 0.0, len(events))
+                if pool is not None:
+                    obs.tracer.add("bootstrap.verify", pool.blocked_s, pool.verified)
                 obs.tracer.add("bootstrap.blocks", 0.0, blocks)
                 sp.attrs.update(events=len(events), blocks=blocks,
                                 rung=self.ladder_rung())
