@@ -4,12 +4,13 @@ UndeterminedEvents + memo-cache discipline, src/hashgraph/hashgraph.go:36-40,
 767-780, recast as device-resident buffers + delta scatters).
 
 What the file holds, in order: the state (`IncState`), one append batch
-(`Batch`), the append body and the fame + round-received body
+(`Batch`) and where its fields lie in the one int32 buffer a launch hands
+over (`BatchLayout`), the append body and the fame + round-received body
 (`_step_body`, `_decide_body`), the two jitted programs over them — `step`
 (one batch, then decide) and `multi_step` (a scan of K batches, then one
 decide) — and the host-side builder that slices a recorded grid into
-batches (`batches_from_grid`). `tpu/live.py` is the caller: its
-`LiveDeviceEngine` builds the same `Batch` from a node's inserts, launches
+batch buffers (`batches_from_grid`). `tpu/live.py` is the caller: its
+`LiveDeviceEngine` writes the same buffer from a node's inserts, launches
 `step` for a sync of one or two batches and `multi_step` trains padded to
 K=4 or K=16 beyond that, and fetches the decisions. It is the one append
 engine of the package.
@@ -42,6 +43,7 @@ from __future__ import annotations
 
 import functools
 import itertools
+import math
 from typing import NamedTuple
 
 import jax
@@ -135,6 +137,83 @@ class Batch(NamedTuple):
     # but a child's lamport needs the value); -1 where the parent is a row
     sp_lamport: jax.Array  # (B,) int32
     op_lamport: jax.Array  # (B,) int32
+
+
+# static height of the within-batch level table; a gossip batch deeper
+# than this (one creator chaining >L_MAX events) is split automatically
+L_MAX = 16
+
+
+class BatchLayout(NamedTuple):
+    """Where each `Batch` field lies in the one int32 buffer a launch hands
+    the device: the fields in `Batch` order, each at a static offset and
+    in its static shape, `coin` as 0/1. A launch pays per array it hands
+    over, whatever the array's size (PERF.md section 7), so `step` and
+    `multi_step` take one buffer (a train: one row a batch) and this
+    layout as a static argument, and slice the buffer back into a `Batch`
+    inside the program (`_unpack`). The host writes a batch straight into
+    its slices (`fields`, on a copy of `blank`) or packs one it built
+    (`pack`)."""
+
+    batch_cap: int  # B, the rows a batch appends
+    n: int
+    upd_cap: int  # U, the first-descendant updates a batch stages
+
+    def shapes(self) -> Batch:
+        b, u = self.batch_cap, self.upd_cap
+        return Batch(
+            rows=(b,), creator=(b,), index=(b,), sp_row=(b,), op_row=(b,),
+            la_rows=(b, self.n), coin=(b,), fixed_round=(b,),
+            upd_row=(u,), upd_col=(u,), upd_val=(u,), levels=(L_MAX, b),
+            sp_lamport=(b,), op_lamport=(b,),
+        )
+
+    @property
+    def width(self) -> int:
+        return _spans(self)[-1][1]
+
+    def fields(self, buf) -> Batch:
+        """Each field's slice of `buf`, whose last axis is the layout's, in
+        the field's shape: views of a host buffer, where a batch is
+        written, and slices of a traced one. `coin` stays int32 here."""
+        lead = buf.shape[:-1]
+        return Batch(*[buf[..., start:stop].reshape(lead + shape)
+                       for start, stop, shape in _spans(self)])
+
+    def blank(self, e_cap: int) -> np.ndarray:
+        """A batch that appends nothing, every scatter dropping (rows -1,
+        update rows e_cap): each field holds its padding."""
+        buf = np.full(self.width, -1, dtype=np.int32)
+        f = self.fields(buf)
+        for zero in (f.creator, f.coin, f.upd_col, f.upd_val):
+            zero[:] = 0
+        f.index[:] = MAX_INT32
+        f.upd_row[:] = e_cap
+        return buf
+
+    def pack(self, batch: Batch) -> np.ndarray:
+        """The buffer of a `Batch` a caller built field by field."""
+        buf = np.empty(self.width, dtype=np.int32)
+        for view, value in zip(self.fields(buf), batch):
+            view[...] = value
+        return buf
+
+
+@functools.lru_cache(maxsize=None)
+def _spans(layout: BatchLayout):
+    """(start, stop, shape) of each field of `layout`, in `Batch` order."""
+    spans, at = [], 0
+    for shape in layout.shapes():
+        size = math.prod(shape)
+        spans.append((at, at + size, shape))
+        at += size
+    return tuple(spans)
+
+
+def _unpack(buf, layout: BatchLayout) -> Batch:
+    """The traced `Batch` of one batch's buffer: static slices."""
+    b = layout.fields(buf)
+    return b._replace(coin=b.coin != 0)
 
 
 # statically unrolled fame-voting depth: decisions normally land at d<=5;
@@ -515,16 +594,16 @@ def _decide_body(
 
 
 # kernel-contract: _step_full
-#   in: state:pytree batch:pytree
-#   static: super_majority n_participants r_win e_win packed
+#   in: state:pytree buf:i32[1]
+#   static: layout super_majority n_participants r_win e_win packed
 #   donate: state
 #   rung: incremental
 #   out: IncState (in-place via donation)
-def _step_full(state, batch, super_majority, n_participants,
+def _step_full(state, buf, layout, super_majority, n_participants,
                r_win: int = 32, e_win: int = 8192, packed: bool = False):
     return _decide_body(
-        _step_body(state, batch, super_majority, n_participants,
-                   packed=packed),
+        _step_body(state, _unpack(buf, layout), super_majority,
+                   n_participants, packed=packed),
         super_majority, n_participants, r_win=r_win, e_win=e_win,
         packed=packed,
     )
@@ -533,28 +612,31 @@ def _step_full(state, batch, super_majority, n_participants,
 step = functools.partial(
     jax.jit,
     static_argnames=(
-        "super_majority", "n_participants", "r_win", "e_win", "packed",
+        "layout", "super_majority", "n_participants", "r_win", "e_win",
+        "packed",
     ),
     donate_argnames=("state",),
 )(_step_full)
 
 
 # kernel-contract: multi_step
-#   in: state:pytree stacked:pytree
-#   static: super_majority n_participants r_win e_win packed
+#   in: state:pytree buf:i32[2]
+#   static: layout super_majority n_participants r_win e_win packed
 #   donate: state
 #   rung: incremental
 #   out: IncState after K scanned batches + one decide
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "super_majority", "n_participants", "r_win", "e_win", "packed",
+        "layout", "super_majority", "n_participants", "r_win", "e_win",
+        "packed",
     ),
     donate_argnames=("state",),
 )
 def multi_step(
     state: IncState,
-    stacked: Batch,  # every field stacked along a leading K axis
+    buf: jax.Array,  # (K, layout.width) int32: one batch a row
+    layout: BatchLayout,
     super_majority: int,
     n_participants: int,
     r_win: int = 32,
@@ -568,26 +650,13 @@ def multi_step(
     per-execute overhead and the decide cost over K batches; the host
     dispatches one call per K syncs."""
 
-    def body(st, b):
-        return _step_body(st, b, super_majority, n_participants,
-                          packed=packed), None
+    def body(st, row):
+        return _step_body(st, _unpack(row, layout), super_majority,
+                          n_participants, packed=packed), None
 
-    out, _ = jax.lax.scan(body, state, stacked)
+    out, _ = jax.lax.scan(body, state, buf)
     return _decide_body(out, super_majority, n_participants,
                         r_win=r_win, e_win=e_win, packed=packed)
-
-
-def stack_batches(batches):
-    """Host-side: stack a list of equal-shape Batch pytrees along axis 0."""
-    return Batch(*[
-        np.stack([np.asarray(getattr(b, f)) for b in batches])
-        for f in Batch._fields
-    ])
-
-
-def _pad1(a, pad, fill, dtype=np.int32):
-    a = np.asarray(a, dtype=dtype)
-    return np.concatenate([a, np.full(pad, fill, dtype=dtype)])
 
 
 # a batch of this many first-descendant cells or fewer is packed cell by
@@ -598,8 +667,10 @@ def _pad1(a, pad, fill, dtype=np.int32):
 FEW_CELLS = 64
 
 
-def _pack_upd(cell_rows, n_cells, counts, creator, index, upd_cap, e_cap):
-    """One batch's first-descendant scatter operands. `cell_rows` yields
+def _pack_upd(cell_rows, n_cells, counts, creator, index, urow, ucol, uval):
+    """One batch's first-descendant scatter operands, written into the
+    batch buffer's three update slices `urow`, `ucol`, `uval`, which hold
+    their padding (row e_cap, which the scatter drops). `cell_rows` yields
     the rows of the `n_cells` ancestors whose cell the batch's events
     wrote, event after event, `counts[k]` of them for event k; a cell's
     column and value are its writer's own creator position and index,
@@ -607,12 +678,11 @@ def _pack_upd(cell_rows, n_cells, counts, creator, index, upd_cap, e_cap):
     of the state any more: its fd row is final and can never be read
     again, so the update is dropped, and the rest keep their order. Over
     FEW_CELLS the cells are taken in bulk, with no Python object a cell.
-    Returns (urow, ucol, uval), padded to upd_cap with row e_cap (which the
-    scatter drops), and the number of cells staged. What lets the GIL go
-    costs a served node most, since its other threads take it: an
-    allocation of upd_cap elements does (so column and value share one),
-    and so does a mask over a second axis at any size (so everything
-    indexed is one-dimensional); PERF.md section 6, PR 32."""
+    Returns the number of cells staged. What lets the GIL go costs a
+    served node most, since its other threads take it: an allocation of
+    upd_cap elements does (so none is made here), and so does a mask over
+    a second axis at any size (so everything indexed is one-dimensional);
+    PERF.md section 6, PR 32."""
     if n_cells <= FEW_CELLS:
         writers = (
             w for w, count in zip(zip(creator, index), counts)
@@ -627,19 +697,12 @@ def _pack_upd(cell_rows, n_cells, counts, creator, index, upd_cap, e_cap):
         cols = np.repeat(creator, counts)[kept]
         vals = np.repeat(index, counts)[kept]
     m = len(rows)
-    if m > upd_cap:
+    if m > len(urow):
         raise GridUnsupported("fd update burst exceeds device staging")
-    urow = np.full(upd_cap, e_cap, dtype=np.int32)
-    ucol, uval = np.zeros((2, upd_cap), dtype=np.int32)
     urow[:m] = rows
     ucol[:m] = cols
     uval[:m] = vals
-    return urow, ucol, uval, m
-
-
-# static height of the within-batch level table; a gossip batch deeper
-# than this (one creator chaining >L_MAX events) is split automatically
-L_MAX = 16
+    return m
 
 
 def _dep_levels(sp_pos, op_pos):
@@ -655,23 +718,25 @@ def _dep_levels(sp_pos, op_pos):
     return lvl
 
 
-def _level_table(lvl, batch_size):
-    """(L_MAX, batch_size) table of the slice positions on each level, in
-    slice order, -1 padding. The caller keeps every depth under L_MAX."""
-    levels = np.full((L_MAX, batch_size), -1, dtype=np.int32)
+def _level_table(lvl, levels):
+    """Write the slice positions on each level, in slice order, into the
+    (L_MAX, B) table `levels`, which holds -1 padding. The caller keeps
+    every depth under L_MAX."""
     slot = [0] * L_MAX
     for k, l in enumerate(lvl):
         levels[l, slot[l]] = k
         slot[l] += 1
-    return levels
 
 
 def batches_from_grid(grid: DagGrid, batch_size: int, upd_cap: int, e_cap: int):
-    """Slice a recorded synthetic DAG into fixed-shape append batches —
-    the host-side work a live node would do during inserts (O(batch)).
+    """Slice a recorded synthetic DAG into append batches, each written
+    into one buffer of `BatchLayout(batch_size, grid.n, upd_cap)` — the
+    host-side work a live node would do during inserts (O(batch)).
     Batches whose within-batch dependency depth exceeds L_MAX, or whose
     first-descendant updates exceed upd_cap, are split."""
     assert grid.fd_update_stream is not None, "need record_fd_updates=True"
+    layout = BatchLayout(batch_size, grid.n, upd_cap)
+    blank = layout.blank(e_cap)
     spans = [
         (s, min(s + batch_size, grid.e))
         for s in range(0, grid.e, batch_size)
@@ -681,7 +746,6 @@ def batches_from_grid(grid: DagGrid, batch_size: int, upd_cap: int, e_cap: int):
         start, end = spans.pop(0)
         rows = np.arange(start, end)
         b = len(rows)
-        pad = batch_size - b
 
         sp = grid.self_parent[rows]
         op = grid.other_parent[rows]
@@ -698,26 +762,22 @@ def batches_from_grid(grid: DagGrid, batch_size: int, upd_cap: int, e_cap: int):
             mid = (start + end) // 2
             spans[:0] = [(start, mid), (mid, end)]
             continue
-        urow, ucol, uval, _ = _pack_upd(
+        buf = blank.copy()
+        f = layout.fields(buf)
+        _pack_upd(
             itertools.chain.from_iterable(cells), n_upd,
-            counts, grid.creator[rows], grid.index[rows], upd_cap, e_cap,
+            counts, grid.creator[rows], grid.index[rows],
+            f.upd_row, f.upd_col, f.upd_val,
         )
-
-        no_row = np.full(batch_size, -1, dtype=np.int32)  # a grid holds every parent
-        out.append(Batch(
-            rows=_pad1(rows, pad, -1),
-            creator=_pad1(grid.creator[rows], pad, 0),
-            index=_pad1(grid.index[rows], pad, MAX_INT32),
-            sp_row=_pad1(sp, pad, -1),
-            op_row=_pad1(op, pad, -1),
-            la_rows=np.concatenate(
-                [grid.last_ancestors[rows],
-                 np.full((pad, grid.n), -1, dtype=np.int32)]
-            ),
-            coin=_pad1(grid.coin_bit[rows], pad, False, dtype=bool),
-            fixed_round=_pad1(grid.fixed_round[rows], pad, -1),
-            upd_row=urow, upd_col=ucol, upd_val=uval,
-            levels=_level_table(lvl, batch_size),
-            sp_lamport=no_row, op_lamport=no_row,
-        ))
+        f.rows[:b] = rows
+        f.creator[:b] = grid.creator[rows]
+        f.index[:b] = grid.index[rows]
+        f.sp_row[:b] = sp
+        f.op_row[:b] = op
+        f.la_rows[:b] = grid.last_ancestors[rows]
+        f.coin[:b] = grid.coin_bit[rows]
+        f.fixed_round[:b] = grid.fixed_round[rows]
+        _level_table(lvl, f.levels)
+        # sp_lamport / op_lamport keep -1: a grid holds every parent
+        out.append(buf)
     return out

@@ -12,9 +12,10 @@ Wiring: the Hashgraph's insert path reports each inserted event plus the
 first-descendant cells its insert wrote (hashgraph.insert_listener): the
 hashes of the ancestors written, since a cell's column and value are the
 event's own creator position and index. LiveDeviceEngine.serve drains that
-queue into fixed-shape append batches, a batch's cells going to the three
-update arrays in bulk (`_build_batch`, `incremental._pack_upd`),
-advances the device state, and writes new rounds/fame/received back into
+queue into fixed-shape append batches, each written into one int32 buffer
+(`incremental.BatchLayout`) and a batch's cells going to its three update
+slices in bulk (`_build_batch`, `incremental._pack_upd`), advances the
+device state, and writes new rounds/fame/received back into
 the store exactly like the one-shot engine. Passes 4-5 stay host-side, so
 blocks remain byte-identical by construction.
 
@@ -35,15 +36,15 @@ import numpy as np
 from ..obs.devledger import ledger_call
 from .grid import MAX_INT32, DagGrid, GridUnsupported, grid_from_hashgraph
 from .incremental import (
-    Batch,
+    BatchLayout,
     IncState,
     L_MAX,
     _dep_levels,
     _level_table,
     _pack_upd,
+    batches_from_grid,
     init_state,
     multi_step,
-    stack_batches,
     step,
 )
 from .packed import observe_table_bytes, packed_enabled
@@ -224,6 +225,12 @@ class LiveDeviceEngine:
             buckets=[i / 10 for i in range(11)],
         )
         self.layout = "packed" if self.packed else "wide"  # ledger cells
+        # where a batch's fields lie in the one buffer a launch hands over,
+        # and the batch that appends nothing: the template every batch is
+        # written on and what pads a train
+        self.batch_layout = BatchLayout(self.batch_cap, self.n, self.upd_cap)
+        self.empty_batch = self.batch_layout.blank(self.e_cap)
+        self.empty_batch.flags.writeable = False
         self.state: IncState = init_state(self.n, self.e_cap, self.r_cap)
         # the state's `reopened` counts as of the last integration
         self.reopened_seen = np.zeros(self.r_cap, np.int32)
@@ -368,12 +375,12 @@ class LiveDeviceEngine:
         grid = dataclasses.replace(
             grid, fd_update_stream=derive_fd_updates(grid)
         )
-        from .incremental import batches_from_grid
-
-        for b in batches_from_grid(grid, self.batch_cap, self.upd_cap, self.e_cap):
+        for buf in batches_from_grid(grid, self.batch_cap, self.upd_cap,
+                                     self.e_cap):
             self.state = step(
-                self.state, b, self.hg.super_majority, self.n,
-                e_win=self.e_win, r_win=self.r_win, packed=self.packed,
+                self.state, buf, self.batch_layout, self.hg.super_majority,
+                self.n, e_win=self.e_win, r_win=self.r_win,
+                packed=self.packed,
             )
 
     def _kept_room(self) -> int:
@@ -779,37 +786,38 @@ class LiveDeviceEngine:
                 # would otherwise exceed the level table) and the batch's
                 # first-descendant updates (a wide validator set, or a
                 # withheld chain revealed at once, bursts past the staging)
-                built: List[Batch] = []
+                built: List[np.ndarray] = []
                 pos, staged_before = 0, self.cells_staged
                 cuts_before = self.update_cuts
                 while pos < len(drained):
                     chunk = drained[pos : pos + self.batch_cap]
                     chunk = self._cut(chunk)
                     pos += len(chunk)
-                    batch, rows = self._build_batch(chunk)
-                    built.append(batch)
+                    buf, rows = self._build_batch(chunk)
+                    built.append(buf)
                     new_rows.extend(rows)
                 sp.attrs["batches"] = len(built)
-                # trains: up to 16 batches, padded to K=4 or K=16, stacked.
-                # One shape per dispatch: where a sync overflows one K=16
-                # train (a revealed chain is cut every L_MAX events), its
-                # last few batches ride a second K=16 train and not the
-                # K=4 program, which a node with large syncs never
-                # compiled
+                # trains: up to 16 batches, padded to K=4 or K=16, stacked
+                # into one (K, width) buffer. One shape per dispatch: where
+                # a sync overflows one K=16 train (a revealed chain is cut
+                # every L_MAX events), its last few batches ride a second
+                # K=16 train and not the K=4 program, which a node with
+                # large syncs never compiled
                 trains = []
                 if len(built) > 2:
                     k = 4 if len(built) <= 4 else 16
                     for i in range(0, len(built), k):
                         group = built[i : i + k]
-                        group = group + [self._empty_batch()] * (k - len(group))
-                        trains.append((k, stack_batches(group)))
+                        group = group + [self.empty_batch] * (k - len(group))
+                        trains.append((k, np.stack(group)))
 
             with obs.devledger.activate("live", layout=self.layout):
                 for b in ([] if trains else built):
                     with obs.span("live.launch", program="step", k=1):
                         self.state = ledger_call(
                             "_step_full", step,
-                            self.state, b, self.hg.super_majority, self.n,
+                            self.state, b, self.batch_layout,
+                            self.hg.super_majority, self.n,
                             e_win=self.e_win, r_win=self.r_win,
                             packed=self.packed,
                         )
@@ -818,13 +826,14 @@ class LiveDeviceEngine:
                     with obs.span("live.launch", program="multi_step", k=k):
                         self.state = ledger_call(
                             "multi_step", multi_step,
-                            self.state, stacked,
+                            self.state, stacked, self.batch_layout,
                             self.hg.super_majority, self.n, e_win=self.e_win,
                             r_win=self.r_win, packed=self.packed,
                         )
                     self.dispatches += 1
-            # the cells the stage took in bulk (the pruned ones dropped) and
-            # which program the dispatch launched, as count totals
+            # the cells the stage took in bulk (the pruned ones dropped),
+            # which program the dispatch launched, and the launches handed
+            # their batches as one buffer (all of them), as count totals
             obs.tracer.add(
                 "stage.cells", 0.0, self.cells_staged - staged_before,
             )
@@ -833,33 +842,9 @@ class LiveDeviceEngine:
                 obs.tracer.add("live.launch.train", 0.0, len(trains))
             else:
                 obs.tracer.add("live.launch.step", 0.0, len(built))
+            obs.tracer.add("live.launch.packed", 0.0,
+                           len(trains) or len(built))
         return new_rows
-
-    def _empty_batch(self) -> Batch:
-        """A no-op Batch (every scatter drops) for padding multi_step
-        groups to their fixed stack shapes."""
-        cached = getattr(self, "_empty_batch_cache", None)
-        if cached is not None:
-            return cached
-        n, b_cap = self.n, self.batch_cap
-        b = Batch(
-            rows=np.full(b_cap, -1, dtype=np.int32),
-            creator=np.zeros(b_cap, dtype=np.int32),
-            index=np.full(b_cap, MAX_INT32, dtype=np.int32),
-            sp_row=np.full(b_cap, -1, dtype=np.int32),
-            op_row=np.full(b_cap, -1, dtype=np.int32),
-            la_rows=np.full((b_cap, n), -1, dtype=np.int32),
-            coin=np.zeros(b_cap, dtype=bool),
-            fixed_round=np.full(b_cap, -1, dtype=np.int32),
-            upd_row=np.full(self.upd_cap, self.e_cap, dtype=np.int32),
-            upd_col=np.zeros(self.upd_cap, dtype=np.int32),
-            upd_val=np.zeros(self.upd_cap, dtype=np.int32),
-            levels=np.full((L_MAX, b_cap), -1, dtype=np.int32),
-            sp_lamport=np.full(b_cap, -1, dtype=np.int32),
-            op_lamport=np.full(b_cap, -1, dtype=np.int32),
-        )
-        self._empty_batch_cache = b
-        return b
 
     def _cut(self, chunk):
         """Longest prefix of `chunk` whose within-chunk dependency depth
@@ -887,13 +872,13 @@ class LiveDeviceEngine:
             depth[ev.hex()] = d
         return chunk
 
-    def _build_batch(self, chunk) -> Tuple[Batch, List[int]]:
+    def _build_batch(self, chunk) -> Tuple[np.ndarray, List[int]]:
         """One append batch from `chunk` ((event, cells) pairs, as the
-        listener was handed them): the Batch and the rows it appends. Built
-        per batch: a field is a Python list through the event loop and one
-        slice store after it, and the cells of all the chunk's events
-        become the three update arrays in one `_pack_upd`."""
-        n, b_cap = self.n, self.batch_cap
+        listener was handed them): its buffer (`batch_layout`) and the rows
+        it appends. Written on a copy of the empty batch: a field is a
+        Python list through the event loop and one slice store after it,
+        and the cells of all the chunk's events go to the three update
+        slices in one `_pack_upd`."""
         b = len(chunk)
         row_of, position = self.row_of, self.hg.peer_position
         base_row = len(self.hashes)
@@ -902,9 +887,8 @@ class LiveDeviceEngine:
         la_flat: List[int] = []
         cell_hashes: List[str] = []
         counts: List[int] = []
-        fixed_round = np.full(b_cap, -1, dtype=np.int32)
-        sp_lamport = np.full(b_cap, -1, dtype=np.int32)
-        op_lamport = np.full(b_cap, -1, dtype=np.int32)
+        buf = self.empty_batch.copy()
+        f = self.batch_layout.fields(buf)
 
         from ..hashgraph.hashgraph import middle_bit
 
@@ -924,9 +908,9 @@ class LiveDeviceEngine:
             # which "no row" already says to the device, and its lamport
             # timestamp is the host's stamp
             if sp < 0 and idx != 0:
-                sp_lamport[k] = self._pruned_lamport(sp_hash)
+                f.sp_lamport[k] = self._pruned_lamport(sp_hash)
             if op < 0 and op_hash != "":
-                op_lamport[k] = self._pruned_lamport(op_hash)
+                f.op_lamport[k] = self._pruned_lamport(op_hash)
             if sp < 0 and op_hash == "":
                 # directly root-attached: round forced to the base root's
                 # next_round (reference: hashgraph.go:207-236); first
@@ -935,7 +919,7 @@ class LiveDeviceEngine:
                 # can only occur before any rebase (base 0).
                 if self.round_base > 0:
                     raise GridUnsupported("root attachment after rebase")
-                fixed_round[k] = 0
+                f.fixed_round[k] = 0
             sp_rows.append(sp)
             op_rows.append(op)
             la_flat.extend([c[0] for c in ev.last_ancestors])
@@ -943,29 +927,22 @@ class LiveDeviceEngine:
             cell_hashes.extend(cells)
             counts.append(len(cells))
 
-        brows = np.full(b_cap, -1, dtype=np.int32)
-        brows[:b] = rows
-        creator = np.zeros(b_cap, dtype=np.int32)
-        creator[:b] = creators
-        index = np.full(b_cap, MAX_INT32, dtype=np.int32)
-        index[:b] = indexes
-        sp_row = np.full(b_cap, -1, dtype=np.int32)
-        sp_row[:b] = sp_rows
-        op_row = np.full(b_cap, -1, dtype=np.int32)
-        op_row[:b] = op_rows
-        coin = np.zeros(b_cap, dtype=bool)
-        coin[:b] = coins
-        la_rows = np.full((b_cap, n), -1, dtype=np.int32)
-        la_rows.reshape(-1)[: b * n] = la_flat
+        f.rows[:b] = rows
+        f.creator[:b] = creators
+        f.index[:b] = indexes
+        f.sp_row[:b] = sp_rows
+        f.op_row[:b] = op_rows
+        f.coin[:b] = coins
+        f.la_rows.reshape(-1)[: b * self.n] = la_flat
         # the cells' rows, resolved after the loop so that an ancestor of
         # the same batch has its row. No row: pruned by a rebase, its fd
         # row is final, and _pack_upd drops the update. (The cells come
         # from the hashgraph's own insert walk, so a hash is always a real
         # ancestor.)
-        urow, ucol, uval, staged = _pack_upd(
+        staged = _pack_upd(
             map(row_of.get, cell_hashes, itertools.repeat(-1)),
             len(cell_hashes), counts, creators, indexes,
-            self.upd_cap, self.e_cap,
+            f.upd_row, f.upd_col, f.upd_val,
         )
         self.cells_staged += staged
         # within-batch levels over batch-local dependencies (a parent of an
@@ -974,17 +951,8 @@ class LiveDeviceEngine:
         lvl = _dep_levels(
             [p - base_row for p in sp_rows], [p - base_row for p in op_rows],
         )
-        return (
-            Batch(
-                rows=brows, creator=creator, index=index,
-                sp_row=sp_row, op_row=op_row, la_rows=la_rows, coin=coin,
-                fixed_round=fixed_round,
-                upd_row=urow, upd_col=ucol, upd_val=uval,
-                levels=_level_table(lvl, b_cap),
-                sp_lamport=sp_lamport, op_lamport=op_lamport,
-            ),
-            rows,
-        )
+        _level_table(lvl, f.levels)
+        return buf, rows
 
     def _pruned_lamport(self, parent: str) -> int:
         """The host's lamport stamp of a parent that is no row of the

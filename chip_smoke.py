@@ -336,7 +336,7 @@ def warm_live_programs(n: int, node_conf, seed: int) -> Dict[str, object]:
 
     from babble_tpu.hashgraph import Hashgraph, InmemStore
     from babble_tpu.peers import Peer, Peers
-    from babble_tpu.tpu.incremental import multi_step, stack_batches, step
+    from babble_tpu.tpu.incremental import multi_step, step
     from babble_tpu.tpu.live import (
         LiveDeviceEngine, _pack_results,
     )
@@ -352,22 +352,23 @@ def warm_live_programs(n: int, node_conf, seed: int) -> Dict[str, object]:
         batch_cap=node_conf.dispatch_batch_rows,
     )
     kw = dict(e_win=eng.e_win, r_win=eng.r_win, packed=eng.packed)
-    empty = eng._empty_batch()
+    empty = eng.empty_batch
     led = hg.obs.devledger
 
     def advance(entry, fn, batch):
         with led.activate("live"):
             eng.state = led.call(
-                entry, fn, eng.state, batch, hg.super_majority, eng.n, **kw
+                entry, fn, eng.state, batch, eng.batch_layout,
+                hg.super_majority, eng.n, **kw
             )
         return eng.state
 
     programs = {
         "step": lambda: advance("_step_full", step, empty),
         "multi_step_k4": lambda: advance(
-            "multi_step", multi_step, stack_batches([empty] * 4)),
+            "multi_step", multi_step, np.stack([empty] * 4)),
         "multi_step_k16": lambda: advance(
-            "multi_step", multi_step, stack_batches([empty] * 16)),
+            "multi_step", multi_step, np.stack([empty] * 16)),
         "pack_results": lambda: led.call(
             "_pack_results", _pack_results,
             eng.state, np.int32(0), eng.e_win, eng.r_cap, eng.n),

@@ -7,7 +7,6 @@ K=16, a short train padded with the engine's empty batches), whatever the
 batch size, and on a stream whose adversarial third arrives late."""
 
 import dataclasses
-import types
 
 import numpy as np
 import pytest
@@ -16,42 +15,36 @@ from babble_tpu.tpu import synthetic_grid
 from babble_tpu.tpu.engine import run_passes
 from babble_tpu.tpu.grid import build_levels
 from babble_tpu.tpu.incremental import (
+    BatchLayout,
     IncState,
     batches_from_grid,
     init_state,
     multi_step,
-    stack_batches,
     step,
 )
-from babble_tpu.tpu.live import LiveDeviceEngine
 
 DECISIONS = ("rounds", "lamport", "witness", "received")
 TABLES = ("wtable", "fame_decided", "famous", "rounds_decided")
 
 
-def empty_batch(n, e_cap, rows=32, upd_cap=8192):
-    """The no-op batch `LiveDeviceEngine` pads a train with, from its own
-    builder at these shapes."""
-    shapes = types.SimpleNamespace(
-        n=n, batch_cap=rows, upd_cap=upd_cap, e_cap=e_cap)
-    return LiveDeviceEngine._empty_batch(shapes)
-
-
-def replay(grid, batches, e_cap, k=None, e_win=512):
-    """`batches` into a fresh state: one `step` each, or `multi_step`
-    trains of `k`, the last one padded to `k` as the engine pads it."""
+def replay(grid, batches, e_cap, k=None, e_win=512, rows=32, upd_cap=8192):
+    """`batches`, the buffers `batches_from_grid(grid, rows, upd_cap, ...)`
+    wrote, into a fresh state: one `step` each, or `multi_step` trains of
+    `k`, the last one padded to `k` with the empty batch, as the engine
+    pads it."""
     n = grid.n
+    layout = BatchLayout(rows, n, upd_cap)
     st = init_state(n, e_cap, 64)
     if k is None:
         for b in batches:
-            st = step(st, b, grid.super_majority, n, e_win=e_win)
+            st = step(st, b, layout, grid.super_majority, n, e_win=e_win)
         return st
-    rows = batches[0].rows.shape[0]
+    empty = layout.blank(e_cap)
     for i in range(0, len(batches), k):
         group = batches[i : i + k]
-        group = group + [empty_batch(n, e_cap, rows)] * (k - len(group))
+        group = group + [empty] * (k - len(group))
         st = multi_step(
-            st, stack_batches(group), grid.super_majority, n, e_win=e_win)
+            st, np.stack(group), layout, grid.super_majority, n, e_win=e_win)
     return st
 
 
@@ -131,7 +124,7 @@ def test_incremental_matches_one_shot(zipf, withheld, program):
         assert e - 64 < grid.e <= e
     rows, k = program
     st = replay(grid, batches_from_grid(grid, rows, 8192, e), e, k=k,
-                e_win=e if withheld else 512)
+                e_win=e if withheld else 512, rows=rows)
 
     ref = run_passes(grid)
     assert not bool(st.stale)
@@ -172,7 +165,7 @@ def test_batch_size_does_not_move_the_end_state(rows):
     n, e = 8, 768
     grid = synthetic_grid(n, e, seed=9, zipf_a=1.1, record_fd_updates=True)
     at_32 = replay(grid, batches_from_grid(grid, 32, 8192, e), e)
-    other = replay(grid, batches_from_grid(grid, rows, 8192, e), e)
+    other = replay(grid, batches_from_grid(grid, rows, 8192, e), e, rows=rows)
     assert_same(at_32, other, DECISIONS + ("fame_decided", "famous"))
     assert not bool(other.stale) and not bool(other.fame_lag)
 
@@ -208,19 +201,15 @@ def test_batches_split_at_the_update_cap():
     n, e = 8, 512
     grid = synthetic_grid(n, e, seed=5, zipf_a=1.1, record_fd_updates=True)
     whole = batches_from_grid(grid, 32, 8192, e)
-    burst = max(int((np.asarray(b.upd_row) != e).sum()) for b in whole)
+    staged = lambda b, cap: int((BatchLayout(32, n, cap).fields(b).upd_row
+                                 != e).sum())
+    burst = max(staged(b, 8192) for b in whole)
     cap = burst // 2
     halves = batches_from_grid(grid, 32, cap, e)
     assert len(halves) > len(whole)
-    assert all(int((np.asarray(b.upd_row) != e).sum()) <= cap for b in halves)
+    assert all(staged(b, cap) <= cap for b in halves)
 
-    def run(batches):
-        st = init_state(n, e, 64)
-        for b in batches:
-            st = step(st, b, grid.super_majority, n, e_win=512)
-        return st
-
-    one, two = run(whole), run(halves)
+    one, two = replay(grid, whole, e), replay(grid, halves, e, upd_cap=cap)
     for f in ("rounds", "lamport", "witness", "received", "fd"):
         np.testing.assert_array_equal(
             np.asarray(getattr(one, f)), np.asarray(getattr(two, f)), f)

@@ -252,28 +252,29 @@ def test_sharded_2d_mesh_packed_matches_wide(dv, dr):
 
 def test_incremental_step_and_multi_step_packed_match_wide():
     from babble_tpu.tpu.incremental import (
-        batches_from_grid, init_state, multi_step, stack_batches, step,
+        BatchLayout, batches_from_grid, init_state, multi_step, step,
     )
 
     n, e = 7, 512
     grid = synthetic_grid(n, e, seed=3, zipf_a=1.1, record_fd_updates=True)
     sm = grid.super_majority
     batches = batches_from_grid(grid, 32, 8192, e)
+    layout = BatchLayout(32, n, 8192)
     assert len(batches) >= 16
 
     def per_batch(st, packed):
         for b in batches:
-            st = step(st, b, sm, n, e_win=512, packed=packed)
+            st = step(st, b, layout, sm, n, e_win=512, packed=packed)
         return st
 
     def per_train(st, packed):
         # whole K=16 trains; what is left of the stream goes batch by batch
         whole = len(batches) - len(batches) % 16
         for i in range(0, whole, 16):
-            st = multi_step(st, stack_batches(batches[i : i + 16]), sm, n,
-                            e_win=512, packed=packed)
+            st = multi_step(st, np.stack(batches[i : i + 16]), layout, sm,
+                            n, e_win=512, packed=packed)
         for b in batches[whole:]:
-            st = step(st, b, sm, n, e_win=512, packed=packed)
+            st = step(st, b, layout, sm, n, e_win=512, packed=packed)
         return st
 
     for run in (per_batch, per_train):

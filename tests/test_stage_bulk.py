@@ -1,14 +1,17 @@
 """The live rung stages a batch's first-descendant cells in bulk (the
 listener is handed ancestor hashes, `_build_batch` resolves a whole batch of
-them at once, `incremental._pack_upd` makes the three update arrays with
-numpy), and the device is handed the bytes it was handed before.
+them at once, `incremental._pack_upd` writes the three update slices with
+numpy) into the batch's one buffer (`incremental.BatchLayout`), and the
+device is handed the bytes it was handed before.
 
 The yardstick is kept here: `PlainStage.build_batch`, `plain_pack_upd`,
 `plain_dep_levels` and `plain_batches_from_grid` are the per-cell loops as
 they stood (a Python tuple a cell, read back, and copied by three scalar
 stores), so that a later change to the staging is still compared with the
-plain loops and not with itself. Every field of every `Batch`, padding
-included, is compared byte for byte, batch by batch, on the same inserts.
+plain loops and not with itself. Every field of every batch, padding
+included, is compared byte for byte, batch by batch, on the same inserts:
+the plain `Batch` packed (`BatchLayout.pack`) against the buffer the
+engine wrote.
 """
 
 import random
@@ -22,7 +25,7 @@ from babble_tpu.tpu import live as live_mod
 from babble_tpu.tpu import synthetic_grid
 from babble_tpu.tpu.grid import MAX_INT32, GridUnsupported
 from babble_tpu.tpu.incremental import (
-    FEW_CELLS, L_MAX, Batch, batches_from_grid,
+    FEW_CELLS, L_MAX, Batch, BatchLayout, batches_from_grid,
 )
 from babble_tpu.tpu.live import LiveDeviceEngine, derive_fd_updates
 from benchmark.entries import replay
@@ -218,11 +221,16 @@ def plain_derive_fd_updates(grid):
 # ---------------------------------------------------------------------------
 
 
-def assert_same_batch(got, want, where):
+def assert_same_batch(got, want, layout, where):
+    """`got`, a batch's buffer, holds the plain `Batch` `want`: field by
+    field in the field's shape, and byte for byte as a whole."""
+    fields = layout.fields(got)
+    assert got.dtype == np.int32 and got.shape == (layout.width,), where
     for f in Batch._fields:
-        a, b = np.asarray(getattr(got, f)), np.asarray(getattr(want, f))
-        assert a.dtype == b.dtype and a.shape == b.shape, (where, f)
-        assert a.tobytes() == b.tobytes(), (where, f)
+        a, b = getattr(fields, f), np.asarray(getattr(want, f))
+        assert a.shape == b.shape, (where, f)
+        assert np.array_equal(a, b), (where, f)
+    assert got.tobytes() == layout.pack(want).tobytes(), where
 
 
 def attempt(build):
@@ -269,9 +277,10 @@ def build_both(eng, chunk, seen):
         assert type(got) is GridUnsupported and str(got) == str(want), where
         seen.refused.append(str(got))
         return got
-    batch, rows = got
+    buf, rows = got
+    batch = eng.batch_layout.fields(buf)
     staged = eng.cells_staged - staged_before
-    assert_same_batch(batch, want[0], where)
+    assert_same_batch(buf, want[0], eng.batch_layout, where)
     assert rows == want[1], where
     handed = sum(len(cells) for _, cells in chunk)
     assert staged == handed - plain.dropped, where
@@ -408,7 +417,7 @@ def test_update_arrays_are_the_plain_walks(n, stream64, stream128):
             done += len(chunk)
             want, _ = PlainStage(eng).build_batch(triples)
             got, _ = ENGINE_BUILD(eng, chunk)
-            assert_same_batch(got, want, f"batch {batches}")
+            assert_same_batch(got, want, eng.batch_layout, f"batch {batches}")
             batches += 1
     assert done == events and batches >= events // 32
     assert eng.cells_staged == sum(map(len, walked)) > events * 40
@@ -549,8 +558,9 @@ def test_batches_from_grid_are_byte_equal(n, e, byz, rows, cap):
     assert len(got) == len(want) >= e // rows
     if cap == 100:
         assert len(got) > -(-e // rows)
+    layout = BatchLayout(rows, n, cap)
     for k, (a, b) in enumerate(zip(got, want)):
-        assert_same_batch(a, b, f"batch {k}")
+        assert_same_batch(a, b, layout, f"batch {k}")
 
 
 def test_an_event_over_the_cap_in_a_grid_is_refused_alike():
@@ -583,4 +593,4 @@ def test_capacities_by_width(n, e_win):
     assert (eng.n, eng.e_win) == (n, e_win)
     assert (eng.upd_cap, eng.e_cap, eng.r_cap, eng.r_win) == (
         d["upd_cap"], d["e_cap"], d["r_cap"], d["r_win"]) == (8192, 65536, 64, 64)
-    assert eng._empty_batch().upd_row.shape == (8192,)
+    assert eng.batch_layout.fields(eng.empty_batch).upd_row.shape == (8192,)
